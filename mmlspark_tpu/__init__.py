@@ -45,30 +45,3 @@ Layout mirrors the reference's module map (SURVEY.md §1-2):
 """
 
 __version__ = "0.2.0"
-
-# jax < 0.5 compat: the codebase targets the top-level `jax.shard_map`
-# (with its `check_vma` kwarg); older jax only ships
-# `jax.experimental.shard_map.shard_map` (whose equivalent kwarg is
-# `check_rep`).  Install a translating alias so every call site works on
-# both — without it the whole parallel/ layer fails at call time.
-# Tolerate a missing jax entirely: the pure-source tools (graft-lint,
-# `python -m mmlspark_tpu.analysis`) must run on lint-only environments;
-# compute modules fail at their own import time as before.
-try:
-    import jax as _jax
-except ImportError:
-    _jax = None
-
-if _jax is not None and not hasattr(_jax, "shard_map"):
-    import functools as _functools
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    @_functools.wraps(_shard_map)
-    def _shard_map_compat(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(*args, **kwargs)
-
-    _jax.shard_map = _shard_map_compat
-
-del _jax

@@ -212,7 +212,7 @@ class TilePrefetcher:
     deterministic tests; :attr:`waiting` is a test seam set while the
     consumer is blocked on an empty pipeline.
 
-    Transient ``load_fn`` failures (flaky storage, a wedged device relay)
+    Transient ``load_fn`` failures (flaky storage, a hung device dispatch)
     retry up to ``retries`` times with exponential backoff
     (``retry_backoff_s`` × ``retry_backoff_mult``^k, clipped to the
     ambient :class:`~mmlspark_tpu.utils.resilience.Deadline`), classified

@@ -148,7 +148,7 @@ class BinMapper:
 
         Default is HOST binning: the uint8 result is 4x smaller than the
         float32 input, so binning before the host->device transfer quarters
-        the interconnect traffic (decisive through a device relay/DCN).
+        the interconnect traffic.
         Threaded C++ when the data plane + cores exist, vectorized numpy
         per-column searchsorted otherwise; ``device=True`` digitizes on the
         accelerator for data already device-resident.

@@ -2232,8 +2232,8 @@ class ContinuousDecoder:
             else jnp.asarray(self._fin)
         if self.watchdog is not None:
             # the armed section covers the dispatch AND the host fetch
-            # below — both are the hang shapes (a wedged relay stalls the
-            # fetch; a dead runtime stalls the enqueue)
+            # below — both are the hang shapes (a hung device dispatch
+            # stalls the fetch; a dead runtime stalls the enqueue)
             self.watchdog.arm("runner.decode.step")
         t_disp0 = time.perf_counter()
         tok_d, fin_d, self._cache = self._step(

@@ -113,10 +113,8 @@ def _render_leaf(sig: Tuple) -> str:
 
 
 def _extract_cost(analysis) -> Optional[Dict[str, float]]:
-    """Normalize ``Compiled.cost_analysis()`` (a dict on new jax, a
-    one-element list of dicts on 0.4.x) to {flops, bytes_accessed}."""
-    if isinstance(analysis, (list, tuple)):
-        analysis = analysis[0] if analysis else None
+    """Reduce ``Compiled.cost_analysis()``'s dict to {flops,
+    bytes_accessed}."""
     if not isinstance(analysis, dict):
         return None
     out = {}
@@ -370,6 +368,14 @@ class InstrumentedJit:
     # a drop-in must still expose the AOT entry point some callers use
     def lower(self, *args, **kwargs):
         return self._jitted.lower(*args, **kwargs)
+
+    def clear_cache(self) -> None:
+        """``jax.jit``'s ``clear_cache()``: release every compiled
+        executable this wrapper holds (the next call recompiles and books
+        the compile again)."""
+        with self._lock:
+            self._entries.clear()
+        self._jitted.clear_cache()
 
     def __repr__(self):
         return (f"InstrumentedJit({self.name!r}, "

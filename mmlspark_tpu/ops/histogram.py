@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from ..observability.compute import instrumented_jit
+from ..utils.device import platform
 
 
 def build_histograms(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
@@ -160,17 +161,16 @@ def _node_pure_layout(binned, grad, hess, node_ids, num_nodes, R,
     # the one-hot cumsum materializes (n, P+1) transients — a candidate win
     # only while P is small (depth-5 level-wise peaks at P=16); wide-node
     # builds (deep trees, leaf-wise num_leaves buffers) always use the
-    # stable sort.  Default stays "sort" (the r4-measured baseline) until
-    # the on-chip A/B in bench_attempts/tune_r5.log proves cumsum faster —
-    # select it via MMLSPARK_TPU_HIST_LAYOUT=cumsum
+    # stable sort.  Default stays "sort" until an on-chip A/B proves cumsum
+    # faster (ROADMAP D2) — select it via MMLSPARK_TPU_HIST_LAYOUT=cumsum
     use_cumsum = (_os.environ.get("MMLSPARK_TPU_HIST_LAYOUT", "sort")
                   == "cumsum") and P + 1 <= 33
     if use_cumsum:
         # rank-by-cumulative-count: rows keep their original order within
         # each node, exactly like the stable argsort below, but the slot
         # comes from an exclusive prefix count over a (n, P+1) one-hot —
-        # P <= num_nodes is tiny, so 17 parallel prefix sums beat a full
-        # 1M-key sort on both CPU and TPU (tools/profile_gbdt.py)
+        # P <= num_nodes is tiny: 17 parallel prefix sums instead of a
+        # full 1M-key sort
         onehot_n = (node_s[:, None] == jnp.arange(P + 1)).astype(jnp.int32)
         inc = jnp.cumsum(onehot_n, axis=0)
         counts = inc[-1]
@@ -650,51 +650,36 @@ def build_histograms_matmul_quantized(binned: jnp.ndarray, qg: jnp.ndarray,
     return jnp.moveaxis(hist, 0, -1)                                   # (P,F,B,3)
 
 
-def _pallas_pref():
-    """``MMLSPARK_TPU_HIST_PALLAS`` hatch: 1/true forces the fused Pallas
-    backend into the auto choice on ANY platform (interpret mode off-TPU),
-    0/false keeps auto off it, unset = auto-select on TPU only.  Explicit
-    ``backend=``/``MMLSPARK_TPU_HIST_BACKEND`` settings always win."""
-    import os
-    raw = os.environ.get("MMLSPARK_TPU_HIST_PALLAS", "").strip().lower()
-    if raw in ("0", "false", "off", "no"):
-        return False
-    if raw in ("1", "true", "on", "yes"):
-        return True
-    return None
+def xla_backend() -> str:
+    """The XLA builder family for this platform, float or quantized:
+    ``scatter`` on CPU (where one-hot matmuls lose), ``matmul`` (the MXU
+    build) on TPU."""
+    return "scatter" if platform() == "cpu" else "matmul"
 
 
 def resolve_quantized_backend(backend: str = "auto") -> str:
     """Resolve the quantized-build backend the way ``build_quantized``
     will: explicit caller choice > ``MMLSPARK_TPU_HIST_BACKEND`` env >
-    platform auto (TPU -> the fused Pallas kernel unless the
-    ``MMLSPARK_TPU_HIST_PALLAS=0`` hatch says otherwise; CPU -> scatter;
-    other accelerators -> matmul).  The growers call this at trace time to
-    decide whether the fused frontier path engages — the env knobs are part
-    of every jit cache key (``lightgbm.core._resolve_hist_backend``)."""
+    platform auto (CPU -> scatter, TPU -> matmul).  The fused Pallas kernel
+    is never an auto choice: the Pallas TPU lowering refuses its block
+    shapes (ROADMAP D2), so ``pallas`` is by explicit request only — under
+    the interpreter on CPU, compiled-or-raising on TPU.  The growers call
+    this at trace time to decide whether the fused frontier path engages —
+    the env knobs are part of every jit cache key
+    (``lightgbm.core._resolve_hist_backend``)."""
     import os
     if backend == "auto":
         backend = os.environ.get("MMLSPARK_TPU_HIST_BACKEND", "auto")
     if backend != "auto":
         return backend
-    pref = _pallas_pref()
-    if pref is True:
-        return "pallas"
-    plat = jax.default_backend()
-    if plat == "cpu":
-        return "scatter"
-    if plat == "tpu" and pref is not False:
-        return "pallas"
-    return "matmul"
+    return xla_backend()
 
 
 def build_quantized(binned, qg, qh, node_ids, num_nodes, num_bins,
                     quant_bins: int = 16, backend: str = "auto",
                     max_rows=None, node_rows_bound=None):
     """Quantized-path backend dispatcher, mirroring ``build``: 'auto' picks
-    the fused Pallas kernel on TPU (``MMLSPARK_TPU_HIST_PALLAS=0/1``
-    hatch; interpret mode everywhere else), the int8 MXU build on other
-    accelerators and the packed int32 scatter on CPU;
+    the int8 MXU build on TPU and the packed int32 scatter on CPU;
     ``MMLSPARK_TPU_HIST_BACKEND`` overrides only when the caller did not
     request a specific backend.  Returns int32 (nodes, F, B, 3)
     [sum_qg, sum_qh, count] — rescale with ``dequantize_histogram``."""
@@ -710,7 +695,7 @@ def build_quantized(binned, qg, qh, node_ids, num_nodes, num_bins,
         # clean fallback: unsupported shape (bins/quant range, or a node
         # frontier wider than the kernel's VMEM node cap — deep-level/
         # sharded/streamed builds) -> the XLA builders
-        backend = "scatter" if jax.default_backend() == "cpu" else "matmul"
+        backend = xla_backend()
     if backend == "matmul":
         kw = {}
         block_rows = int(os.environ.get("MMLSPARK_TPU_HIST_BLOCK_ROWS", "0"))
@@ -744,7 +729,7 @@ def build(binned, grad, hess, node_ids, num_nodes, num_bins,
         backend = os.environ.get("MMLSPARK_TPU_HIST_BACKEND", backend)
         # not request a specific backend (ADVICE r2)
     if backend in ("auto", "pallas"):
-        backend = "scatter" if jax.default_backend() == "cpu" else "matmul"
+        backend = xla_backend()
     # MXU tuning knobs (read at trace time; train() keys its jit caches on
     # them): block size, lo one-hot width, residual channels on/off
     block_rows = int(os.environ.get("MMLSPARK_TPU_HIST_BLOCK_ROWS", "0")) or None

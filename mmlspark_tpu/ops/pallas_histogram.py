@@ -41,22 +41,36 @@ Accumulation modes (static, chosen per backend):
 
 Both modes accumulate exact integers, so outputs are bit-identical to
 ``build_histograms_quantized`` (tested across layouts, ragged tiles and
-streamed per-tile accumulation).  Interpret mode is the correctness
-contract this container can gate; the on-chip (Mosaic-compiled) number is
-recorded at the next TPU bench round (``bench.py phase_hist_ab`` fused arm
-runs the real kernel there; the round-5 retirement of the *float* Pallas
-histogram — Mosaic grad-channel drift, see PARITY.md — does not apply to
-this integer kernel, whose sums carry no rounding to drift).
+streamed per-tile accumulation) — under the Pallas INTERPRETER, which is
+the only way this kernel has ever run.
 
-VMEM tile-sizing rule (docs/lightgbm.md): with row tile R, feature block
-FB, N frontier nodes and C lane channels, the resident set is the binned
-tile (R*FB bytes), the one-hot operands (R*FB*(LO + N*C*HI) operand
-bytes), and the accumulator (C*N*FB*B*4 bytes); the compiled default
-R=1024, FB=8 keeps the sum (double-buffered) well under the 16 MB VMEM
-budget up to N=16 frontier nodes at B=256.  Interpret mode uses large
-tiles (R = (1<<23)/F — the XLA scatter builder's chunk rule, FB=F): the
-grid is a while_loop, so fewer/fatter steps win, while the rule keeps
-the per-step scatter intermediate at ~32 MB.
+Status on the TPU (v5e, jax 0.9.0, PR 22 chip run): the kernel does not
+compile, so it is never an ``auto`` choice (``resolve_quantized_backend``);
+an explicit ``backend="pallas"`` on a TPU raises.  The toolchain's words:
+
+- default tiles (R=1024, FB=8): "The Pallas TPU lowering currently
+  requires that the last two dimensions of your block shape are divisible
+  by 8 and 128 respectively, or be equal to the respective dimensions of
+  the overall array" — the ``(R, FB)`` binned block;
+- ``feat_block=F`` (full lane dimension), builder mode: "Mosaic failed to
+  compile TPU kernel: infer-vector-layout: unsupported shape cast …
+  tpu.reshape (vector<1024x32xi1>) -> vector<1024x32x1xi1>";
+- fused mode: "Unimplemented primitive in Pallas TPU lowering for
+  KernelType.TC: cumsum".
+
+ROADMAP D2 holds the redesign-or-delete decision.
+
+VMEM tile-sizing rule — NOT validated by any compiler: with row tile R,
+feature block FB, N frontier nodes and C lane channels, the rule counts
+the binned tile (R*FB bytes), the one-hot operands (R*FB*(LO + N*C*HI)
+operand bytes), and the accumulator (C*N*FB*B*4 bytes) at R=1024, FB=8.
+It undercounts the ``(n_out, FB, B, 3)`` int32 output block: a minor
+dimension of 3 is padded to 128 lanes in VMEM, so a (32, 8, 256, 3) block
+holds ~33 MB per buffer, not the ~0.8 MB the rule counts.  A redesign
+needs bins (not channels) minor.  Interpret mode uses large tiles
+(R = (1<<23)/F — the XLA scatter builder's chunk rule, FB=F): the grid is
+a while_loop, so fewer/fatter steps win, while the rule keeps the
+per-step scatter intermediate at ~32 MB.
 
 Split-gain contract: the in-kernel scan mirrors the growers' gain math
 (dequantize -> f32 bin cumsum -> leaf_score with l1/l2 ->
@@ -76,34 +90,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils.device import platform
 from .histogram import _pack_lanes, _packed_layout, _unpack_lanes
 
 _CHANNELS = {"all3": 1, "2ch": 2, "wide": 3}
 _LO = 16  # lo one-hot width of the onehot accumulation mode
 
-#: max frontier nodes (the kernel's N) the VMEM tile-sizing rule holds
-#: for at the compiled defaults (R=1024, FB=8, B<=256): the per-block
-#: resident set — (2N, FB, B, 3) hist out, (N, FB, B, 3) parent,
-#: (C, N, FB, B) scratch — scales linearly with N and clears the 16 MB
-#: budget up to here.  The level-wise grower statically falls back to
-#: the XLA scan for deeper levels (interpret mode enforces the same cap
-#: so tier-1 exercises exactly what the compiled path runs).
+#: max frontier nodes (the kernel's N) the fused path accepts; the
+#: level-wise grower statically falls back to the XLA scan for deeper
+#: levels.  Sized from the module docstring's VMEM rule, which no compiler
+#: has validated (see "Status on the TPU").
 FUSED_MAX_NODES = 16
 
 
 def builder_node_cap(num_bins: int) -> int:
-    """Max ``num_nodes`` the BUILDER path clears the VMEM budget for at the
-    compiled defaults (FB=8): per feature block the resident set is the
-    double-buffered ``(N, FB, B, 3)`` int32 output plus the ``(C<=3, N,
-    FB, B)`` int32 scratch accumulator — 36·FB·B bytes per node — and a
-    12 MiB slice of the 16 MiB budget leaves headroom for the input
-    blocks.  ``FUSED_MAX_NODES`` gates the growers' fused-frontier calls;
-    this cap gates everything else reaching ``build_histograms_pallas``
-    through the dispatcher (deep-level, sharded and streamed builds pass
-    frontier widths up to 2^(D-1) nodes), which falls back to the XLA
-    builders above it.  Static, platform-independent: interpret mode
-    enforces the same cap so tier-1 exercises the exact dispatch the
-    compiled path takes."""
+    """Max ``num_nodes`` the BUILDER path accepts: 12 MiB over the module
+    docstring's 36·FB·B bytes-per-node rule at FB=8 (a rule no compiler
+    has validated — it ignores the lane padding of the 3-wide minor
+    dimension).  ``FUSED_MAX_NODES`` gates the growers' fused-frontier
+    calls; this cap gates everything else reaching
+    ``build_histograms_pallas`` through the dispatcher (deep-level,
+    sharded and streamed builds pass frontier widths up to 2^(D-1) nodes),
+    which falls back to the XLA builders above it.  Static and
+    platform-independent."""
     return max(1, (12 << 20) // (36 * 8 * num_bins))
 
 
@@ -119,9 +128,9 @@ def pallas_supported(num_bins: int, quant_bins: int = 16,
 
 
 def _interpret_default() -> bool:
-    # the compiled (Mosaic) path is TPU-only; everything else runs the
-    # kernel under the Pallas interpreter, which lowers to plain XLA
-    return jax.default_backend() != "tpu"
+    # the Pallas interpreter (plain XLA) is the CPU path only: on a TPU the
+    # kernel is handed to Mosaic, and platform() raises on anything else
+    return platform() == "cpu"
 
 
 def _plan(n: int, F: int, interpret: bool,
@@ -314,9 +323,7 @@ def _frontier(binned, qg, qh, node_ids, num_nodes, num_bins, *, quant_bins,
                          f"<= 256 and quant_bins <= 128, got ({B}, "
                          f"{quant_bins})")
     if gains and N > FUSED_MAX_NODES:
-        # the builder path has its own cap (builder_node_cap); the fused
-        # path's VMEM rule is only sized up to FUSED_MAX_NODES — past it
-        # the compiled kernel would surface an opaque Mosaic OOM instead
+        # the builder path has its own cap (builder_node_cap)
         raise ValueError(
             f"fused_frontier VMEM node cap exceeded: {N} frontier nodes > "
             f"FUSED_MAX_NODES={FUSED_MAX_NODES} — callers must fall back "
@@ -404,7 +411,7 @@ def _frontier(binned, qg, qh, node_ids, num_nodes, num_bins, *, quant_bins,
 
     kw = {}
     if not interpret:
-        kw["compiler_params"] = pltpu.TPUCompilerParams(
+        kw["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"))
     # pallas-site: compiled inside the growers'/bench's instrumented_jit
     # programs — compile booking rides lightgbm.grower/iter/multi_iter

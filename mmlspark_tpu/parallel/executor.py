@@ -85,7 +85,9 @@ def run_local_cluster(fn: Callable, num_processes: int = 2,
                       timeout_s: float = 300.0) -> List:
     """Run fn(mesh, process_id) in `num_processes` REAL separate processes
     forming one global mesh of num_processes*devices_per_process CPU devices.
-    Returns each process's pickled result."""
+    CPU-only by construction (the worker template pins JAX_PLATFORMS=cpu),
+    so a parent that holds the chip can spawn it safely.  Returns each
+    process's pickled result."""
     from ..utils import pickling
 
     configs = make_cluster_configs(num_processes, devices_per_process,

@@ -114,7 +114,7 @@ class StatusStormInjector(ChaosInjector):
 class FlakyLoadInjector(ChaosInjector):
     """Compute-plane twin of the HTTP injectors: wraps a prefetcher
     ``load_fn`` and makes it raise a transient error on a seeded coin —
-    the tile-load failure class (flaky storage, wedged device relay) the
+    the tile-load failure class (flaky storage, a hung device dispatch) the
     ``TilePrefetcher`` retry exists for.  ``max_injections`` bounds the
     total faults so a high rate cannot exhaust a bounded retry budget by
     pure bad luck; ``exc_factory`` picks the failure shape (default: a
@@ -149,7 +149,7 @@ class FlakyLoadInjector(ChaosInjector):
 
 class HungLoadInjector:
     """The failure the retry CANNOT see: a tile load that never returns
-    (NFS server gone away mid-read, wedged device relay holding the
+    (NFS server gone away mid-read, a hung device dispatch holding the
     transfer lock).  No exception is raised, so ``FlakyLoadInjector``'s
     retry path never engages — the prefetch worker just blocks, the
     consumer's tick stream freezes, and only the ISSUE 19 stall watchdog
@@ -349,7 +349,7 @@ class ElasticTopologyDrill:
 
 class HungWorkerInjector:
     """A worker that accepts connections and never replies — the SLOW
-    failure class (hung XLA dispatch, wedged TPU relay) the tail-tolerance
+    failure class (a hung device dispatch) the tail-tolerance
     layer exists for (ISSUE 16).  Unlike :class:`WorkerKiller`'s crash, a
     hung worker keeps its socket OPEN: a connect succeeds, the request is
     swallowed, and without hedging/timeouts the client slot is tied up

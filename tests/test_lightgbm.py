@@ -263,6 +263,27 @@ def test_chunked_training_matches_unchunked(monkeypatch):
     assert acc_c > 0.9 and acc_p > 0.9, (acc_c, acc_p)
 
 
+def test_chunked_program_is_not_stale_across_train_calls(monkeypatch):
+    """The chunked program is cached by (params, shape): a second train()
+    at the same shape must learn ITS data, not the first call's (the data
+    used to be closed over and baked into the cached program — the TPU
+    default path, found by PR 22's chip bring-up)."""
+    monkeypatch.setenv("MMLSPARK_TPU_GBDT_CHUNK", "4")
+    from mmlspark_tpu.lightgbm import core as gbdt_core
+    rng = np.random.default_rng(0)
+    n, f = 50_000, 6
+    X1 = rng.standard_normal((n, f)).astype(np.float32)
+    X2 = rng.standard_normal((n, f)).astype(np.float32)
+    y1 = (X1[:, 0] > 0).astype(np.float32)
+    y2 = (X2[:, 3] > 0).astype(np.float32)      # a different signal feature
+    p = gbdt_core.GBDTParams(num_iterations=8, objective="binary",
+                             max_depth=3, seed=3)
+    gbdt_core.train(X1, y1, p)
+    second = gbdt_core.train(X2, y2, p).booster
+    assert (second.split_feature[:, 0] == 3).all(), second.split_feature[:, 0]
+    assert ((second.predict(X2) > 0.5) == (y2 > 0.5)).mean() > 0.95
+
+
 def test_tree_shap_exact_vs_bruteforce():
     """Path-dependent TreeSHAP must match brute-force Shapley values computed
     from the tree's conditional expectations over all feature subsets."""

@@ -282,36 +282,50 @@ def test_fused_frontier_masks_and_depth_gate():
     assert bool(jnp.all(bf2 == 0)) and bool(jnp.all(bb2 == 0))
 
 
-# ------------------------------------------------- dispatcher and hatch
+# ------------------------------------------------------------ dispatcher
 
-def test_backend_resolution_and_pallas_hatch(monkeypatch):
-    from mmlspark_tpu.ops.histogram import resolve_quantized_backend
+def test_backend_resolution(monkeypatch):
+    from mmlspark_tpu.ops import histogram as H
     monkeypatch.delenv("MMLSPARK_TPU_HIST_BACKEND", raising=False)
-    monkeypatch.delenv("MMLSPARK_TPU_HIST_PALLAS", raising=False)
-    # CPU auto stays on the scatter build — tier-1 defaults are unchanged
-    assert resolve_quantized_backend("auto") == "scatter"
-    # the hatch forces the fused kernel into the auto choice anywhere
-    # (interpret mode off-TPU); 0/off keeps auto off it
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_PALLAS", "1")
-    assert resolve_quantized_backend("auto") == "pallas"
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_PALLAS", " OFF ")
-    assert resolve_quantized_backend("auto") == "scatter"
-    # explicit choices always beat the hatch, either direction
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_PALLAS", "1")
-    assert resolve_quantized_backend("matmul") == "matmul"
+    # CPU auto stays on the scatter build; the TPU auto choice is the XLA
+    # int8 MXU build — the fused kernel is never chosen automatically (the
+    # Pallas TPU lowering refuses it, ROADMAP D2)
+    assert H.resolve_quantized_backend("auto") == "scatter"
+    monkeypatch.setattr(H, "platform", lambda: "tpu")
+    assert H.resolve_quantized_backend("auto") == "matmul"
+    # the kernel stays reachable by explicit request, argument or env
+    assert H.resolve_quantized_backend("pallas") == "pallas"
     monkeypatch.setenv("MMLSPARK_TPU_HIST_BACKEND", "pallas")
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_PALLAS", "0")
-    assert resolve_quantized_backend("auto") == "pallas"
+    assert H.resolve_quantized_backend("auto") == "pallas"
+    # an explicit argument beats the env
+    assert H.resolve_quantized_backend("matmul") == "matmul"
 
 
-def test_hatch_is_part_of_the_jit_cache_key(monkeypatch):
+def test_interpreter_is_the_cpu_path_only(monkeypatch):
+    """On a TPU the kernel is handed to the compiler (compile or raise,
+    never interpret); a third, unknown platform is an error."""
+    import jax
+    from mmlspark_tpu.ops import pallas_histogram as PH
+    from mmlspark_tpu.utils import device
+    assert PH._interpret_default() is True            # tier-1 runs on CPU
+    monkeypatch.setattr(PH, "platform", lambda: "tpu")
+    assert PH._interpret_default() is False
+    monkeypatch.undo()
+    monkeypatch.setattr(jax, "default_backend", lambda: "mystery")
+    with pytest.raises(RuntimeError, match="unsupported JAX platform"):
+        device.platform()
+    with pytest.raises(RuntimeError, match="unsupported JAX platform"):
+        PH._interpret_default()
+
+
+def test_backend_env_is_part_of_the_jit_cache_key(monkeypatch):
     """Every histogram env knob must key the growers' jit caches — a
     cached program must never keep serving a previously-selected
     configuration (the _resolve_hist_backend contract)."""
     from mmlspark_tpu.lightgbm.core import _resolve_hist_backend
-    monkeypatch.delenv("MMLSPARK_TPU_HIST_PALLAS", raising=False)
+    monkeypatch.delenv("MMLSPARK_TPU_HIST_BACKEND", raising=False)
     base = _resolve_hist_backend()
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_PALLAS", "1")
+    monkeypatch.setenv("MMLSPARK_TPU_HIST_BACKEND", "pallas")
     assert _resolve_hist_backend() != base
 
 
@@ -479,29 +493,6 @@ def test_streamed_training_identical_across_backends(monkeypatch):
     np.testing.assert_array_equal(a.split_feature, b.split_feature)
     np.testing.assert_array_equal(a.threshold_bin, b.threshold_bin)
     np.testing.assert_array_equal(a.leaf_value, b.leaf_value)
-
-
-# ------------------------------------------------------------- slow lane
-
-@pytest.mark.slow
-@pytest.mark.pallas
-def test_fused_kernel_on_chip_bit_exact():
-    """The compiled (Mosaic) kernel on a real TPU must match the
-    interpret-mode sums bit for bit — the on-chip gate for the next TPU
-    bench round (tier-1 is CPU-only; this runs under the `pallas`
-    marker)."""
-    import jax
-    if jax.default_backend() != "tpu":
-        pytest.skip("needs a real TPU (compiled Mosaic path)")
-    import jax.numpy as jnp
-    from mmlspark_tpu.ops import histogram as H
-    from mmlspark_tpu.ops import pallas_histogram as PH
-    binned, g, h, node = _hist_inputs(n=100_000, f=32, seed=0)
-    qg, qh, _, _ = H.quantize_gradients(g, h, 16, seed=3)
-    compiled = PH.build_histograms_pallas(binned, qg, qh, node, 8, 255,
-                                          interpret=False)
-    ref = H.build_histograms_quantized(binned, qg, qh, node, 8, 255)
-    assert bool(jnp.all(compiled == ref))
 
 
 def _split(X, y, seed=5):
