@@ -50,6 +50,17 @@ from ..ops.histogram import build_histograms, xla_backend
 from ..utils.device import platform
 from .binning import BinMapper
 
+#: The device phases of one boosting iteration: the ``jax.named_scope`` names
+#: the grower programs carry, written here and nowhere else.  A scope is
+#: metadata on the lowered operations (it adds none and changes no fusion);
+#: a profiler trace shows it in each operation's path, inside an ``L<d>``
+#: scope per tree level, and ``benchmark/phase_times.py`` reads device
+#: seconds per phase from that (the ``gbdt.*_ms_per_iter`` metrics).
+DEVICE_PHASES = ("gbdt.grad", "gbdt.quantize", "gbdt.layout", "gbdt.hist",
+                 "gbdt.allreduce", "gbdt.split", "gbdt.route", "gbdt.update")
+(PHASE_GRAD, PHASE_QUANTIZE, PHASE_LAYOUT, PHASE_HIST, PHASE_ALLREDUCE,
+ PHASE_SPLIT, PHASE_ROUTE, PHASE_UPDATE) = DEVICE_PHASES
+
 
 @dataclasses.dataclass
 class GBDTParams:
@@ -519,28 +530,36 @@ def make_tree_grower(max_depth: int, num_features: int, num_bins: int,
             # rounding noise is keyed per GLOBAL row (elastic resume,
             # ISSUE 14): a row quantizes identically at any shard count,
             # which is what makes resume onto a re-sized mesh bit-exact.
-            row_ids = hist_ops.global_row_ids(axis_name, n)
-            qg, qh, g_scale, h_scale = hist_ops.quantize_gradients(
-                grad, hess, quant_bins, seed=params.seed, axis_name=axis_name,
-                row_ids=row_ids)
+            with jax.named_scope(PHASE_QUANTIZE):
+                row_ids = hist_ops.global_row_ids(axis_name, n)
+                qg, qh, g_scale, h_scale = hist_ops.quantize_gradients(
+                    grad, hess, quant_bins, seed=params.seed,
+                    axis_name=axis_name, row_ids=row_ids)
 
         def build_local(node_a, num_nodes, max_rows=None):
-            if use_quant:
-                return hist_ops.build_quantized(
-                    binned, qg, qh, node_a, num_nodes, num_bins,
-                    quant_bins=quant_bins, backend=backend,
-                    max_rows=max_rows, node_rows_bound=max_rows)
-            return hist_ops.build(binned, grad, hess, node_a, num_nodes,
-                                  num_bins, backend=backend,
-                                  max_rows=max_rows)
+            # (the builders put their sort of the rows into node-pure blocks
+            # under PHASE_LAYOUT themselves)
+            with jax.named_scope(PHASE_HIST):
+                if use_quant:
+                    return hist_ops.build_quantized(
+                        binned, qg, qh, node_a, num_nodes, num_bins,
+                        quant_bins=quant_bins, backend=backend,
+                        max_rows=max_rows, node_rows_bound=max_rows)
+                return hist_ops.build(binned, grad, hess, node_a, num_nodes,
+                                      num_bins, backend=backend,
+                                      max_rows=max_rows)
+
+        def allreduce(h_):
+            with jax.named_scope(PHASE_ALLREDUCE):
+                return histogram_psum(h_, axis_name,
+                                      row_bound=psum_row_bound,
+                                      quant_bins=quant_bins) \
+                    if use_quant else jax.lax.psum(h_, axis_name)
 
         def hist(node_a, num_nodes, max_rows=None):
             out = build_local(node_a, num_nodes, max_rows=max_rows)
             if axis_name is not None:
-                out = histogram_psum(out, axis_name,
-                                     row_bound=psum_row_bound,
-                                     quant_bins=quant_bins) \
-                    if use_quant else jax.lax.psum(out, axis_name)
+                out = allreduce(out)
             return out
 
         def dehist(h_):
@@ -549,7 +568,8 @@ def make_tree_grower(max_depth: int, num_features: int, num_bins: int,
             # subtraction chain
             if not use_quant:
                 return h_
-            return hist_ops.dequantize_histogram(h_, g_scale, h_scale)
+            with jax.named_scope(PHASE_HIST):
+                return hist_ops.dequantize_histogram(h_, g_scale, h_scale)
 
         node = jnp.zeros((n,), jnp.int32)          # level-local node, all rows
         split_feature = jnp.full((I,), -1, jnp.int32)
@@ -619,6 +639,9 @@ def make_tree_grower(max_depth: int, num_features: int, num_bins: int,
         best_stats = None
         small_left = None      # set per level; read from the NEXT level on
         for d in range(D):
+          # one scope per level outside the phase scopes: an operation's
+          # path reads .../L3/gbdt.hist/dot_general
+          with jax.named_scope(f"L{d}"):
             nodes_d = 2 ** d
             off = nodes_d - 1                       # BFS offset of this level
             if d > 0 and not use_voting:
@@ -626,9 +649,10 @@ def make_tree_grower(max_depth: int, num_features: int, num_bins: int,
                 # split counts): rebuild only each parent's smaller child,
                 # sibling = parent - small.  One definition serving both
                 # the fused-kernel and XLA frontier paths below.
-                is_left = node % 2 == 0
-                in_small = is_left == small_left[node // 2]
-                small_node = jnp.where(hist_mask & in_small, node // 2, -1)
+                with jax.named_scope(PHASE_HIST):
+                    is_left = node % 2 == 0
+                    in_small = is_left == small_left[node // 2]
+                    small_node = jnp.where(hist_mask & in_small, node // 2, -1)
             fused_d = False        # set by the fused branch when it engages
             if use_voting:
                 # voting-parallel (reference voting_parallel + topK): each
@@ -637,40 +661,44 @@ def make_tree_grower(max_depth: int, num_features: int, num_bins: int,
                 # O(k*B) comm instead of O(F*B).  Sibling subtraction stays
                 # valid on the PRE-psum local histograms (local_right =
                 # local_parent - local_left).
-                if d == 0:
-                    local = build_local(jnp.where(hist_mask, node, -1), 1)
-                else:
-                    left_node = jnp.where(hist_mask & (node % 2 == 0),
-                                          node // 2, -1)
-                    left_local = build_local(left_node, nodes_d // 2)
-                    local = jnp.stack([left_local, prev_hist - left_local],
-                                      axis=1).reshape(nodes_d, F, B, 3)
+                with jax.named_scope(PHASE_HIST):
+                    if d == 0:
+                        local = build_local(jnp.where(hist_mask, node, -1), 1)
+                    else:
+                        left_node = jnp.where(hist_mask & (node % 2 == 0),
+                                              node // 2, -1)
+                        left_local = build_local(left_node, nodes_d // 2)
+                        local = jnp.stack(
+                            [left_local, prev_hist - left_local],
+                            axis=1).reshape(nodes_d, F, B, 3)
                 prev_hist = local
-                gain_l, _, _ = split_gains(dehist(local), feat_mask[None, :],
-                                           edge_finite, cat_b[None, :],
-                                           sub_b[None, :])
-                per_feat = gain_l.max(axis=2)        # (nodes, F) local best
-                top_gain, top_local = jax.lax.top_k(per_feat, voting_k)
-                # a shard with fewer than k locally-valid candidates must not
-                # cast spurious ballots for the tie-broken low-index features
-                ballot = (top_gain > -jnp.inf).astype(jnp.float32)
-                votes = jnp.zeros((nodes_d, F)).at[
-                    jnp.arange(nodes_d)[:, None], top_local].add(ballot)
-                votes = jax.lax.psum(votes, axis_name)
+                with jax.named_scope(PHASE_SPLIT):
+                    gain_l, _, _ = split_gains(
+                        dehist(local), feat_mask[None, :], edge_finite,
+                        cat_b[None, :], sub_b[None, :])
+                    per_feat = gain_l.max(axis=2)    # (nodes, F) local best
+                    top_gain, top_local = jax.lax.top_k(per_feat, voting_k)
+                    # a shard with fewer than k locally-valid candidates must
+                    # not cast spurious ballots for the tie-broken low-index
+                    # features
+                    ballot = (top_gain > -jnp.inf).astype(jnp.float32)
+                    votes = jnp.zeros((nodes_d, F)).at[
+                        jnp.arange(nodes_d)[:, None], top_local].add(ballot)
+                with jax.named_scope(PHASE_ALLREDUCE):
+                    votes = jax.lax.psum(votes, axis_name)
                 k2 = min(2 * voting_k, F)
-                _, sel = jax.lax.top_k(votes, k2)    # (nodes, k2) global pick
-                sel_hist = jnp.take_along_axis(
-                    local, sel[:, :, None, None], axis=1)
-                sel_hist = histogram_psum(sel_hist, axis_name,
-                                          row_bound=psum_row_bound,
-                                          quant_bins=quant_bins) \
-                    if use_quant else jax.lax.psum(sel_hist, axis_name)
-                sel_hist = dehist(sel_hist)
-                edge3 = jnp.take_along_axis(
-                    jnp.broadcast_to(edge_finite, (nodes_d, F, B)),
-                    sel[:, :, None], axis=1)
-                gain, pick, (Gp0, Hp0, Cp0) = split_gains(
-                    sel_hist, feat_mask[sel], edge3, cat_b[sel], sub_b[sel])
+                with jax.named_scope(PHASE_SPLIT):
+                    _, sel = jax.lax.top_k(votes, k2)  # (nodes, k2) global pick
+                    sel_hist = jnp.take_along_axis(
+                        local, sel[:, :, None, None], axis=1)
+                sel_hist = dehist(allreduce(sel_hist))
+                with jax.named_scope(PHASE_SPLIT):
+                    edge3 = jnp.take_along_axis(
+                        jnp.broadcast_to(edge_finite, (nodes_d, F, B)),
+                        sel[:, :, None], axis=1)
+                    gain, pick, (Gp0, Hp0, Cp0) = split_gains(
+                        sel_hist, feat_mask[sel], edge3, cat_b[sel],
+                        sub_b[sel])
                 hist_for_win = sel_hist
                 Fs = k2
             elif use_fused and max(1, nodes_d // 2) <= \
@@ -683,28 +711,32 @@ def make_tree_grower(max_depth: int, num_features: int, num_bins: int,
                 # past FUSED_MAX_NODES frontier parents the kernel's
                 # VMEM-resident blocks outgrow the tile-sizing budget, so
                 # deeper levels take the XLA branch below (bit-exact
-                # histograms; gains differ only by f32 cumsum rounding)
+                # histograms; gains differ only by f32 cumsum rounding).
+                # One kernel, so one phase: it is booked to the histogram.
                 fused_d = True
-                if d == 0:
-                    hist_d, fused_best = pl_hist.fused_frontier(
-                        binned, qg, qh, jnp.where(hist_mask, node, -1), 1,
-                        B, g_scale, h_scale, feat_mask, edge_ok2,
-                        quant_bins=quant_bins, l1=l1, l2=l2,
-                        min_data=min_data, min_hess=min_hess)
-                else:
-                    hist_d, fused_best = pl_hist.fused_frontier(
-                        binned, qg, qh, small_node, nodes_d // 2, B,
-                        g_scale, h_scale, feat_mask, edge_ok2,
-                        quant_bins=quant_bins, l1=l1, l2=l2,
-                        min_data=min_data, min_hess=min_hess,
-                        parent_hist=prev_hist, small_left=small_left,
-                        node_rows_bound=n // 2 + nodes_d)
+                with jax.named_scope(PHASE_HIST):
+                    if d == 0:
+                        hist_d, fused_best = pl_hist.fused_frontier(
+                            binned, qg, qh, jnp.where(hist_mask, node, -1), 1,
+                            B, g_scale, h_scale, feat_mask, edge_ok2,
+                            quant_bins=quant_bins, l1=l1, l2=l2,
+                            min_data=min_data, min_hess=min_hess)
+                    else:
+                        hist_d, fused_best = pl_hist.fused_frontier(
+                            binned, qg, qh, small_node, nodes_d // 2, B,
+                            g_scale, h_scale, feat_mask, edge_ok2,
+                            quant_bins=quant_bins, l1=l1, l2=l2,
+                            min_data=min_data, min_hess=min_hess,
+                            parent_hist=prev_hist, small_left=small_left,
+                            node_rows_bound=n // 2 + nodes_d)
                 prev_hist = hist_d
                 best_gain, bf, bb, bsel, tot3f = fused_best
                 Gp0, Hp0, Cp0 = tot3f[:, 0], tot3f[:, 1], tot3f[:, 2]
             else:
                 if d == 0:
-                    hist_d = hist(jnp.where(hist_mask, node, -1), 1)
+                    with jax.named_scope(PHASE_HIST):
+                        root_node = jnp.where(hist_mask, node, -1)
+                    hist_d = hist(root_node, 1)
                 else:
                     # smaller-child scatter (small_node above): at most
                     # floor(n/2) rows are ever scattered, which — single-
@@ -714,79 +746,91 @@ def make_tree_grower(max_depth: int, num_features: int, num_bins: int,
                     # children, so no bound is claimed there).
                     cap = None if axis_name is not None else n // 2 + nodes_d
                     hist_small = hist(small_node, nodes_d // 2, max_rows=cap)
-                    hist_sib = prev_hist - hist_small
-                    sl4 = small_left[:, None, None, None]
-                    hist_d = jnp.stack(
-                        [jnp.where(sl4, hist_small, hist_sib),
-                         jnp.where(sl4, hist_sib, hist_small)], axis=1) \
-                        .reshape(nodes_d, F, B, 3)
+                    with jax.named_scope(PHASE_HIST):
+                        hist_sib = prev_hist - hist_small
+                        sl4 = small_left[:, None, None, None]
+                        hist_d = jnp.stack(
+                            [jnp.where(sl4, hist_small, hist_sib),
+                             jnp.where(sl4, hist_sib, hist_small)], axis=1) \
+                            .reshape(nodes_d, F, B, 3)
                 prev_hist = hist_d
-                gain, pick, (Gp0, Hp0, Cp0) = split_gains(
-                    dehist(hist_d), feat_mask[None, :], edge_finite,
-                    cat_b[None, :], sub_b[None, :])
+                with jax.named_scope(PHASE_SPLIT):
+                    gain, pick, (Gp0, Hp0, Cp0) = split_gains(
+                        dehist(hist_d), feat_mask[None, :], edge_finite,
+                        cat_b[None, :], sub_b[None, :])
                 hist_for_win = dehist(hist_d)
                 sel = None
                 Fs = F
 
-            if not fused_d:
-                flat = gain.reshape(nodes_d, Fs * B)
-                best = jnp.argmax(flat, axis=1)
-                best_gain = jnp.take_along_axis(flat, best[:, None],
-                                                axis=1)[:, 0]
-                bf_local = (best // B).astype(jnp.int32)
-                bb = (best % B).astype(jnp.int32)
-                bf = sel[jnp.arange(nodes_d), bf_local] \
-                    if sel is not None else bf_local
-                bsel = pick[jnp.arange(nodes_d), bf_local, bb, :]  # left
-            do_split = best_gain > min_gain
+            with jax.named_scope(PHASE_SPLIT):
+                if not fused_d:
+                    flat = gain.reshape(nodes_d, Fs * B)
+                    best = jnp.argmax(flat, axis=1)
+                    best_gain = jnp.take_along_axis(flat, best[:, None],
+                                                    axis=1)[:, 0]
+                    bf_local = (best // B).astype(jnp.int32)
+                    bb = (best % B).astype(jnp.int32)
+                    bf = sel[jnp.arange(nodes_d), bf_local] \
+                        if sel is not None else bf_local
+                    bsel = pick[jnp.arange(nodes_d), bf_local, bb, :]  # left
+                do_split = best_gain > min_gain
 
-            idx = off + jnp.arange(nodes_d)
-            if has_cat:
-                member = winner_member(
-                    hist_for_win[jnp.arange(nodes_d), bf_local], bf, bb)
-                cat_member = cat_member.at[idx].set(
-                    member & do_split[:, None] & cat_b[bf][:, None])
-            split_feature = split_feature.at[idx].set(jnp.where(do_split, bf, -1))
-            threshold_bin = threshold_bin.at[idx].set(bb)
-            thr_raw = edges[bf, jnp.clip(bb, 0, B - 2)]
-            if has_cat:  # categorical: the raw threshold IS the category code
-                thr_raw = jnp.where(cat_b[bf], bb.astype(jnp.float32), thr_raw)
-            threshold = threshold.at[idx].set(thr_raw)
-            split_gain = split_gain.at[idx].set(jnp.where(do_split, best_gain, 0.0))
-            internal_value = internal_value.at[idx].set(leaf_output(Gp0, Hp0))
-            internal_count = internal_count.at[idx].set(Cp0)
+                idx = off + jnp.arange(nodes_d)
+                if has_cat:
+                    member = winner_member(
+                        hist_for_win[jnp.arange(nodes_d), bf_local], bf, bb)
+                    cat_member = cat_member.at[idx].set(
+                        member & do_split[:, None] & cat_b[bf][:, None])
+                split_feature = split_feature.at[idx].set(
+                    jnp.where(do_split, bf, -1))
+                threshold_bin = threshold_bin.at[idx].set(bb)
+                thr_raw = edges[bf, jnp.clip(bb, 0, B - 2)]
+                if has_cat:  # categorical: the raw threshold IS the category code
+                    thr_raw = jnp.where(cat_b[bf], bb.astype(jnp.float32),
+                                        thr_raw)
+                threshold = threshold.at[idx].set(thr_raw)
+                split_gain = split_gain.at[idx].set(
+                    jnp.where(do_split, best_gain, 0.0))
+                internal_value = internal_value.at[idx].set(
+                    leaf_output(Gp0, Hp0))
+                internal_count = internal_count.at[idx].set(Cp0)
 
-            # left/right child stats at the chosen split -> leaf values at the
-            # last level come straight from here (no extra leaf pass)
-            tot3 = jnp.stack([Gp0, Hp0, Cp0], axis=-1)
-            left_stats = jnp.where(do_split[:, None], bsel, tot3)
-            right_stats = tot3 - left_stats
-            best_stats = (left_stats, right_stats, do_split, tot3)
-            # the next level scatters only each parent's smaller child
-            # (unsplit parents: right is empty -> small, contributing 0 rows)
-            small_left = left_stats[:, 2] <= right_stats[:, 2]
+                # left/right child stats at the chosen split -> leaf values at
+                # the last level come straight from here (no extra leaf pass)
+                tot3 = jnp.stack([Gp0, Hp0, Cp0], axis=-1)
+                left_stats = jnp.where(do_split[:, None], bsel, tot3)
+                right_stats = tot3 - left_stats
+                best_stats = (left_stats, right_stats, do_split, tot3)
+                # the next level scatters only each parent's smaller child
+                # (unsplit parents: right is empty -> small, contributing 0
+                # rows)
+                small_left = left_stats[:, 2] <= right_stats[:, 2]
 
             # route all rows (bagged-out rows too: they need leaf ids for scores)
-            f_of_row = bf[node]
-            t_of_row = bb[node]
-            s_of_row = do_split[node]
-            row_bin = binned[jnp.arange(n), jnp.maximum(f_of_row, 0)].astype(jnp.int32)
-            if has_cat:
-                memb_row = member[node, row_bin]
-                right_dec = jnp.where(cat_b[jnp.maximum(f_of_row, 0)],
-                                      ~memb_row, row_bin > t_of_row)
-            else:
-                right_dec = row_bin > t_of_row
-            go_right = s_of_row & right_dec
-            node = 2 * node + go_right.astype(jnp.int32)
+            with jax.named_scope(PHASE_ROUTE):
+                f_of_row = bf[node]
+                t_of_row = bb[node]
+                s_of_row = do_split[node]
+                row_bin = binned[jnp.arange(n),
+                                 jnp.maximum(f_of_row, 0)].astype(jnp.int32)
+                if has_cat:
+                    memb_row = member[node, row_bin]
+                    right_dec = jnp.where(cat_b[jnp.maximum(f_of_row, 0)],
+                                          ~memb_row, row_bin > t_of_row)
+                else:
+                    right_dec = row_bin > t_of_row
+                go_right = s_of_row & right_dec
+                node = 2 * node + go_right.astype(jnp.int32)
 
         # leaves: children of the last level's nodes
-        left_stats, right_stats, do_split, tot3 = best_stats
-        lv = jnp.stack([leaf_output(left_stats[:, 0], left_stats[:, 1]),
-                        leaf_output(right_stats[:, 0], right_stats[:, 1])],
-                       axis=1).reshape(L)
-        lc = jnp.stack([left_stats[:, 2], right_stats[:, 2]], axis=1).reshape(L)
-        leaf_value = jnp.where(lc > 0, lv, 0.0)
+        with jax.named_scope(PHASE_UPDATE):
+            left_stats, right_stats, do_split, tot3 = best_stats
+            lv = jnp.stack([leaf_output(left_stats[:, 0], left_stats[:, 1]),
+                            leaf_output(right_stats[:, 0], right_stats[:, 1])],
+                           axis=1).reshape(L)
+            lc = jnp.stack([left_stats[:, 2], right_stats[:, 2]],
+                           axis=1).reshape(L)
+            leaf_value = jnp.where(lc > 0, lv, 0.0)
         return (lc_const, rc_const, split_feature, threshold, threshold_bin,
                 split_gain, internal_value, internal_count, leaf_value, lc,
                 cat_member, node)
@@ -1512,11 +1556,16 @@ def train(X: np.ndarray, y: np.ndarray, params: GBDTParams,
 
     # training-phase telemetry: per-iteration observations into the global
     # registry + ONE lightgbm.train span (child of the ambient fit span)
-    # carrying phase totals.  Timings are host-side dispatch+wait — no
-    # block_until_ready() syncs are inserted, the hot loop stays async.
+    # carrying phase totals.  Timings are on the host's clock round
+    # asynchronous dispatches — no block_until_ready() syncs are inserted,
+    # the hot loop stays async — so a device phase (histogram_split_update,
+    # gradients, histogram_split, update) reads the time to ENQUEUE it, not
+    # the device's; device time per phase is DEVICE_PHASES' in a trace.
     _phase_h = get_registry().histogram(
         "mmlspark_lightgbm_phase_seconds",
-        "per-iteration training phase timings (host-side)",
+        "per-iteration training phase timings on the host's clock: for a "
+        "device phase the enqueue time of an asynchronous dispatch, not "
+        "device time",
         labels=("phase", "backend", "quantized"))
     _phase_totals: Dict[str, float] = {}
 
@@ -1875,29 +1924,32 @@ def train(X: np.ndarray, y: np.ndarray, params: GBDTParams,
 
     def _iter_body(scores, y_d, w_d, binned_d, base_mask, feat_mask_d, edges_d,
                    grad_scale, new_w, key, g_pre, h_pre, use_pre):
-        if use_pre:
-            g, h = g_pre, h_pre
-        else:
-            g, h = objective(scores / grad_scale, y_d, w_d)
-        hist_mask = base_mask
-        if is_goss and not use_pre:
-            absg = jnp.abs(g).sum(axis=1)
-            order = jnp.argsort(-absg)
-            top_idx = order[:a_n]
-            rest = order[a_n:]
-            perm = jax.random.permutation(key, rest.shape[0])
-            small_idx = rest[perm[:b_n]]
-            mask = jnp.zeros((n,), bool).at[top_idx].set(True).at[small_idx].set(True)
-            amp = (1.0 - p.top_rate) / max(p.other_rate, 1e-12)
-            wamp = jnp.ones((n,)).at[small_idx].set(amp)
-            hist_mask = hist_mask & mask
-            g, h = g * wamp[:, None], h * wamp[:, None]
+        with jax.named_scope(PHASE_GRAD):
+            if use_pre:
+                g, h = g_pre, h_pre
+            else:
+                g, h = objective(scores / grad_scale, y_d, w_d)
+            hist_mask = base_mask
+            if is_goss and not use_pre:
+                absg = jnp.abs(g).sum(axis=1)
+                order = jnp.argsort(-absg)
+                top_idx = order[:a_n]
+                rest = order[a_n:]
+                perm = jax.random.permutation(key, rest.shape[0])
+                small_idx = rest[perm[:b_n]]
+                mask = jnp.zeros((n,), bool).at[top_idx].set(True) \
+                    .at[small_idx].set(True)
+                amp = (1.0 - p.top_rate) / max(p.other_rate, 1e-12)
+                wamp = jnp.ones((n,)).at[small_idx].set(amp)
+                hist_mask = hist_mask & mask
+                g, h = g * wamp[:, None], h * wamp[:, None]
         tree_out = []
         for c in range(K):
             lch, rch, sf, th, tb, sg, iv, ic, lv, lc, cbs, leaf = grow_fn(
                 binned_d, g[:, c], h[:, c], hist_mask, feat_mask_d, edges_d)
-            lv_s = lv * shrink_const
-            scores = scores.at[:, c].add(lv_s[leaf] * new_w)
+            with jax.named_scope(PHASE_UPDATE):
+                lv_s = lv * shrink_const
+                scores = scores.at[:, c].add(lv_s[leaf] * new_w)
             tree_out.append((lch, rch, sf, th, tb, sg, iv, ic, lv_s, lc, cbs))
         return scores, tree_out
 
@@ -1917,7 +1969,12 @@ def train(X: np.ndarray, y: np.ndarray, params: GBDTParams,
                           donate_argnums=(0,), name="lightgbm.iter_pre"))}
 
     import jax.random as jrandom
-    jit_objective = instrumented_jit(objective, name="lightgbm.objective") \
+    def _scoped_objective(scores, y_d, w_d):
+        with jax.named_scope(PHASE_GRAD):
+            return objective(scores, y_d, w_d)
+
+    jit_objective = instrumented_jit(_scoped_objective,
+                                     name="lightgbm.objective") \
         if objective is not None else None
     start_iter = len(tree_weights) // K
 
@@ -1949,38 +2006,44 @@ def train(X: np.ndarray, y: np.ndarray, params: GBDTParams,
         def body(data, carry, key):
             binned, y_dev, w_dev, edges = data
             scores_c, t = carry
-            kf, kb, kg = jrandom.split(key, 3)
-            feat_mask = jnp.ones((F,), bool)
-            if ff_on:
-                sel = jrandom.choice(kf, F, (keep,), replace=False)
-                feat_mask = jnp.zeros((F,), bool).at[sel].set(True)
-            base_mask = jnp.ones((n,), bool)
-            if bag_on:
-                base_mask = jrandom.uniform(kb, (n,)) < p.bagging_fraction
-            grad_scale = jnp.maximum(1.0, jnp.floor(t / K)) if rf_mode else 1.0
-            g, h = objective(scores_c / grad_scale, y_dev, w_dev)
-            hist_mask = base_mask
-            if is_goss:
-                absg = jnp.abs(g).sum(axis=1)
-                order = jnp.argsort(-absg)
-                top_idx = order[:a_n]
-                rest = order[a_n:]
-                perm = jrandom.permutation(kg, rest.shape[0])
-                small_idx = rest[perm[:b_n]]
-                mask = jnp.zeros((n,), bool).at[top_idx].set(True)                     .at[small_idx].set(True)
-                amp = (1.0 - p.top_rate) / max(p.other_rate, 1e-12)
-                wamp = jnp.ones((n,)).at[small_idx].set(amp)
-                hist_mask = hist_mask & mask
-                g, h = g * wamp[:, None], h * wamp[:, None]
+            with jax.named_scope(PHASE_GRAD):
+                kf, kb, kg = jrandom.split(key, 3)
+                feat_mask = jnp.ones((F,), bool)
+                if ff_on:
+                    sel = jrandom.choice(kf, F, (keep,), replace=False)
+                    feat_mask = jnp.zeros((F,), bool).at[sel].set(True)
+                base_mask = jnp.ones((n,), bool)
+                if bag_on:
+                    base_mask = jrandom.uniform(kb, (n,)) < p.bagging_fraction
+                grad_scale = jnp.maximum(1.0, jnp.floor(t / K)) \
+                    if rf_mode else 1.0
+                g, h = objective(scores_c / grad_scale, y_dev, w_dev)
+                hist_mask = base_mask
+                if is_goss:
+                    absg = jnp.abs(g).sum(axis=1)
+                    order = jnp.argsort(-absg)
+                    top_idx = order[:a_n]
+                    rest = order[a_n:]
+                    perm = jrandom.permutation(kg, rest.shape[0])
+                    small_idx = rest[perm[:b_n]]
+                    mask = jnp.zeros((n,), bool).at[top_idx].set(True) \
+                        .at[small_idx].set(True)
+                    amp = (1.0 - p.top_rate) / max(p.other_rate, 1e-12)
+                    wamp = jnp.ones((n,)).at[small_idx].set(amp)
+                    hist_mask = hist_mask & mask
+                    g, h = g * wamp[:, None], h * wamp[:, None]
             outs = []
             for c in range(K):
                 # chunked path excludes categoricals, so the bitset is a dummy
                 lch, rch, sf, th, tb, sg, iv, ic, lv, lc, _cbs, leaf = grow_fn(
                     binned, g[:, c], h[:, c], hist_mask, feat_mask, edges)
-                lv_s = lv * shrink_const
-                scores_c = scores_c.at[:, c].add(lv_s[leaf])
+                with jax.named_scope(PHASE_UPDATE):
+                    lv_s = lv * shrink_const
+                    scores_c = scores_c.at[:, c].add(lv_s[leaf])
                 outs.append((lch, rch, sf, th, tb, sg, iv, ic, lv_s, lc))
-            stacked = tuple(jnp.stack([o[j] for o in outs]) for j in range(10))
+            with jax.named_scope(PHASE_UPDATE):
+                stacked = tuple(jnp.stack([o[j] for o in outs])
+                                for j in range(10))
             return (scores_c, t + K), stacked
 
         def multi(scores_c, t0, keys, binned, y_dev, w_dev, edges):

@@ -114,6 +114,14 @@ def bin_matrix(x: jnp.ndarray, edges: jnp.ndarray, num_bins: int) -> jnp.ndarray
 # MXU histogram backend
 # ---------------------------------------------------------------------------
 
+def _layout_scope():
+    """The device-phase scope of ``_node_pure_layout``, for the builders that
+    call it.  The names live in ``lightgbm.core.DEVICE_PHASES`` alone; the
+    import is late because that module imports this one."""
+    from ..lightgbm.core import PHASE_LAYOUT
+    return jax.named_scope(PHASE_LAYOUT)
+
+
 def _node_pure_layout(binned, grad, hess, node_ids, num_nodes, R,
                       sample_weight=None, residuals=True, max_rows=None,
                       quantized=False):
@@ -285,9 +293,10 @@ def build_histograms_matmul(binned: jnp.ndarray, grad: jnp.ndarray,
     # stays proportionate
     R = min(block_rows, max(256, 1 << max(0, (n - 1)).bit_length()))
 
-    bb_all, w_ch, node_blk, NB = _node_pure_layout(
-        binned, grad, hess, node_ids, num_nodes, R, sample_weight,
-        residuals=residuals, max_rows=max_rows)
+    with _layout_scope():
+        bb_all, w_ch, node_blk, NB = _node_pure_layout(
+            binned, grad, hess, node_ids, num_nodes, R, sample_weight,
+            residuals=residuals, max_rows=max_rows)
     C = w_ch.shape[0]                                # 5 or 3 channels
 
     hi_iota = jnp.arange(HI, dtype=jnp.int32)
@@ -619,9 +628,10 @@ def build_histograms_matmul_quantized(binned: jnp.ndarray, qg: jnp.ndarray,
     P = num_nodes
     R = min(block_rows, max(256, 1 << max(0, (n - 1)).bit_length()))
 
-    bb_all, w_ch, node_blk, NB = _node_pure_layout(
-        binned, qg, qh, node_ids, num_nodes, R, quantized=True,
-        max_rows=max_rows)
+    with _layout_scope():
+        bb_all, w_ch, node_blk, NB = _node_pure_layout(
+            binned, qg, qh, node_ids, num_nodes, R, quantized=True,
+            max_rows=max_rows)
     C = 3                                            # qg, qh, count
 
     hi_iota = jnp.arange(HI, dtype=jnp.int32)

@@ -60,13 +60,26 @@ def enable_compilation_cache() -> str:
     at ``<checkout>/.xla_cache`` (git-ignored).  The path is fixed — it is
     part of the cache key, so a directory that moves never hits.  A cache
     that cannot be set raises: a silently cold cache turns every chip call
-    into a full recompile."""
+    into a full recompile.
+
+    The key covers the programs' metadata (scope names and the source line
+    of each operation), which JAX leaves out by default: an executable
+    carries the ``jax.named_scope`` paths of the source that compiled it
+    into every profiler trace, and the GBDT device phases are read from
+    those (``lightgbm.core.DEVICE_PHASES``), so a hit on another source's
+    entry would show that source's names, or none.  The callers' frames are
+    kept out of that metadata in turn (a location holds the innermost frame
+    alone): with them a program built again from another call site (the
+    sharded trainer re-jits its objective in every ``train()``) would miss
+    the entry its first build wrote."""
     import jax
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
         cache_dir = os.path.join(_REPO, ".xla_cache")
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return cache_dir

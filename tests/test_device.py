@@ -35,14 +35,19 @@ _PRINT_CACHE = (
     "from mmlspark_tpu.utils.device import enable_compilation_cache\n"
     "got = enable_compilation_cache()\n"
     "print(got)\n"
-    "print(jax.config.jax_compilation_cache_dir)\n")
+    "print(jax.config.jax_compilation_cache_dir)\n"
+    "print(jax.config.jax_compilation_cache_include_metadata_in_key,\n"
+    "      jax.config.jax_traceback_in_locations_limit, sep=',')\n")
 
 
 def test_compile_cache_defaults_to_the_checkout():
     proc = _run(_PRINT_CACHE, {})
     assert proc.returncode == 0, proc.stderr[-800:]
-    returned, configured = proc.stdout.split()
+    returned, configured, metadata_in_key = proc.stdout.split()
     assert returned == configured == os.path.join(_REPO, ".xla_cache")
+    # a cached executable carries its source's scope names into every
+    # trace, so the key covers them, but not the frames of who called
+    assert metadata_in_key == "True,1"
 
 
 def test_compile_cache_follows_the_environment(tmp_path):
@@ -51,7 +56,7 @@ def test_compile_cache_follows_the_environment(tmp_path):
     placed = str(tmp_path / "placed_cache")
     proc = _run(_PRINT_CACHE, {"JAX_COMPILATION_CACHE_DIR": placed})
     assert proc.returncode == 0, proc.stderr[-800:]
-    returned, configured = proc.stdout.split()
+    returned, configured, _ = proc.stdout.split()
     assert returned == configured == placed
 
 

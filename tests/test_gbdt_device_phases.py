@@ -1,0 +1,217 @@
+"""The device phases of a boosting iteration: every name of
+``lightgbm.core.DEVICE_PHASES`` is a ``jax.named_scope`` in the compiled
+grower programs, every heavy operation lies under one, the scopes change no
+arithmetic, and each has its per-layer metric in ``BENCHMARK.json``.
+
+The programs are compiled on the CPU as the chip takes them: quantized
+gradients, the ``matmul`` histogram builder, and (for ``lightgbm.multi_iter``)
+the chunk of four iterations a dispatch."""
+import contextlib
+import json
+import os
+import re
+
+import numpy as np
+import pytest
+
+from mmlspark_tpu.lightgbm import GBDTParams, core, train
+from mmlspark_tpu.lightgbm.core import DEVICE_PHASES
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+DEPTH = 3
+#: the phases that run once a level, inside an ``L<d>`` scope
+IN_LEVELS = ("gbdt.layout", "gbdt.hist", "gbdt.split", "gbdt.route")
+HEAVY = ("dot", "sort", "gather", "scatter", "all-reduce")
+
+
+def _data(rows, seed=0):
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(rows, 8)).astype(np.float32)
+    y = (X[:, 0] + 0.5 * X[:, 1] + 0.3 * rng.normal(size=rows) > 0)
+    return X, y.astype(np.float32)
+
+
+def _params(iterations):
+    return GBDTParams(num_iterations=iterations, objective="binary",
+                      max_depth=DEPTH, max_bin=63, use_quantized_grad=True)
+
+
+def _compiled_text(program):
+    """Optimized HLO text of the newest compiled signature of ``program``
+    among the trainer's cached programs."""
+    found = [fn for fn in core._JIT_CACHE.values()
+             if getattr(fn, "name", None) == program and fn._entries]
+    assert len(found) == 1, (program, found)
+    return list(found[0]._entries.values())[-1].compiled.as_text()
+
+
+def _instructions(text):
+    """``(opcode, op_name)`` of every instruction outside fused and applied
+    computations.  One without an ``op_name`` of its own that calls a fused
+    computation counts by that computation's root."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        if line and not line[0].isspace():
+            m = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+            cur = m.group(1) if m else None
+            if cur:
+                comps[cur] = []
+        elif cur and " = " in line:
+            comps[cur].append(line)
+
+    def op_name(line):
+        m = re.search(r'op_name="([^"]*)"', line)
+        return m.group(1) if m else ""
+
+    called = set(re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)", text))
+    out = []
+    for comp, lines in comps.items():
+        if comp in called:
+            continue
+        for line in lines:
+            m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = .*? ([\w\-]+)\(", line)
+            if not m:
+                continue
+            name = op_name(line)
+            callee = re.search(r"calls=%?([\w.\-]+)", line)
+            if not name and callee:
+                roots = [ln for ln in comps.get(callee.group(1), [])
+                         if ln.lstrip().startswith("ROOT")]
+                name = op_name(roots[0]) if roots else ""
+            out.append((m.group(1), name))
+    return out
+
+
+def _phase(name):
+    parts = [p for p in name.split("/") if p in DEVICE_PHASES]
+    return parts[-1] if parts else None
+
+
+@pytest.fixture(scope="module")
+def chip_path():
+    """The environment switches that make the CPU trace the chip's path."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("MMLSPARK_TPU_HIST_BACKEND", "matmul")
+        mp.setenv("MMLSPARK_TPU_GBDT_CHUNK", "4")
+        yield
+
+
+@pytest.fixture(scope="module")
+def programs(chip_path):
+    """HLO text of the grower programs: the per-iteration one at 4,000 rows,
+    the chunked one (it needs 50,000), and over four virtual devices the
+    sharded grower with the objective it dispatches beside it."""
+    from mmlspark_tpu.parallel import active_mesh, data_parallel_mesh
+    texts = {}
+    train(*_data(4000), _params(3))
+    texts["lightgbm.iter"] = _compiled_text("lightgbm.iter")
+    res = train(*_data(50_000), _params(8))
+    assert res.booster.num_trees == 8
+    texts["lightgbm.multi_iter"] = _compiled_text("lightgbm.multi_iter")
+    # the sharded path makes its jitted objective anew in every train()
+    made = []
+    with pytest.MonkeyPatch.context() as mp:
+        jit = core.instrumented_jit
+        mp.setattr(core, "instrumented_jit",
+                   lambda *a, **kw: made.append(jit(*a, **kw)) or made[-1])
+        with active_mesh(data_parallel_mesh(4)):
+            train(*_data(4000), _params(2), shard_rows=True)
+    texts["lightgbm.sharded_grower"] = _compiled_text("lightgbm.sharded_grower")
+    (objective,) = [w for w in made if w.name == "lightgbm.objective"]
+    texts["lightgbm.objective"] = list(
+        objective._entries.values())[-1].compiled.as_text()
+    return texts
+
+
+@pytest.mark.parametrize("phase", [p for p in DEVICE_PHASES
+                                   if p != "gbdt.allreduce"])
+@pytest.mark.parametrize("program", ["lightgbm.iter", "lightgbm.multi_iter"])
+def test_each_phase_is_a_scope_of_the_compiled_program(programs, program,
+                                                       phase):
+    # every instruction counts here, those inside fused computations too:
+    # a fusion across a scope boundary carries one name outside
+    names = re.findall(r'op_name="([^"]*)"', programs[program])
+    mine = [n for n in names if _phase(n) == phase]
+    assert mine, f"no instruction of {program} under {phase}"
+    if phase in IN_LEVELS:
+        levels = {p for n in mine for p in n.split("/")
+                  if re.fullmatch(r"L\d+", p)}
+        assert levels == {f"L{d}" for d in range(DEPTH)}, (phase, levels)
+        # the level lies outside the phase: .../L2/gbdt.hist/...
+        assert all(re.search(r"(^|/)L\d+/(.*/)?" + re.escape(phase) + "(/|$)", n)
+                   for n in mine)
+
+
+def test_the_sharded_grower_reduces_its_histograms_under_allreduce(programs):
+    ops = _instructions(programs["lightgbm.sharded_grower"])
+    reduces = [n for op, n in ops if op.startswith("all-reduce")]
+    assert reduces
+    hist = [n for n in reduces if _phase(n) == "gbdt.allreduce"]
+    # one a level, each inside its level's scope
+    assert {p for n in hist for p in n.split("/")
+            if re.fullmatch(r"L\d+", p)} == {f"L{d}" for d in range(DEPTH)}
+    # the scale maxima and the noise key of the quantization cross the mesh
+    # too, under their own phase
+    assert {_phase(n) for n in reduces} == {"gbdt.allreduce", "gbdt.quantize"}
+    # the separately dispatched objective of the sharded path
+    assert all(_phase(n) == "gbdt.grad"
+               for op, n in _instructions(programs["lightgbm.objective"])
+               if n and op != "parameter")
+
+
+@pytest.mark.parametrize("program", ["lightgbm.iter", "lightgbm.multi_iter",
+                                     "lightgbm.sharded_grower"])
+def test_every_heavy_operation_lies_under_a_phase(programs, program):
+    heavy = [(op, n) for op, n in _instructions(programs[program])
+             if op.split("-start")[0].split("-done")[0] in HEAVY]
+    assert {op for op, _ in heavy} >= {"dot", "sort"}
+    bare = [(op, n) for op, n in heavy if _phase(n) is None]
+    assert not bare, bare[:5]
+
+
+def test_the_scopes_change_no_arithmetic(chip_path, monkeypatch):
+    """The trees of a fit, bit for bit, against the same fit traced with
+    every scope taken out."""
+    import jax
+    X, y = _data(4000, seed=3)
+
+    def fit():
+        core._JIT_CACHE.clear()
+        b = train(X, y, _params(3)).booster
+        return {k: np.asarray(getattr(b, k)) for k in (
+            "split_feature", "threshold", "threshold_bin", "split_gain",
+            "internal_value", "internal_count", "leaf_value", "leaf_count")}
+
+    scoped = fit()
+    assert "gbdt.hist" in _compiled_text("lightgbm.iter")
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = fit()
+    assert "gbdt.hist" not in _compiled_text("lightgbm.iter")
+    core._JIT_CACHE.clear()
+    assert (scoped["split_feature"] >= 0).sum() >= 3 * 3
+    for key in scoped:
+        np.testing.assert_array_equal(scoped[key], bare[key], err_msg=key)
+
+
+def test_each_phase_has_its_metric_and_no_other_name_is_written():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        metrics = {m["name"]: m for m in json.load(f)["per_layer"]}
+    for phase in DEVICE_PHASES:
+        m = metrics[f"{phase}_ms_per_iter"]
+        assert m["source"] == "device_trace" and m["moves"] == "rows_per_s"
+        reader = os.path.join(REPO, "benchmark", "layer_metrics",
+                              f"{phase}_ms_per_iter.py")
+        with open(reader) as f:
+            assert f'"{phase}"' in f.read()
+    assert metrics["gbdt.allreduce_ms_per_iter"]["workloads"] == \
+        ["gbdt-train-dp4"]
+    assert len(set(DEVICE_PHASES)) == len(DEVICE_PHASES) == 8
+    # the program spells a phase's name once, in the vocabulary
+    for rel in ("mmlspark_tpu/lightgbm/core.py", "mmlspark_tpu/ops/histogram.py",
+                "mmlspark_tpu/parallel/collectives.py"):
+        with open(os.path.join(REPO, rel)) as f:
+            code = f.read()
+        for phase in DEVICE_PHASES:
+            want = 1 if rel.endswith("core.py") else 0
+            assert code.count(f'"{phase}"') == want, (rel, phase)
