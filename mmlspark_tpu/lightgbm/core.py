@@ -537,7 +537,7 @@ def make_tree_grower(max_depth: int, num_features: int, num_bins: int,
                     axis_name=axis_name, row_ids=row_ids)
 
         def build_local(node_a, num_nodes, max_rows=None):
-            # (the builders put their sort of the rows into node-pure blocks
+            # (the builders put their layout of the rows as node-pure blocks
             # under PHASE_LAYOUT themselves)
             with jax.named_scope(PHASE_HIST):
                 if use_quant:
@@ -1435,6 +1435,10 @@ def default_metric(objective: str) -> str:
 # training driver
 # ---------------------------------------------------------------------------
 
+#: where ``MMLSPARK_TPU_HIST_QUANT`` sits in ``_resolve_hist_backend()``
+_HIST_CFG_QUANT = 4
+
+
 def _resolve_hist_backend() -> tuple:
     """(backend, block_rows, lo_width, residuals) env knobs the growers will
     trace with.  Resolved ONCE per train() call and made part of every jit
@@ -1447,7 +1451,6 @@ def _resolve_hist_backend() -> tuple:
             os.environ.get("MMLSPARK_TPU_HIST_BLOCK_ROWS", ""),
             os.environ.get("MMLSPARK_TPU_HIST_LO", ""),
             os.environ.get("MMLSPARK_TPU_HIST_RESID", ""),
-            os.environ.get("MMLSPARK_TPU_HIST_LAYOUT", ""),
             os.environ.get("MMLSPARK_TPU_HIST_QUANT", ""),
             os.environ.get("MMLSPARK_TPU_HIST_STORE16", ""))
 
@@ -1592,10 +1595,11 @@ def train(X: np.ndarray, y: np.ndarray, params: GBDTParams,
     hist_cfg = _resolve_hist_backend()
     hist_backend = hist_cfg[0]
     _uq = p.use_quantized_grad
-    if hist_cfg[5].strip():              # MMLSPARK_TPU_HIST_QUANT=0/1
+    quant_env = hist_cfg[_HIST_CFG_QUANT].strip()
+    if quant_env:                        # MMLSPARK_TPU_HIST_QUANT=0/1
         # case-insensitive: an operator's QUANT=OFF during an incident must
         # never fail open into force-ENABLING the feature
-        _uq = hist_cfg[5].strip().lower() not in ("0", "false", "off", "no")
+        _uq = quant_env.lower() not in ("0", "false", "off", "no")
     if _uq is None:                      # auto: packed ints on the TPU
         _uq = platform() != "cpu"
     p = dataclasses.replace(p, use_quantized_grad=bool(_uq))
@@ -2738,8 +2742,9 @@ def train_streamed(X, y: Optional[np.ndarray] = None, params: GBDTParams = None,
     hist_cfg = _resolve_hist_backend()
     hist_backend = hist_cfg[0]
     _uq = p.use_quantized_grad
-    if hist_cfg[5].strip():
-        _uq = hist_cfg[5].strip().lower() not in ("0", "false", "off", "no")
+    quant_env = hist_cfg[_HIST_CFG_QUANT].strip()
+    if quant_env:
+        _uq = quant_env.lower() not in ("0", "false", "off", "no")
     if _uq is None:
         _uq = platform() != "cpu"
     p = dataclasses.replace(p, use_quantized_grad=bool(_uq))

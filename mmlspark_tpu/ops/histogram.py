@@ -125,118 +125,121 @@ def _layout_scope():
 def _node_pure_layout(binned, grad, hess, node_ids, num_nodes, R,
                       sample_weight=None, residuals=True, max_rows=None,
                       quantized=False):
-    """Shared host/device prep for the MXU histogram backend:
-    sort rows by node and pad so every R-row block is node-pure, then build
-    the bf16x2-decomposed weight channels (``residuals=False`` keeps just
-    bf16-rounded grad/hess + count — 3 channels instead of 5).
+    """Shared device prep for the MXU histogram backend: R-row blocks that
+    are node-pure, with the bf16x2-decomposed weight channels
+    (``residuals=False`` keeps just bf16-rounded grad/hess + count — 3
+    channels instead of 5).
 
     With ``quantized=True``, ``grad``/``hess`` are the pre-quantized int
     gradients and the weight channels come back as **int8**
     (qg, qh, valid) — the packed-histogram operand layout.
 
-    Returns (bb_all (N_pad, F) u8, w_ch (5 or 3, N_pad) f32, node_blk (NB,)
-    i32, NB).  Masked rows (node < 0) land in dummy node P whose buffer is
-    dropped by the caller.
+    Returns the operands of the builders' block scan: (bb (NB, R, F) u8,
+    w_ch (NB, 5 or 3, R), node_blk (NB,) i32).  Masked rows (node < 0) land
+    in dummy node P whose buffer is dropped by the caller.
+
+    One stable sort by node, the row index and the weights riding along as
+    payloads; after it every block is a CONTIGUOUS run of the sorted order
+    (block ``b`` of node ``p`` starts ``b*R - padded_off[p]`` rows into the
+    node's run), so the blocks are R-element slices at P+1 offsets — no
+    per-row slot, no scatter.  Only the binned rows are gathered, by the
+    blocks' sorted row ids.  A build of ONE node with no ``max_rows`` (the
+    root of every tree, a leaf-wise ``local_hist``) needs no order at all:
+    ``binned`` itself in R-row blocks, masked rows at zero weight.
 
     ``max_rows`` is a STATIC caller GUARANTEE that at most that many rows
     are unmasked (node >= 0).  It truncates the padded layout — and with it
     the block scan — to ``ceil(max_rows/R) + P + 1`` blocks instead of
-    covering all n rows; surplus masked rows fall off the end of the
-    (smaller) scatter and are dropped.  The level-wise grower uses this with
-    LightGBM's smaller-child rule: levels below the root only ever scatter
+    covering all n rows; the dummy node sorts last, so it is surplus masked
+    rows that fall off the end.  The level-wise grower uses this with
+    LightGBM's smaller-child rule: levels below the root only ever build
     the smaller sibling of each parent (<= n/2 rows total), halving the
     one-hot operand traffic of every build after the root.  If the caller's
     guarantee is violated, UNMASKED rows are silently dropped — callers must
     pass a true bound.
     """
-    import jax
-    import jax.numpy as jnp
-
     n, F = binned.shape
     P = num_nodes
     if quantized:
-        g = grad.astype(jnp.int32)
-        h = hess.astype(jnp.int32)
+        # ONE int32 payload: |qg| <= 64 and qh <= 127 by the quant_bins cap,
+        # so both fit the int8 operand lanes and share a word exactly
+        w = ((grad.astype(jnp.int32) << 8) | (hess.astype(jnp.int32) & 255),)
     else:
         g = grad.astype(jnp.float32)
         h = hess.astype(jnp.float32)
         if sample_weight is not None:
             g, h = g * sample_weight, h * sample_weight
-    c = jnp.ones_like(g)  # counts stay unweighted (min_data_in_leaf semantics)
+        w = (g, h)
+    keep = node_ids >= 0
 
-    import os as _os
-    node_s = jnp.where(node_ids < 0, P, node_ids).astype(jnp.int32)
-    # the one-hot cumsum materializes (n, P+1) transients — a candidate win
-    # only while P is small (depth-5 level-wise peaks at P=16); wide-node
-    # builds (deep trees, leaf-wise num_leaves buffers) always use the
-    # stable sort.  Default stays "sort" until an on-chip A/B proves cumsum
-    # faster (ROADMAP D2) — select it via MMLSPARK_TPU_HIST_LAYOUT=cumsum
-    use_cumsum = (_os.environ.get("MMLSPARK_TPU_HIST_LAYOUT", "sort")
-                  == "cumsum") and P + 1 <= 33
-    if use_cumsum:
-        # rank-by-cumulative-count: rows keep their original order within
-        # each node, exactly like the stable argsort below, but the slot
-        # comes from an exclusive prefix count over a (n, P+1) one-hot —
-        # P <= num_nodes is tiny: 17 parallel prefix sums instead of a
-        # full 1M-key sort
-        onehot_n = (node_s[:, None] == jnp.arange(P + 1)).astype(jnp.int32)
-        inc = jnp.cumsum(onehot_n, axis=0)
-        counts = inc[-1]
-        rank_all = jnp.take_along_axis(inc - onehot_n, node_s[:, None],
-                                       axis=1)[:, 0]
+    if P == 1 and max_rows is None:
+        NB = -(-n // R)
+
+        def blocks(x):
+            x = jnp.pad(x, ((0, NB * R - n),) + ((0, 0),) * (x.ndim - 1))
+            return x.reshape((NB, R) + x.shape[1:])
+
+        bb, valid, w = blocks(binned), blocks(keep), [blocks(x) for x in w]
+        node_blk = jnp.zeros((NB,), jnp.int32)
     else:
-        order = jnp.argsort(node_s)                 # stable
-        ns = node_s[order]
-        counts = jax.ops.segment_sum(jnp.ones((n,), jnp.int32), node_s,
-                                     num_segments=P + 1)
-        start = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                 jnp.cumsum(counts)[:-1]])
-    # empty nodes get ZERO blocks (their buffer stays at acc0's zeros);
-    # node_blk's searchsorted('right')-1 naturally skips past zero-width
-    # offsets to the node that actually owns the rows
-    padded_counts = ((counts + R - 1) // R) * R
-    padded_off = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                  jnp.cumsum(padded_counts)[:-1]])
-    n_cap = n if max_rows is None else min(n, int(max_rows))
-    N_pad = ((n_cap + R - 1) // R + P + 1) * R       # static upper bound, R-aligned
-    if use_cumsum:
-        pos = padded_off[node_s] + rank_all
-        padded_idx = jnp.full((N_pad,), -1, jnp.int32).at[pos].set(
-            jnp.arange(n, dtype=jnp.int32))
-    else:
-        rank = jnp.arange(n, dtype=jnp.int32) - start[ns]
-        pos = padded_off[ns] + rank
-        padded_idx = jnp.full((N_pad,), -1, jnp.int32).at[pos].set(order)
+        # R pad keys past the dummy node: no block slice below runs off the
+        # sorted arrays, and bounds[P + 1] is the end of the dummy node
+        def padded(x, fill):
+            return jnp.concatenate([x, jnp.full((R,), fill, x.dtype)])
 
-    NB = N_pad // R
-    block_starts = jnp.arange(NB, dtype=jnp.int32) * R
-    node_blk = jnp.searchsorted(padded_off, block_starts, side="right").astype(jnp.int32) - 1
-    node_blk = jnp.clip(node_blk, 0, P)
-    # blocks past a node's real (padded) rows are all -1 ids -> zero weights
+        node_s = jnp.where(keep, node_ids, P).astype(jnp.int32)
+        ks, order, *w = jax.lax.sort(
+            (padded(node_s, P + 1), jnp.arange(n + R, dtype=jnp.int32),
+             *[padded(x, 0) for x in w]), num_keys=1, is_stable=True)
+        bounds = jnp.searchsorted(
+            ks, jnp.arange(P + 2, dtype=jnp.int32)).astype(jnp.int32)
+        start, counts = bounds[:-1], jnp.diff(bounds)
+        # empty nodes get ZERO blocks (their buffer stays at acc0's zeros);
+        # node_blk's searchsorted('right')-1 naturally skips past zero-width
+        # offsets to the node that actually owns the rows
+        padded_counts = -(-counts // R) * R
+        padded_off = jnp.cumsum(padded_counts) - padded_counts
+        n_cap = n if max_rows is None else min(n, int(max_rows))
+        NB = -(-n_cap // R) + P + 1                  # static upper bound
+        block_starts = jnp.arange(NB, dtype=jnp.int32) * R
+        node_blk = jnp.clip(
+            jnp.searchsorted(padded_off, block_starts, side="right")
+            .astype(jnp.int32) - 1, 0, P)
+        # rows of its node before the block; past a node's real rows (and
+        # past every node: those blocks read as the dummy's) nothing is valid
+        into = block_starts - padded_off[node_blk]
+        src = start[node_blk] + into
+        valid = (jnp.arange(R, dtype=jnp.int32)
+                 < (counts[node_blk] - into)[:, None])
 
-    valid = (padded_idx >= 0)
-    safe_idx = jnp.maximum(padded_idx, 0)
-    bb_all = binned[safe_idx]                        # (N_pad, F) uint8
+        def blocks(x):
+            return jax.vmap(
+                lambda s: jax.lax.dynamic_slice(x, (s,), (R,)))(src)
+
+        w = [blocks(x) for x in w]
+        bb = binned[jnp.where(valid, blocks(order), 0)]          # (NB, R, F)
+
     if quantized:
-        # int8 operand lanes: |qg| <= 64 and qh <= 127 by the quant_bins
-        # cap, so the per-row values are exact; accumulation is int32
-        vi = valid.astype(jnp.int32)
-        w_ch = jnp.stack([g[safe_idx] * vi, h[safe_idx] * vi, vi],
-                         axis=0).astype(jnp.int8)               # (3, N_pad)
-        return bb_all, w_ch, node_blk, NB
+        # int8 operand lanes: the per-row values are exact; accumulation is
+        # int32
+        pk = w[0]
+        w_ch = jnp.stack([jnp.where(valid, pk >> 8, 0),
+                          jnp.where(valid, pk & 255, 0),
+                          valid.astype(jnp.int32)], axis=1).astype(jnp.int8)
+        return bb, w_ch, node_blk
     # bf16x2 decomposition for the MXU inputs: grad/hess are signed and
     # cancellation-sensitive, so each carries a bf16 residual channel; counts
-    # (small ints) are exact in bf16.  Accumulation itself is f32 on the MXU.
-    gp = g[safe_idx] * valid
-    hp = h[safe_idx] * valid
-    cp = c[safe_idx] * valid
+    # (small ints, unweighted: min_data_in_leaf semantics) are exact in bf16.
+    # Accumulation itself is f32 on the MXU.
+    gp = jnp.where(valid, w[0], 0.0)
+    hp = jnp.where(valid, w[1], 0.0)
+    cp = valid.astype(jnp.float32)
     g_hi = gp.astype(jnp.bfloat16).astype(jnp.float32)
     h_hi = hp.astype(jnp.bfloat16).astype(jnp.float32)
     if not residuals:
-        w_ch = jnp.stack([g_hi, h_hi, cp], axis=0)                  # (3, N_pad)
-        return bb_all, w_ch, node_blk, NB
-    w5 = jnp.stack([g_hi, gp - g_hi, h_hi, hp - h_hi, cp], axis=0)  # (5, N_pad)
-    return bb_all, w5, node_blk, NB
+        return bb, jnp.stack([g_hi, h_hi, cp], axis=1), node_blk
+    w5 = jnp.stack([g_hi, gp - g_hi, h_hi, hp - h_hi, cp], axis=1)
+    return bb, w5, node_blk
 
 
 def build_histograms_matmul(binned: jnp.ndarray, grad: jnp.ndarray,
@@ -253,8 +256,9 @@ def build_histograms_matmul(binned: jnp.ndarray, grad: jnp.ndarray,
     needs.  This backend reformulates the build so the inner loop is matrix
     multiplication:
 
-    1. rows are sorted by node and padded so every `block_rows` block is
-       node-pure (one bounded-size scatter of int32 row ids, not n*F floats);
+    1. rows are sorted by node, once, and every `block_rows` block is a
+       node-pure contiguous slice of that order, each node padded to whole
+       blocks (``_node_pure_layout``; one node needs no sort at all);
     2. each 8-bit bin splits into hi/lo parts (``lo_width`` lanes wide); a
        block's histogram is the pair of one-hot indicators contracted over
        rows — ``einsum('rfm,rfl->fml', onehot_hi * weight, onehot_lo)`` —
@@ -294,10 +298,10 @@ def build_histograms_matmul(binned: jnp.ndarray, grad: jnp.ndarray,
     R = min(block_rows, max(256, 1 << max(0, (n - 1)).bit_length()))
 
     with _layout_scope():
-        bb_all, w_ch, node_blk, NB = _node_pure_layout(
+        bb_all, w_ch, node_blk = _node_pure_layout(
             binned, grad, hess, node_ids, num_nodes, R, sample_weight,
             residuals=residuals, max_rows=max_rows)
-    C = w_ch.shape[0]                                # 5 or 3 channels
+    C = w_ch.shape[1]                                # 5 or 3 channels
 
     hi_iota = jnp.arange(HI, dtype=jnp.int32)
     lo_iota = jnp.arange(LO, dtype=jnp.int32)
@@ -319,10 +323,7 @@ def build_histograms_matmul(binned: jnp.ndarray, grad: jnp.ndarray,
         return acc.at[nb].add(blk), None
 
     acc0 = jnp.zeros((P + 1, F, C * HI, LO), jnp.float32)
-    acc, _ = jax.lax.scan(
-        body, acc0,
-        (bb_all.reshape(NB, R, F), jnp.moveaxis(w_ch.reshape(C, NB, R), 1, 0),
-         node_blk))
+    acc, _ = jax.lax.scan(body, acc0, (bb_all, w_ch, node_blk))
     acc = acc[:P].reshape(P, F, C, HI, LO)                             # split channels
     if residuals:
         acc3 = jnp.stack([acc[:, :, 0] + acc[:, :, 1],
@@ -629,7 +630,7 @@ def build_histograms_matmul_quantized(binned: jnp.ndarray, qg: jnp.ndarray,
     R = min(block_rows, max(256, 1 << max(0, (n - 1)).bit_length()))
 
     with _layout_scope():
-        bb_all, w_ch, node_blk, NB = _node_pure_layout(
+        bb_all, w_ch, node_blk = _node_pure_layout(
             binned, qg, qh, node_ids, num_nodes, R, quantized=True,
             max_rows=max_rows)
     C = 3                                            # qg, qh, count
@@ -651,10 +652,7 @@ def build_histograms_matmul_quantized(binned: jnp.ndarray, qg: jnp.ndarray,
         return acc.at[nb].add(blk), None
 
     acc0 = jnp.zeros((P + 1, F, C * HI, LO), jnp.int32)
-    acc, _ = jax.lax.scan(
-        body, acc0,
-        (bb_all.reshape(NB, R, F),
-         jnp.moveaxis(w_ch.reshape(C, NB, R), 1, 0), node_blk))
+    acc, _ = jax.lax.scan(body, acc0, (bb_all, w_ch, node_blk))
     acc = acc[:P].reshape(P, F, C, HI, LO)
     hist = jnp.moveaxis(acc, 2, 0).reshape(3, P, F, HI * LO)[..., :B]
     return jnp.moveaxis(hist, 0, -1)                                   # (P,F,B,3)

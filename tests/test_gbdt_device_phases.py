@@ -169,6 +169,44 @@ def test_every_heavy_operation_lies_under_a_phase(programs, program):
     assert not bare, bare[:5]
 
 
+def _primitives(jaxpr, outer=""):
+    """``(primitive, scope path)`` of every equation, nested ones too."""
+    import jax
+    for eqn in jaxpr.eqns:
+        path = outer + "/" + str(eqn.source_info.name_stack)
+        yield eqn.primitive.name, path
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub, path)
+
+
+@pytest.mark.parametrize("num_nodes,max_rows,sorts", [
+    (1, None, 0), (1, 600, 1), (4, None, 1), (4, 600, 1), (40, None, 1)])
+def test_the_row_layout_sorts_once_and_scatters_nothing(num_nodes, max_rows,
+                                                        sorts):
+    """The layout is one sort and block slices: no per-row slot, so no
+    scatter under ``gbdt.layout``; one node without a bound is not even
+    sorted.  The scan's accumulation into the node buffers stays the only
+    scatter of the build."""
+    import jax
+    import jax.numpy as jnp
+    from mmlspark_tpu.ops import histogram as H
+    n = 1000
+    binned = jnp.zeros((n, 4), jnp.uint8)
+    q = jnp.zeros((n,), jnp.int32)
+    for build, args in (
+            (H.build_histograms_matmul_quantized, (binned, q, q, q)),
+            (H.build_histograms_matmul,
+             (binned, q.astype(jnp.float32), q.astype(jnp.float32), q))):
+        prims = list(_primitives(jax.make_jaxpr(
+            lambda *a: build(*a, num_nodes, 63, block_rows=256,
+                             max_rows=max_rows))(*args).jaxpr))
+        assert any("gbdt.layout" in path for _, path in prims)
+        assert [path for name, path in prims if name == "sort"] == \
+            ["/gbdt.layout"] * sorts
+        scatters = [path for name, path in prims if "scatter" in name]
+        assert len(scatters) == 1 and "gbdt.layout" not in scatters[0]
+
+
 def test_the_scopes_change_no_arithmetic(chip_path, monkeypatch):
     """The trees of a fit, bit for bit, against the same fit traced with
     every scope taken out."""

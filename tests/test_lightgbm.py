@@ -165,16 +165,14 @@ def test_sharded_training_matches(mesh8):
     assert np.allclose(a, b, atol=1e-4), np.abs(a - b).max()
 
 
-@pytest.mark.parametrize("layout", ["sort", "cumsum"])
-def test_histogram_backends_agree(layout, monkeypatch):
+@pytest.mark.parametrize("p", [1, 4])
+def test_histogram_backends_agree(p):
     import jax.numpy as jnp
     from mmlspark_tpu.ops.histogram import build_histograms, build_histograms_matmul
-    # row-layout knob is read at trace time inside the matmul backend;
-    # both layouts must produce identical histograms (cumsum only engages
-    # when P+1 <= 33 — true here, P=4)
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_LAYOUT", layout)
+    # one node takes the matmul backend's unsorted root layout, four its
+    # sorted block slices: both must match the scatter build
     rng = np.random.default_rng(7)
-    n, f, b, p = 3000, 9, 255, 4
+    n, f, b = 3000, 9, 255
     binned = jnp.asarray(rng.integers(0, b, (n, f)).astype(np.uint8))
     g = jnp.asarray(rng.normal(size=n).astype(np.float32))
     h = jnp.asarray(rng.uniform(0.1, 1, n).astype(np.float32))
@@ -221,6 +219,149 @@ def test_histogram_max_rows_compaction_exact():
         m = build_histograms_matmul(binned, g, h, node, p, b,
                                     block_rows=256, max_rows=cap)
         assert float(jnp.max(jnp.abs(ref - m))) < 1e-3, cap
+
+
+def _layout_reference(node, R, P, NB):
+    """Plain numpy: stable sort by node (masked rows last, as node P), each
+    node padded to a multiple of R; the first NB blocks.  Returns the row id
+    of every slot (-1 = padding) and the node of every block that has rows."""
+    node_s = np.where(node < 0, P, node)
+    order = np.argsort(node_s, kind="stable")
+    slots, owner = [], []
+    for q in range(P + 1):
+        rows = order[node_s[order] == q]
+        nblk = -(-len(rows) // R)
+        slots.append(np.concatenate(
+            [rows, np.full(nblk * R - len(rows), -1, np.int64)]))
+        owner += [q] * nblk
+    slots = np.concatenate(slots)
+    slots = np.concatenate([slots, np.full(NB * R, -1, np.int64)])[:NB * R]
+    return slots.reshape(NB, R), np.asarray(owner[:NB])
+
+
+def _layout_cases():
+    rng = np.random.default_rng(21)
+    R = 256
+
+    def mixed(n, P, masked=0.15, empty=None, exact=None):
+        node = rng.integers(0, P, n)
+        if empty is not None:                 # a node no row reaches
+            node[node == empty] = (empty + 1) % P
+        if exact is not None:                 # a node of exactly 2 * R rows
+            node[node == exact] = (exact + 1) % P
+            node[rng.permutation(n)[:2 * R]] = exact
+        node[rng.uniform(size=n) < masked] = -1
+        return node.astype(np.int32)
+
+    heavy = mixed(4000, 8, masked=0.7)
+    unmasked = int((heavy >= 0).sum())
+    return {
+        "masked_rows": (mixed(3000, 4), 4, R, None),
+        "empty_node": (mixed(3000, 5, empty=2), 5, R, None),
+        "exact_multiple_of_R": (mixed(3000, 4, masked=0.0, exact=1), 4, R,
+                                None),
+        "n_not_multiple_of_R": (mixed(2999, 3), 3, R, None),
+        "max_rows_at_boundary": (heavy, 8, R, unmasked),
+        "max_rows_70pct_masked": (heavy, 8, R, len(heavy) // 2),
+        "one_node_with_max_rows": (mixed(3000, 1, masked=0.6), 1, R, 1500),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_layout_cases()))
+def test_node_pure_layout_blocks_match_numpy_reference(case):
+    """Block for block: the same rows in the same order as "stable sort by
+    node, pad each node to a multiple of R", weights riding along."""
+    import jax.numpy as jnp
+    from mmlspark_tpu.ops.histogram import _node_pure_layout
+    node, P, R, max_rows = _layout_cases()[case]
+    n, F = len(node), 5
+    rng = np.random.default_rng(22)
+    # every row's bins spell its row id, so a block's rows can be read back
+    binned = np.stack([(np.arange(n) >> (8 * k)) & 255
+                       for k in range(2)] + [rng.integers(0, 255, n)] * 3,
+                      axis=1).astype(np.uint8)
+    qg = rng.integers(-8, 9, n).astype(np.int32)
+    qh = rng.integers(0, 16, n).astype(np.int32)
+    bb, w, node_blk = _node_pure_layout(
+        jnp.asarray(binned), jnp.asarray(qg), jnp.asarray(qh),
+        jnp.asarray(node), P, R, quantized=True, max_rows=max_rows)
+    bb, w, node_blk = np.asarray(bb), np.asarray(w), np.asarray(node_blk)
+    NB = bb.shape[0]
+    n_cap = n if max_rows is None else min(n, max_rows)
+    assert NB == -(-n_cap // R) + P + 1 and bb.shape == (NB, R, F)
+    assert w.shape == (NB, 3, R) and w.dtype == np.int8
+    slots, owner = _layout_reference(node, R, P, NB)
+    valid = slots >= 0
+    np.testing.assert_array_equal(w[:, 2, :], valid)
+    rows = bb[..., 0].astype(np.int64) | (bb[..., 1].astype(np.int64) << 8)
+    np.testing.assert_array_equal(rows[valid], slots[valid])
+    np.testing.assert_array_equal(w[:, 0, :], np.where(valid, qg[slots], 0))
+    np.testing.assert_array_equal(w[:, 1, :], np.where(valid, qh[slots], 0))
+    # a block with rows belongs to their node; every unmasked row is there
+    has_rows = valid.any(axis=1)
+    np.testing.assert_array_equal(node_blk[has_rows][:len(owner)],
+                                  owner[:has_rows.sum()])
+    kept = slots[valid & (node_blk[:, None] < P)]
+    np.testing.assert_array_equal(np.sort(kept), np.flatnonzero(node >= 0))
+
+
+def test_root_layout_is_taken_for_one_node_without_max_rows_only():
+    """One node and no bound: ``binned`` itself in R-row blocks, in row
+    order, masked rows at zero weight.  With ``max_rows`` the sorted layout
+    runs (its truncation is what the bound is for)."""
+    import jax.numpy as jnp
+    from mmlspark_tpu.ops import histogram as H
+    rng = np.random.default_rng(23)
+    n, F, R, b = 1500, 4, 256, 255
+    binned = jnp.asarray(rng.integers(0, b, (n, F)).astype(np.uint8))
+    g = jnp.asarray(rng.normal(size=n).astype(np.float32))
+    h = jnp.asarray(rng.uniform(0.1, 1, n).astype(np.float32))
+    node_np = np.where(rng.uniform(size=n) < 0.4, -1, 0).astype(np.int32)
+    node = jnp.asarray(node_np)
+    qg, qh, _, _ = H.quantize_gradients(g, h, 16, seed=2)
+    bb, w, node_blk = H._node_pure_layout(binned, qg, qh, node, 1, R,
+                                          quantized=True)
+    NB = -(-n // R)
+    assert bb.shape == (NB, R, F) and not np.asarray(node_blk).any()
+    np.testing.assert_array_equal(np.asarray(bb).reshape(-1, F)[:n], binned)
+    np.testing.assert_array_equal(np.asarray(w)[:, 2, :].reshape(-1)[:n],
+                                  node_np >= 0)
+    bb2, _, _ = H._node_pure_layout(binned, qg, qh, node, 1, R,
+                                    quantized=True, max_rows=n)
+    assert bb2.shape[0] == NB + 2
+    # bit for bit on the quantized path, within 1e-3 on the float path
+    sc = H.build_histograms_quantized(binned, qg, qh, node, 1, b)
+    mm = H.build_histograms_matmul_quantized(binned, qg, qh, node, 1, b,
+                                             block_rows=R)
+    assert bool(jnp.all(sc == mm))
+    ref = H.build_histograms(binned, g, h, node, 1, b)
+    fm = H.build_histograms_matmul(binned, g, h, node, 1, b, block_rows=R)
+    assert float(jnp.max(jnp.abs(ref - fm))) < 1e-3
+
+
+@pytest.mark.parametrize("p", [33, 63])
+def test_wide_node_buffers_agree_with_scatter(p):
+    """The leaf-wise regime: more nodes than the level-wise grower ever
+    has, some of them empty."""
+    import jax.numpy as jnp
+    from mmlspark_tpu.ops import histogram as H
+    rng = np.random.default_rng(p)
+    n, f, b = 5000, 4, 255
+    binned = jnp.asarray(rng.integers(0, b, (n, f)).astype(np.uint8))
+    g = jnp.asarray(rng.normal(size=n).astype(np.float32))
+    h = jnp.asarray(rng.uniform(0.1, 1, n).astype(np.float32))
+    node_np = rng.integers(-1, p, n).astype(np.int32)
+    node_np[node_np == 7] = 8
+    node = jnp.asarray(node_np)
+    qg, qh, _, _ = H.quantize_gradients(g, h, 16, seed=4)
+    sc = H.build_histograms_quantized(binned, qg, qh, node, p, b)
+    mm = H.build_histograms_matmul_quantized(binned, qg, qh, node, p, b,
+                                             block_rows=256)
+    assert bool(jnp.all(sc == mm))
+    assert not np.asarray(mm[7]).any()
+    ref = H.build_histograms(binned, g, h, node, p, b)
+    fm = H.build_histograms_matmul(binned, g, h, node, p, b, block_rows=256)
+    assert float(jnp.max(jnp.abs(ref - fm))) < 1e-3
 
 
 def test_histogram_env_knobs_drive_training(monkeypatch):

@@ -218,6 +218,32 @@ def test_quant_env_hatch_and_phase_labels(monkeypatch):
         assert ("histogram_split_update", "scatter", "0") in keys, off_token
 
 
+def test_matmul_and_scatter_backends_grow_the_same_trees(monkeypatch):
+    """Integer histograms are exact in both builders, so a quantized fit
+    through the int8 matmul build (one node unsorted at the root, sorted
+    block slices below) takes the same splits as through the packed
+    scatter."""
+    from mmlspark_tpu.lightgbm import GBDTParams, core, train
+    rng = np.random.default_rng(5)
+    X = rng.normal(size=(700, 6)).astype(np.float32)
+    y = (X[:, 0] + 0.5 * X[:, 1] + 0.3 * rng.normal(size=700) > 0)
+    params = GBDTParams(num_iterations=4, max_depth=3, objective="binary",
+                        min_data_in_leaf=5, use_quantized_grad=True,
+                        bagging_fraction=0.8, bagging_freq=1)
+
+    def fit(backend):
+        monkeypatch.setenv("MMLSPARK_TPU_HIST_BACKEND", backend)
+        b = train(X, y.astype(np.float32), params).booster
+        return {k: np.asarray(getattr(b, k)) for k in (
+            "split_feature", "threshold_bin", "threshold", "leaf_value",
+            "internal_count", "leaf_count")}
+
+    mm, sc = fit("matmul"), fit("scatter")
+    assert (mm["split_feature"] >= 0).sum() >= 4 * 3
+    for key in mm:
+        np.testing.assert_array_equal(mm[key], sc[key], err_msg=key)
+
+
 def test_sharded_overflow_guard_uses_global_row_bound():
     """The builders' int32 guard sees only the local shard; the grower must
     reject a GLOBAL row bound that would wrap the hessian lane after the
