@@ -6,17 +6,66 @@ Reference: ``deep-learning/.../cntk/ImageFeaturizer.scala:24-120`` — composes
 (resize + normalize) is fused into the same jitted function as the backbone so
 XLA pipelines HBM loads and the MXU convolutions in one program, and head
 truncation is the model's ``features=True`` path.
+
+Pixels keep the dtype the table holds them in until they are on the device.
+The host copies a partition's images exactly once, into one dense
+``(n, H, W, C)`` array (``_ImageScorer._stack_input``): ``uint8`` when
+every image is ``uint8``, else ``float32`` made in that same copy.  The
+widening to float32
+is the first operation of the jitted ``fused`` program, never the host's:
+it is exact, the device does it inside the fusion that already reads the
+batch, and a widened table costs the host a pass over four times the bytes,
+a second copy of them, and a four-times larger upload (PR 30).
 """
 from __future__ import annotations
 
 from typing import Optional
 
+import jax.numpy as jnp
 import numpy as np
 
 from ..core import ComplexParam, DataFrame, HasInputCol, HasOutputCol, Model, Param
 from ..core.schema import ColumnType
 from ..ops import image as image_ops
 from .jax_model import FlaxModelPayload, JaxModel
+
+
+def _as_hwc(arr: np.ndarray, channels: int) -> np.ndarray:
+    """An unrolled 1-d image viewed as square HWC; any other as it is."""
+    if arr.ndim == 1:
+        side = int(round((arr.size / channels) ** 0.5))
+        arr = arr.reshape(side, side, channels)
+    return arr
+
+
+class _ImageScorer(JaxModel):
+    """The featurizer's private scorer: a ``JaxModel`` over an image column,
+    stacked in the images' own dtype (``input_dtype`` does not apply:
+    ``fused`` widens on the device)."""
+
+    def __init__(self, channels: int):
+        super().__init__()
+        self.channels = channels
+
+    def _stack_input(self, col: np.ndarray) -> np.ndarray:
+        """A partition's image column as one dense ``(n, H, W, C)`` array,
+        in the ONE host copy the images get: ``uint8`` if every image is,
+        else ``float32``.  A dense column is used as it is, with no per-row
+        work."""
+        c = self.channels
+        if col.dtype != object:
+            x = col.reshape(len(col), *_as_hwc(col[0], c).shape)
+            return x if x.dtype == np.uint8 else x.astype(np.float32, copy=False)
+        rows = [_as_hwc(np.asarray(v), c) for v in col]
+        shape = rows[0].shape
+        as_uint8 = all(r.dtype == np.uint8 for r in rows)
+        out = np.empty((len(rows), *shape), np.uint8 if as_uint8 else np.float32)
+        for i, r in enumerate(rows):
+            if r.shape != shape:            # the assignment would broadcast
+                raise ValueError(f"image {i} has shape {r.shape}, the "
+                                 f"partition's first has {shape}")
+            out[i] = r
+        return out
 
 
 class ImageFeaturizer(Model, HasInputCol, HasOutputCol):
@@ -64,6 +113,7 @@ class ImageFeaturizer(Model, HasInputCol, HasOutputCol):
         cut = self.get("cut_output_layers")
         norm = self.get("auto_convert")
         key = (id(payload), h, w, cut, norm, self.get("batch_size"),
+               self.get("channels"),
                self.get_or_fail("input_col"), self.get_or_fail("output_col"))
         if self._scorer_cache is not None and self._scorer_cache[0] == key:
             return self._scorer_cache[1]
@@ -82,7 +132,9 @@ class ImageFeaturizer(Model, HasInputCol, HasOutputCol):
                 return _m.apply(variables, batch, features=(cut > 0), **_kw)
 
         def fused(variables, batch):
-            x = batch                       # NHWC column convention
+            # NHWC column convention, in the table's dtype: widened here,
+            # on the device, so every branch below sees float32 pixels
+            x = batch.astype(jnp.float32)
             if x.shape[1] != h or x.shape[2] != w:
                 x = image_ops.resize(x, h, w)
             if norm:
@@ -94,7 +146,7 @@ class ImageFeaturizer(Model, HasInputCol, HasOutputCol):
                 out = out.reshape(out.shape[0], -1)  # pooled feature maps
             return out
 
-        runner = JaxModel()
+        runner = _ImageScorer(self.get("channels"))
         runner.set_model(apply_fn=fused, variables=payload.variables)
         runner.set("batch_size", self.get("batch_size"))
         runner.set("input_col", self.get_or_fail("input_col"))
@@ -103,22 +155,7 @@ class ImageFeaturizer(Model, HasInputCol, HasOutputCol):
         return runner
 
     def _transform(self, df: DataFrame) -> DataFrame:
-        in_col = self.get_or_fail("input_col")
-        c = self.get("channels")
-
-        def reshape_part(p):
-            col = p[in_col]
-            out = np.empty(len(col), dtype=object)
-            for i, v in enumerate(col):
-                arr = np.asarray(v)
-                if arr.ndim == 1:  # unrolled image -> assume square HWC
-                    side = int(round((arr.size / c) ** 0.5))
-                    arr = arr.reshape(side, side, c)
-                out[i] = arr.astype(np.float32)
-            return {**p, in_col: out}
-
-        reshaped = df.map_partitions(reshape_part)
-        return self._build_runner().transform(reshaped)
+        return self._build_runner().transform(df)
 
     def transform_schema(self, schema):
         schema.require(self.get_or_fail("input_col"))

@@ -545,6 +545,12 @@ class ModelRunner:
                           for f in FRONTS}
         self._c_rows = {f: c_rows.labels(runner=name, front=f)
                         for f in FRONTS}
+        c_input_bytes = reg.counter(
+            "mmlspark_runner_input_bytes_total",
+            "bytes of the padded input chunks handed to the batch executable",
+            labels=("runner", "front"))
+        self._c_input_bytes = {f: c_input_bytes.labels(runner=name, front=f)
+                               for f in FRONTS}
         self._c_pad = reg.counter(
             "mmlspark_runner_pad_rows_total",
             "padding rows added by bucketing (wasted device work)",
@@ -731,6 +737,7 @@ class ModelRunner:
             fn = self.executable(bucket, chunk.shape[1:])
             outs.append(np.asarray(fn(variables, chunk))[:m])
             self._c_batches[front].inc()
+            self._c_input_bytes[front].inc(chunk.nbytes)
         self._c_rows[front].inc(n)
         if pad_total:
             self._c_pad.inc(pad_total)

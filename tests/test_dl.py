@@ -83,6 +83,142 @@ def test_image_featurizer_resnet18_small():
     assert lcol[0].shape == (10,)
 
 
+def _pixels(n, h, w, seed=0):
+    return np.random.default_rng(seed).integers(0, 256, (n, h, w, 3), np.uint8)
+
+
+def _object_column(rows):
+    col = np.empty(len(rows), dtype=object)
+    for i, r in enumerate(rows):
+        col[i] = r
+    return col
+
+
+def _mixed_dtype_column(px):
+    return _object_column([r if i % 2 else r.astype(np.float32)
+                           for i, r in enumerate(px)])
+
+
+def _image_featurizer(**params):
+    """ResNet-free featurizer over a tiny conv net, so bit-equality is
+    about the input path and not about a deep backbone's run time."""
+    import jax
+    import jax.numpy as jnp
+    import flax.linen as nn
+    from mmlspark_tpu.dl import ImageFeaturizer
+
+    class Net(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            x = nn.relu(nn.Conv(4, (3, 3))(x))
+            return nn.Dense(6)(x.mean(axis=(1, 2)))
+
+    mod = Net()
+    variables = mod.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 8, 3)))
+    kw = dict(input_col="image", output_col="features", height=8, width=8,
+              batch_size=4)
+    kw.update(params)
+    feat = ImageFeaturizer(**kw)
+    return feat.set_model(apply_fn=mod.apply, variables=variables)
+
+
+def _spy_on_apply_batch(feat):
+    """Wrap the bound ``apply_batch`` on the featurizer's runner INSTANCE,
+    as ``benchmark/families/image_featurizer.py`` does for its span: the
+    stage must look it up there at call time.  Returns the arrays seen."""
+    runner = feat._build_runner().runner()
+    inner, seen = runner.apply_batch, []
+
+    def apply_batch(x, *args, **kwargs):
+        seen.append(x)
+        return inner(x, *args, **kwargs)
+    runner.apply_batch = apply_batch
+    return seen
+
+
+# (pixels shape (n, h, w); caller's column from the uint8 pixels; stage
+# params; the dtype the runner must be handed)
+_INPUT_PATH_CASES = {
+    "uint8_object_column": ((6, 8, 8), _object_column, {}, np.uint8),
+    "uint8_dense_column": ((6, 8, 8), lambda px: px, {}, np.uint8),
+    "float32_object_column":
+        ((6, 8, 8), lambda px: _object_column(px.astype(np.float32)), {},
+         np.float32),
+    "float64_dense_column":
+        ((6, 8, 8), lambda px: px.astype(np.float64), {}, np.float32),
+    "unrolled_uint8_rows":
+        ((6, 8, 8), lambda px: _object_column(px.reshape(len(px), -1)), {},
+         np.uint8),
+    "unrolled_dense_vectors":
+        ((6, 8, 8), lambda px: px.reshape(len(px), -1).astype(np.float64), {},
+         np.float32),
+    "mixed_dtype_partition": ((6, 8, 8), _mixed_dtype_column, {}, np.float32),
+    # 7 rows at batch_size 4: a chunk of 3 padded to its bucket of 4
+    "partial_last_batch": ((7, 8, 8), _object_column, {}, np.uint8),
+    "auto_convert_off":
+        ((6, 8, 8), _object_column, {"auto_convert": False}, np.uint8),
+    "resize_branch": ((6, 12, 12), _object_column, {}, np.uint8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_INPUT_PATH_CASES))
+def test_image_featurizer_input_path_matches_float32(case):
+    """PR 30: pixels reach the device in the table's dtype.  Whatever the
+    column holds, the features are those of the same pixels handed to the
+    same program as float32 (what the host used to make, image by image):
+    bit-equal, since uint8 -> float32 is exact and every later operation
+    is the same."""
+    (n, h, w), make_column, params, dtype = _INPUT_PATH_CASES[case]
+    px = _pixels(n, h, w)
+    feat = _image_featurizer(**params)
+    want = feat._build_runner().runner().apply_batch(
+        px.astype(np.float32), batch_size=4)
+    seen = _spy_on_apply_batch(feat)
+    df = DataFrame.from_dict({"image": make_column(px)})
+    got = np.stack(list(feat.transform(df).collect()["features"]))
+    assert [x.dtype for x in seen] == [dtype]
+    assert seen[0].shape == px.shape
+    assert got.shape == (n, 6) and np.array_equal(got, want)
+
+
+def test_image_featurizer_keeps_callers_image_column():
+    """The output table carries the caller's own image objects, not
+    widened copies, and one scorer (one ModelRunner) serves every dtype."""
+    px = _pixels(5, 8, 8)
+    col = _object_column(px)
+    feat = _image_featurizer()
+    scorer = feat._build_runner()
+    out = feat.transform(DataFrame.from_dict({"image": col}, 2)).collect()
+    assert all(a is b for a, b in zip(out["image"], col))
+    feat.transform(DataFrame.from_dict({"image": px.astype(np.float32)}))
+    assert feat._build_runner() is scorer
+    assert scorer.runner() is feat._build_runner().runner()
+
+
+def test_image_featurizer_mixed_shapes_fail_in_the_stack():
+    rows = [np.zeros((8, 8, 3), np.uint8), np.zeros((8, 1, 3), np.uint8)]
+    with pytest.raises(ValueError, match="shape"):
+        _image_featurizer().transform(
+            DataFrame.from_dict({"image": _object_column(rows)}))
+
+
+@pytest.mark.parametrize("dtype,bytes_per_row",
+                         [(np.uint8, 150_528), (np.float32, 602_112)])
+def test_runner_input_bytes_counter_counts_padded_rows(dtype, bytes_per_row):
+    """``mmlspark_runner_input_bytes_total`` is the witness of the input
+    path: bytes a row as the device receives them, over the PADDED rows."""
+    from mmlspark_tpu.observability import get_registry
+    feat = _image_featurizer(height=224, width=224)
+    px = _pixels(3, 224, 224).astype(dtype)
+    feat._build_runner()                  # the runner registers the family
+    fam = get_registry().family("mmlspark_runner_input_bytes_total")
+    labels = dict(runner="dl.jax_model", front="transform")
+    before = fam.value(**labels)
+    feat.transform(DataFrame.from_dict({"image": _object_column(px)}))
+    # 3 rows at batch_size 4 -> one chunk in its bucket of 4
+    assert fam.value(**labels) - before == 4 * bytes_per_row
+
+
 def test_bilstm_tagger_shapes():
     import jax
     import jax.numpy as jnp

@@ -304,7 +304,8 @@ def test_runner_books_front_and_decode_metrics():
     from mmlspark_tpu.observability import MetricsRegistry
 
     apply_src = _inspect.getsource(runner_mod.ModelRunner.apply_batch)
-    for needle in ("_c_batches[front]", "_c_rows[front]", "_c_pad"):
+    for needle in ("_c_batches[front]", "_c_rows[front]", "_c_pad",
+                   "_c_input_bytes[front]"):
         assert needle in apply_src, f"apply_batch lost {needle}"
     decode_src = _inspect.getsource(runner_mod.ModelRunner.decode)
     for needle in ("_c_decode_steps", "_c_decode_tokens"):
@@ -319,6 +320,7 @@ def test_runner_books_front_and_decode_metrics():
                            name="sweep", registry=reg)
     for family in ("mmlspark_runner_batches_total",
                    "mmlspark_runner_rows_total",
+                   "mmlspark_runner_input_bytes_total",
                    "mmlspark_runner_pad_rows_total",
                    "mmlspark_runner_decode_steps_total",
                    "mmlspark_runner_decode_tokens_total"):
