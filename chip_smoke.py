@@ -157,7 +157,7 @@ def phase_hist_exact(shapes=((100_000, 32, 8, 255), (1_000_000, 200, 16, 256))):
     import jax.numpy as jnp
     from mmlspark_tpu.ops import histogram as H
 
-    backend = H.resolve_quantized_backend("auto")
+    backend = H.xla_backend()
     out = []
     for n, f, nodes, bins in shapes:
         rng = np.random.default_rng(n)

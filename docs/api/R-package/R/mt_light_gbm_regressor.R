@@ -44,7 +44,7 @@
 #' @param cat_smooth grad/hess ratio smoothing when ordering categories for subset splits
 #' @param cat_l2 extra L2 regularization applied when scoring sorted-subset categorical splits
 #' @param max_cat_threshold max categories on the smaller side of a sorted-subset split
-#' @param use_quantized_grad quantized training (LightGBM 4.x): stochastically round per-row grad/hess to integer levels once per iteration and build packed integer histograms, rescaling only at split-gain time; unset = auto (on for accelerator backends, off on CPU; MMLSPARK_TPU_HIST_QUANT=0/1 overrides)
+#' @param use_quantized_grad quantized training (LightGBM 4.x): stochastically round per-row grad/hess to integer levels once per iteration and build packed integer histograms, rescaling only at split-gain time; unset = auto (on for accelerator backends, off on CPU)
 #' @param num_grad_quant_bins quantization levels for grad/hess under quantized training (reference name; 4-128, reference default 4 — 16 here holds every repo accuracy gate)
 #' @param checkpoint_dir directory for periodic atomic booster checkpoints: the run snapshots booster + iteration + PRNG state every checkpoint_every iterations and auto-resumes from the newest valid snapshot (docs/RESILIENCE.md: training fault tolerance)
 #' @param checkpoint_every checkpoint cadence in boosting iterations (0 = off; requires checkpoint_dir)

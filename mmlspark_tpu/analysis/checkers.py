@@ -41,7 +41,7 @@ _TRACING_ENTRY_POINTS = {
     "mmlspark_tpu.observability.compute.instrumented_jit",
     # Pallas kernel bodies are traced exactly like jitted functions — a
     # host clock/RNG/print inside one either constant-folds or breaks the
-    # Mosaic lowering outright (ISSUE 8: ops/pallas_histogram.py kernels)
+    # Mosaic lowering outright
     "pallas_call", "pl.pallas_call", "pallas.pallas_call",
     "jax.experimental.pallas.pallas_call",
 }

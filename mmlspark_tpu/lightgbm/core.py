@@ -126,7 +126,7 @@ class GBDTParams:
     # accumulated as packed integers (ops.histogram quantized builders) and
     # rescaled only at split-gain time; sibling subtraction is exact in
     # integer space.  None = auto: ON for accelerator backends, OFF on CPU
-    # (train() resolves it; MMLSPARK_TPU_HIST_QUANT=0/1 is the escape hatch)
+    # (train() resolves it)
     use_quantized_grad: Optional[bool] = None
     num_grad_quant_bins: int = 16
 
@@ -372,24 +372,6 @@ def _check_quant_psum_bound(use_quant: bool, quant_bins: int,
             f"{quant_bins} quantization bins — lower num_grad_quant_bins "
             "or disable use_quantized_grad")
 
-def _use_fused_frontier(use_quant: bool, axis_name, has_cat: bool,
-                        backend: str, num_bins: int,
-                        quant_bins: int) -> bool:
-    """ONE eligibility predicate for the fused Pallas frontier (ISSUE 8),
-    shared by both growers so they can never silently disagree on when the
-    kernel engages.  Single-shard quantized numerical-split path only —
-    sharded gains must run on the POST-psum global histogram, voting needs
-    the per-feature local gain table, and categorical candidates need the
-    sorted-subset scan; those paths keep the XLA split_gains (the pallas
-    BUILDER still serves them through ``build_quantized``'s dispatcher).
-    Resolved at trace time; ``train()`` keys its jit caches on every
-    histogram env knob."""
-    from ..ops import histogram as hist_ops
-    from ..ops import pallas_histogram as pl_hist
-    return (use_quant and axis_name is None and not has_cat
-            and hist_ops.resolve_quantized_backend(backend) == "pallas"
-            and pl_hist.pallas_supported(num_bins, quant_bins))
-
 
 class _CatTools:
     """Categorical split machinery shared by both growers: static masks, the
@@ -483,7 +465,6 @@ def make_tree_grower(max_depth: int, num_features: int, num_bins: int,
     import jax.numpy as jnp
     from ..models.gbdt import perfect_tree_children
     from ..ops import histogram as hist_ops
-    from ..ops import pallas_histogram as pl_hist
     from ..parallel.collectives import histogram_psum
 
     use_quant = bool(params.use_quantized_grad)
@@ -494,11 +475,6 @@ def make_tree_grower(max_depth: int, num_features: int, num_bins: int,
     I = 2 ** D - 1     # internal nodes
     L = 2 ** D         # leaves
     ct = _CatTools(params, F, B)
-    # fused Pallas frontier (ISSUE 8): build + sibling subtraction +
-    # split-gain scan in one VMEM-resident kernel (eligibility:
-    # _use_fused_frontier)
-    use_fused = _use_fused_frontier(use_quant, axis_name, ct.has_cat,
-                                    backend, B, quant_bins)
     cat_np, sub_np = ct.cat_np, ct.sub_np
     has_cat, has_subset = ct.has_cat, ct.has_subset
     sorted_prefix, winner_member = ct.sorted_prefix, ct.winner_member
@@ -647,13 +623,11 @@ def make_tree_grower(max_depth: int, num_features: int, num_bins: int,
             if d > 0 and not use_voting:
                 # LightGBM's SMALLER-child rule (by the previous level's
                 # split counts): rebuild only each parent's smaller child,
-                # sibling = parent - small.  One definition serving both
-                # the fused-kernel and XLA frontier paths below.
+                # sibling = parent - small.
                 with jax.named_scope(PHASE_HIST):
                     is_left = node % 2 == 0
                     in_small = is_left == small_left[node // 2]
                     small_node = jnp.where(hist_mask & in_small, node // 2, -1)
-            fused_d = False        # set by the fused branch when it engages
             if use_voting:
                 # voting-parallel (reference voting_parallel + topK): each
                 # shard ranks features by LOCAL gain, shards vote, and only
@@ -701,37 +675,6 @@ def make_tree_grower(max_depth: int, num_features: int, num_bins: int,
                         sub_b[sel])
                 hist_for_win = sel_hist
                 Fs = k2
-            elif use_fused and max(1, nodes_d // 2) <= \
-                    pl_hist.FUSED_MAX_NODES:
-                # fused Pallas frontier: the smaller-child build, the exact
-                # integer sibling subtraction AND the split-gain scan run
-                # in one VMEM-resident kernel; only the assembled child
-                # histograms (the next level's parent) and the per-node
-                # best-split record reach HBM.  Static per-level gate:
-                # past FUSED_MAX_NODES frontier parents the kernel's
-                # VMEM-resident blocks outgrow the tile-sizing budget, so
-                # deeper levels take the XLA branch below (bit-exact
-                # histograms; gains differ only by f32 cumsum rounding).
-                # One kernel, so one phase: it is booked to the histogram.
-                fused_d = True
-                with jax.named_scope(PHASE_HIST):
-                    if d == 0:
-                        hist_d, fused_best = pl_hist.fused_frontier(
-                            binned, qg, qh, jnp.where(hist_mask, node, -1), 1,
-                            B, g_scale, h_scale, feat_mask, edge_ok2,
-                            quant_bins=quant_bins, l1=l1, l2=l2,
-                            min_data=min_data, min_hess=min_hess)
-                    else:
-                        hist_d, fused_best = pl_hist.fused_frontier(
-                            binned, qg, qh, small_node, nodes_d // 2, B,
-                            g_scale, h_scale, feat_mask, edge_ok2,
-                            quant_bins=quant_bins, l1=l1, l2=l2,
-                            min_data=min_data, min_hess=min_hess,
-                            parent_hist=prev_hist, small_left=small_left,
-                            node_rows_bound=n // 2 + nodes_d)
-                prev_hist = hist_d
-                best_gain, bf, bb, bsel, tot3f = fused_best
-                Gp0, Hp0, Cp0 = tot3f[:, 0], tot3f[:, 1], tot3f[:, 2]
             else:
                 if d == 0:
                     with jax.named_scope(PHASE_HIST):
@@ -763,16 +706,15 @@ def make_tree_grower(max_depth: int, num_features: int, num_bins: int,
                 Fs = F
 
             with jax.named_scope(PHASE_SPLIT):
-                if not fused_d:
-                    flat = gain.reshape(nodes_d, Fs * B)
-                    best = jnp.argmax(flat, axis=1)
-                    best_gain = jnp.take_along_axis(flat, best[:, None],
-                                                    axis=1)[:, 0]
-                    bf_local = (best // B).astype(jnp.int32)
-                    bb = (best % B).astype(jnp.int32)
-                    bf = sel[jnp.arange(nodes_d), bf_local] \
-                        if sel is not None else bf_local
-                    bsel = pick[jnp.arange(nodes_d), bf_local, bb, :]  # left
+                flat = gain.reshape(nodes_d, Fs * B)
+                best = jnp.argmax(flat, axis=1)
+                best_gain = jnp.take_along_axis(flat, best[:, None],
+                                                axis=1)[:, 0]
+                bf_local = (best // B).astype(jnp.int32)
+                bb = (best % B).astype(jnp.int32)
+                bf = sel[jnp.arange(nodes_d), bf_local] \
+                    if sel is not None else bf_local
+                bsel = pick[jnp.arange(nodes_d), bf_local, bb, :]  # left
                 do_split = best_gain > min_gain
 
                 idx = off + jnp.arange(nodes_d)
@@ -840,8 +782,7 @@ def make_tree_grower(max_depth: int, num_features: int, num_bins: int,
     rc_const = jnp.asarray(rc_np)
     return grow
 
-def leafwise_store_dtype(n_bound, use_quant: bool, quant_bins: int,
-                         enabled: bool = True):
+def leafwise_store_dtype(n_bound, use_quant: bool, quant_bins: int):
     """Storage dtype for the leaf-wise grower's per-leaf histogram carry
     (the ``(L, F, B, 3)`` buffer sibling subtraction reads from).
 
@@ -854,23 +795,15 @@ def leafwise_store_dtype(n_bound, use_quant: bool, quant_bins: int,
     the stored histograms the dominant resident tensor, and 2-bit gradients
     (``num_grad_quant_bins=4``) stretch the int16 window to ~10.9k rows.
     ``n_bound=None`` (sharded without a declared global bound) and float
-    mode keep the wide dtypes.  ``MMLSPARK_TPU_HIST_STORE16=0`` is the
-    operational escape hatch (read at trace time, keyed into the jit
-    caches via ``_resolve_hist_backend``).
+    mode keep the wide dtypes.
     """
     import jax.numpy as jnp
     if not use_quant:
         return jnp.float32
     qh_cap = max(1, quant_bins - 1)
-    if enabled and n_bound is not None and int(n_bound) * qh_cap < (1 << 15):
+    if n_bound is not None and int(n_bound) * qh_cap < (1 << 15):
         return jnp.int16
     return jnp.int32
-
-
-def _store16_enabled() -> bool:
-    import os
-    raw = os.environ.get("MMLSPARK_TPU_HIST_STORE16", "").strip().lower()
-    return raw not in ("0", "false", "off", "no")
 
 
 def make_leafwise_grower(num_leaves: int, depth_cap: int, num_features: int,
@@ -901,22 +834,13 @@ def make_leafwise_grower(num_leaves: int, depth_cap: int, num_features: int,
     import jax
     import jax.numpy as jnp
     from ..ops import histogram as hist_ops
-    from ..ops import pallas_histogram as pl_hist
     from ..parallel.collectives import histogram_psum
 
     use_quant = bool(params.use_quantized_grad)
     quant_bins = params.num_grad_quant_bins
     _check_quant_psum_bound(use_quant, quant_bins, axis_name, psum_row_bound)
-    store16_ok = _store16_enabled()   # read OUTSIDE traced code; train()
-    #                                   keys its jit caches on the env knob
     L, M, F, B = num_leaves, num_leaves - 1, num_features, num_bins
     ct = _CatTools(params, F, B)
-    # fused Pallas frontier (ISSUE 8): per split step the left-child
-    # rebuild, the exact integer sibling subtraction against the stored
-    # carry and BOTH children's split-gain scans run in one VMEM-resident
-    # kernel (shared eligibility: _use_fused_frontier)
-    use_fused = _use_fused_frontier(use_quant, axis_name, ct.has_cat,
-                                    backend, B, quant_bins)
     cat_np, sub_np = ct.cat_np, ct.sub_np
     has_cat, has_subset = ct.has_cat, ct.has_subset
     l1, l2 = params.lambda_l1, params.lambda_l2
@@ -1101,19 +1025,9 @@ def make_leafwise_grower(num_leaves: int, depth_cap: int, num_features: int,
 
         # ---- root
         leaf_of_row = jnp.zeros((n,), jnp.int32)
-        if use_fused:
-            h_root1, fb_root = pl_hist.fused_frontier(
-                binned, qg, qh, jnp.where(hist_mask, 0, -1), 1, B,
-                g_scale, h_scale, feat_mask, edge_ok,
-                quant_bins=quant_bins, l1=l1, l2=l2, min_data=min_data,
-                min_hess=min_hess, depth_ok=depth_ok_of(0))
-            h_root = h_root1[0]
-            g0, f0, b0 = fb_root[0][0], fb_root[1][0], fb_root[2][0]
-            lp0, tot0, m0 = fb_root[3][0], fb_root[4][0], None
-        else:
-            h_root = psum_maybe(local_hist(hist_mask))
-            g0, f0, b0, lp0, tot0, m0 = best_of(h_root, feat_mask,
-                                                depth_ok_of(0))
+        h_root = psum_maybe(local_hist(hist_mask))
+        g0, f0, b0, lp0, tot0, m0 = best_of(h_root, feat_mask,
+                                            depth_ok_of(0))
 
         # stored-histogram carry dtype: int16 when the STATIC row bound
         # keeps every quantized cell under 15 bits (sums stay exact; the
@@ -1122,9 +1036,7 @@ def make_leafwise_grower(num_leaves: int, depth_cap: int, num_features: int,
         # or voting), the declared global psum bound when they are global.
         stored_bound = n if (axis_name is None or use_voting) \
             else psum_row_bound
-        st_dtype = leafwise_store_dtype(stored_bound, use_quant, quant_bins,
-                                        store16_ok) if use_quant \
-            else jnp.float32
+        st_dtype = leafwise_store_dtype(stored_bound, use_quant, quant_bins)
 
         carry0 = dict(
             leaf_of_row=leaf_of_row,
@@ -1217,32 +1129,14 @@ def make_leafwise_grower(num_leaves: int, depth_cap: int, num_features: int,
             c["leaf_depth"] = set_if(c["leaf_depth"], new_leaf, d_new, do, L)
 
             dok = depth_ok_of(d_new)
-            if use_fused:
-                # one fused kernel: left-child rebuild, exact integer
-                # sibling subtraction against the stored carry (widened
-                # from the int16 storage dtype — arithmetic stays int32),
-                # and both children's split-gain scans
-                pair, fb2 = pl_hist.fused_frontier(
-                    binned, qg, qh,
-                    jnp.where(hist_mask & (c["leaf_of_row"] == j), 0, -1),
-                    1, B, g_scale, h_scale, feat_mask, edge_ok,
-                    quant_bins=quant_bins, l1=l1, l2=l2,
-                    min_data=min_data, min_hess=min_hess,
-                    parent_hist=c["hists"][j].astype(jnp.int32)[None],
-                    small_left=jnp.ones((1,), bool), depth_ok=dok)
-                hl, hr = pair[0], pair[1]
-                gl, fl, bl, lpl = fb2[0][0], fb2[1][0], fb2[2][0], fb2[3][0]
-                gr, fr, br, lpr = fb2[0][1], fb2[1][1], fb2[2][1], fb2[3][1]
-                ml = mr = None
-            else:
-                hl = local_hist(hist_mask & (c["leaf_of_row"] == j))
-                if axis_name is not None and not use_voting:
-                    hl = psum_hist(hl)
-                # subtraction widens back to the build dtype: the int16
-                # carry is storage-only, the arithmetic stays exact int32
-                hr = c["hists"][j].astype(hl.dtype) - hl
-                gl, fl, bl, lpl, _, ml = best_of(hl, feat_mask, dok)
-                gr, fr, br, lpr, _, mr = best_of(hr, feat_mask, dok)
+            hl = local_hist(hist_mask & (c["leaf_of_row"] == j))
+            if axis_name is not None and not use_voting:
+                hl = psum_hist(hl)
+            # subtraction widens back to the build dtype: the int16
+            # carry is storage-only, the arithmetic stays exact int32
+            hr = c["hists"][j].astype(hl.dtype) - hl
+            gl, fl, bl, lpl, _, ml = best_of(hl, feat_mask, dok)
+            gr, fr, br, lpr, _, mr = best_of(hr, feat_mask, dok)
             c["hists"] = set_if(c["hists"], j, hl.astype(st_dtype), do, L)
             c["hists"] = set_if(c["hists"], new_leaf, hr.astype(st_dtype),
                                 do, L)
@@ -1435,24 +1329,16 @@ def default_metric(objective: str) -> str:
 # training driver
 # ---------------------------------------------------------------------------
 
-#: where ``MMLSPARK_TPU_HIST_QUANT`` sits in ``_resolve_hist_backend()``
-_HIST_CFG_QUANT = 4
-
-
-def _resolve_hist_backend() -> tuple:
-    """(backend, block_rows, lo_width, residuals) env knobs the growers will
-    trace with.  Resolved ONCE per train() call and made part of every jit
-    cache key: the env overrides are read at trace time, so without keying
-    on EVERY knob a cached program would silently keep serving a
-    previously-selected configuration.  Add any new histogram env knob to
-    this tuple."""
-    import os
-    return (os.environ.get("MMLSPARK_TPU_HIST_BACKEND", "auto"),
-            os.environ.get("MMLSPARK_TPU_HIST_BLOCK_ROWS", ""),
-            os.environ.get("MMLSPARK_TPU_HIST_LO", ""),
-            os.environ.get("MMLSPARK_TPU_HIST_RESID", ""),
-            os.environ.get("MMLSPARK_TPU_HIST_QUANT", ""),
-            os.environ.get("MMLSPARK_TPU_HIST_STORE16", ""))
+def _resolve_hist_path(p: GBDTParams) -> Tuple[str, bool]:
+    """(backend, quantized) one training call builds its histograms with:
+    the platform's builder family, and ``use_quantized_grad`` where the
+    caller set it, else packed integers on the TPU.  Resolved ONCE per
+    call and passed down; the backend is part of every jit cache key (the
+    params signature already carries the quantization)."""
+    quantized = p.use_quantized_grad
+    if quantized is None:
+        quantized = platform() != "cpu"
+    return xla_backend(), bool(quantized)
 
 
 def _make_grower(p: GBDTParams, F: int, B: int, axis_name: str = None,
@@ -1578,7 +1464,7 @@ def train(X: np.ndarray, y: np.ndarray, params: GBDTParams,
         # backend/quantized labels make A/B runs attributable on /metrics
         for _ in range(times):
             _phase_h.observe(seconds, _train_span.trace_id, phase=phase,
-                             backend=_eff_backend,
+                             backend=hist_backend,
                              quantized="1" if p.use_quantized_grad else "0")
         _phase_totals[phase] = _phase_totals.get(phase, 0.0) + seconds * times
 
@@ -1590,27 +1476,9 @@ def train(X: np.ndarray, y: np.ndarray, params: GBDTParams,
 
     p = params.resolve()
     # histogram backend + quantization resolution, up front so every phase
-    # observation below carries the effective (backend, quantized) labels.
-    # All env knobs are read at trace time and key the jit caches.
-    hist_cfg = _resolve_hist_backend()
-    hist_backend = hist_cfg[0]
-    _uq = p.use_quantized_grad
-    quant_env = hist_cfg[_HIST_CFG_QUANT].strip()
-    if quant_env:                        # MMLSPARK_TPU_HIST_QUANT=0/1
-        # case-insensitive: an operator's QUANT=OFF during an incident must
-        # never fail open into force-ENABLING the feature
-        _uq = quant_env.lower() not in ("0", "false", "off", "no")
-    if _uq is None:                      # auto: packed ints on the TPU
-        _uq = platform() != "cpu"
-    p = dataclasses.replace(p, use_quantized_grad=bool(_uq))
-    if hist_backend != "auto" and (p.use_quantized_grad
-                                   or hist_backend != "pallas"):
-        _eff_backend = hist_backend
-    else:
-        # auto — and the float path's explicit 'pallas' request, which
-        # build() maps to the float builders (the fused kernel is
-        # integer-only): the phase label must name what actually ran
-        _eff_backend = xla_backend()
+    # observation below carries the effective (backend, quantized) labels
+    hist_backend, _uq = _resolve_hist_path(p)
+    p = dataclasses.replace(p, use_quantized_grad=_uq)
     rng = np.random.default_rng(p.seed)
     X = np.asarray(X, np.float32)
     y = np.asarray(y, np.float32)
@@ -1672,7 +1540,7 @@ def train(X: np.ndarray, y: np.ndarray, params: GBDTParams,
                 sub.append(int(f_i))
         p = dataclasses.replace(p, cat_subset=tuple(sub))
 
-    sig = _params_sig(p) + (hist_cfg,)
+    sig = _params_sig(p) + (hist_backend,)
 
     # ---- fault tolerance (ISSUE 10/14): periodic atomic checkpoints +
     # resume through the warm-start machinery below.  The fingerprint is
@@ -2419,7 +2287,7 @@ def train(X: np.ndarray, y: np.ndarray, params: GBDTParams,
     _train_span.set_attribute("iterations", len(tree_weights) // K)
     _train_span.set_attribute("growth", p.growth)
     # the path that ran, for whoever reads the span (chip_smoke.py does)
-    _train_span.set_attribute("hist_backend", _eff_backend)
+    _train_span.set_attribute("hist_backend", hist_backend)
     _train_span.set_attribute("quantized", bool(p.use_quantized_grad))
     _train_span.set_attribute("chunk", CH if multi_iter is not None else 1)
     _extras = None
@@ -2739,21 +2607,14 @@ def train_streamed(X, y: Optional[np.ndarray] = None, params: GBDTParams = None,
                 f"features, dataset has {F}")
 
     # ---- backend / quantization resolution (same contract as train())
-    hist_cfg = _resolve_hist_backend()
-    hist_backend = hist_cfg[0]
-    _uq = p.use_quantized_grad
-    quant_env = hist_cfg[_HIST_CFG_QUANT].strip()
-    if quant_env:
-        _uq = quant_env.lower() not in ("0", "false", "off", "no")
-    if _uq is None:
-        _uq = platform() != "cpu"
-    p = dataclasses.replace(p, use_quantized_grad=bool(_uq))
+    hist_backend, _uq = _resolve_hist_path(p)
+    p = dataclasses.replace(p, use_quantized_grad=_uq)
     use_quant = p.use_quantized_grad
     qb = p.num_grad_quant_bins
     qg_cap = max(1, qb // 2)
     qh_cap = max(1, qb - 1)
     _check_quant_tile_bound(use_quant, qb, n)
-    sig = _params_sig(p) + (hist_cfg,)
+    sig = _params_sig(p) + (hist_backend,)
 
     _parent = current_span()
     _span = Span("lightgbm.train_streamed",

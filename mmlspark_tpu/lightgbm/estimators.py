@@ -83,7 +83,7 @@ def _shared_params(cls):
          "stochastically round per-row grad/hess to integer levels once "
          "per iteration and build packed integer histograms, rescaling "
          "only at split-gain time; unset = auto (on for accelerator "
-         "backends, off on CPU; MMLSPARK_TPU_HIST_QUANT=0/1 overrides)",
+         "backends, off on CPU)",
          "bool", None),
         ("num_grad_quant_bins", "quantization levels for grad/hess under "
          "quantized training (reference name; 4-128, reference default 4 — "

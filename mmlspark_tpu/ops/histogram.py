@@ -485,10 +485,8 @@ def _packed_layout(bound: int, quant_bins: int):
 
 
 def _pack_lanes(qg, qh, mode: str, cbits: int, hbits: int):
-    """Per-row packed int32 weight channels for a ``_packed_layout`` plan.
-    ONE definition shared by the XLA scatter builder and the Pallas kernel
-    — the cross-backend bit-exactness contract depends on both sides
-    packing (and ``_unpack_lanes`` decoding) identically."""
+    """Per-row packed int32 weight channels for a ``_packed_layout`` plan
+    (``_unpack_lanes`` decodes the accumulated sums)."""
     import jax.numpy as jnp
     KC, KH = 1 << cbits, 1 << hbits
     qg = qg.astype(jnp.int32)
@@ -658,63 +656,33 @@ def build_histograms_matmul_quantized(binned: jnp.ndarray, qg: jnp.ndarray,
     return jnp.moveaxis(hist, 0, -1)                                   # (P,F,B,3)
 
 
-def xla_backend() -> str:
-    """The XLA builder family for this platform, float or quantized:
-    ``scatter`` on CPU (where one-hot matmuls lose), ``matmul`` (the MXU
-    build) on TPU."""
-    return "scatter" if platform() == "cpu" else "matmul"
+#: what a caller may pass as ``backend``; ``auto`` resolves by platform
+BACKENDS = ("auto", "scatter", "matmul")
 
 
-def resolve_quantized_backend(backend: str = "auto") -> str:
-    """Resolve the quantized-build backend the way ``build_quantized``
-    will: explicit caller choice > ``MMLSPARK_TPU_HIST_BACKEND`` env >
-    platform auto (CPU -> scatter, TPU -> matmul).  The fused Pallas kernel
-    is never an auto choice: the Pallas TPU lowering refuses its block
-    shapes (ROADMAP D2), so ``pallas`` is by explicit request only — under
-    the interpreter on CPU, compiled-or-raising on TPU.  The growers call
-    this at trace time to decide whether the fused frontier path engages —
-    the env knobs are part of every jit cache key
-    (``lightgbm.core._resolve_hist_backend``)."""
-    import os
-    if backend == "auto":
-        backend = os.environ.get("MMLSPARK_TPU_HIST_BACKEND", "auto")
+def xla_backend(backend: str = "auto") -> str:
+    """The builder family, float or quantized: an explicit ``scatter`` or
+    ``matmul`` as given; ``auto`` is ``scatter`` on CPU (where one-hot
+    matmuls lose) and ``matmul`` (the MXU build) on TPU."""
+    if backend not in BACKENDS:
+        raise ValueError(f"unknown histogram backend {backend!r}: "
+                         f"expected one of {BACKENDS}")
     if backend != "auto":
         return backend
-    return xla_backend()
+    return "scatter" if platform() == "cpu" else "matmul"
 
 
 def build_quantized(binned, qg, qh, node_ids, num_nodes, num_bins,
                     quant_bins: int = 16, backend: str = "auto",
                     max_rows=None, node_rows_bound=None):
     """Quantized-path backend dispatcher, mirroring ``build``: 'auto' picks
-    the int8 MXU build on TPU and the packed int32 scatter on CPU;
-    ``MMLSPARK_TPU_HIST_BACKEND`` overrides only when the caller did not
-    request a specific backend.  Returns int32 (nodes, F, B, 3)
-    [sum_qg, sum_qh, count] — rescale with ``dequantize_histogram``."""
-    import os
-    backend = resolve_quantized_backend(backend)
-    if backend == "pallas":
-        from . import pallas_histogram as _plh
-        if _plh.pallas_supported(num_bins, quant_bins, num_nodes=num_nodes):
-            return _plh.build_histograms_pallas(
-                binned, qg, qh, node_ids, num_nodes, num_bins,
-                quant_bins=quant_bins, node_rows_bound=node_rows_bound,
-                max_rows=max_rows)
-        # clean fallback: unsupported shape (bins/quant range, or a node
-        # frontier wider than the kernel's VMEM node cap — deep-level/
-        # sharded/streamed builds) -> the XLA builders
-        backend = xla_backend()
-    if backend == "matmul":
-        kw = {}
-        block_rows = int(os.environ.get("MMLSPARK_TPU_HIST_BLOCK_ROWS", "0"))
-        if block_rows:
-            kw["block_rows"] = block_rows
-        lo = int(os.environ.get("MMLSPARK_TPU_HIST_LO", "0"))
-        if lo:
-            kw["lo_width"] = lo
+    the int8 MXU build on TPU and the packed int32 scatter on CPU.  Returns
+    int32 (nodes, F, B, 3) [sum_qg, sum_qh, count] — rescale with
+    ``dequantize_histogram``."""
+    if xla_backend(backend) == "matmul":
         return build_histograms_matmul_quantized(
             binned, qg, qh, node_ids, num_nodes, num_bins,
-            quant_bins=quant_bins, max_rows=max_rows, **kw)
+            quant_bins=quant_bins, max_rows=max_rows)
     return build_histograms_quantized(
         binned, qg, qh, node_ids, num_nodes, num_bins,
         quant_bins=quant_bins, node_rows_bound=node_rows_bound,
@@ -725,34 +693,11 @@ def build(binned, grad, hess, node_ids, num_nodes, num_bins,
           sample_weight=None, backend: str = "auto", max_rows=None):
     """Backend dispatcher.  'auto' picks the MXU matmul build on accelerator
     platforms (13x faster than scatter on v5e, measured) and the scatter
-    build on CPU (where one-hot matmuls lose).  The round-3/4 FLOAT Pallas
-    kernel was retired in round 5 (lost the shootout 3.5x, Mosaic
-    grad-channel drift — PARITY.md); its ISSUE-8 successor
-    (``ops.pallas_histogram``) is integer-only and lives on the QUANTIZED
-    path (``build_quantized``), so a 'pallas' request here falls back
-    cleanly to the surviving float builders.  Override via
-    MMLSPARK_TPU_HIST_BACKEND=matmul|scatter."""
-    import os
-    if backend == "auto":  # env override only applies when the caller did
-        backend = os.environ.get("MMLSPARK_TPU_HIST_BACKEND", backend)
-        # not request a specific backend (ADVICE r2)
-    if backend in ("auto", "pallas"):
-        backend = xla_backend()
-    # MXU tuning knobs (read at trace time; train() keys its jit caches on
-    # them): block size, lo one-hot width, residual channels on/off
-    block_rows = int(os.environ.get("MMLSPARK_TPU_HIST_BLOCK_ROWS", "0")) or None
-    if backend == "matmul":
-        kw = {}
-        if block_rows:
-            kw["block_rows"] = block_rows
-        lo = int(os.environ.get("MMLSPARK_TPU_HIST_LO", "0"))
-        if lo:
-            kw["lo_width"] = lo
-        if os.environ.get("MMLSPARK_TPU_HIST_RESID", "1") == "0":
-            kw["residuals"] = False
+    build on CPU (where one-hot matmuls lose)."""
+    if xla_backend(backend) == "matmul":
         return build_histograms_matmul(binned, grad, hess, node_ids,
                                        num_nodes, num_bins, sample_weight,
-                                       max_rows=max_rows, **kw)
+                                       max_rows=max_rows)
     # scatter drops masked rows natively; the max_rows bound is a no-op there
     return build_histograms(binned, grad, hess, node_ids, num_nodes, num_bins,
                             sample_weight)

@@ -48,6 +48,24 @@ def rng():
     return np.random.default_rng(42)
 
 
+@pytest.fixture()
+def as_platform(monkeypatch):
+    """``as_platform("tpu")`` answers ``platform()`` in the GBDT trainer's and
+    the histogram dispatchers' place, so that a test on the CPU takes the
+    paths the chip takes: the ``matmul`` builders, quantized gradients unless
+    the params say otherwise, four iterations a dispatch from 50,000 rows.
+    The same two attributes ``benchmark/tools/compile_for_v5e.py``
+    substitutes; the resolved backend is part of the trainer's jit-cache key,
+    so both platforms' programs live side by side in one process."""
+    from mmlspark_tpu.lightgbm import core
+    from mmlspark_tpu.ops import histogram as hist_ops
+
+    def answer(name):
+        monkeypatch.setattr(core, "platform", lambda: name)
+        monkeypatch.setattr(hist_ops, "platform", lambda: name)
+    return answer
+
+
 @pytest.fixture(scope="module", autouse=True)
 def _release_compiled_executables():
     """Drop every compiled executable between test modules.  Each one holds

@@ -201,8 +201,11 @@ def test_checkpoint_backend_marker_beats_mtime(tmp_path):
     assert int(forced.step) == 1                     # explicit wins
 
 
-def test_histogram_explicit_backend_not_overridden(monkeypatch):
-    """ADVICE r2: MMLSPARK_TPU_HIST_BACKEND only applies to backend='auto'."""
+def test_histogram_explicit_backend_honoured_unknown_raises(as_platform):
+    """An explicit backend is what runs whatever the platform's own choice
+    would be, and a name that is no backend raises: it is outside input,
+    and used to mean scatter silently."""
+    import jax
     import jax.numpy as jnp
     from mmlspark_tpu.ops import histogram as hist_ops
 
@@ -211,15 +214,26 @@ def test_histogram_explicit_backend_not_overridden(monkeypatch):
     g = jnp.asarray(rng.normal(size=64).astype(np.float32))
     h = jnp.ones(64, jnp.float32)
     node = jnp.zeros(64, jnp.int32)
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_BACKEND", "bogus_backend")
-    # explicit backend: env must NOT redirect (bogus would crash)
+
+    def dots(backend):          # the matmul builders contract, scatter never
+        jaxpr = jax.make_jaxpr(lambda: hist_ops.build(
+            binned, g, h, node, 1, 8, backend=backend))()
+        return "dot_general" in str(jaxpr)
+
+    assert not dots("auto") and not dots("scatter") and dots("matmul")
+    as_platform("tpu")
+    assert dots("auto") and not dots("scatter") and dots("matmul")
     out = hist_ops.build(binned, g, h, node, 1, 8, backend="scatter")
     assert out.shape == (1, 3, 8, 3)
-    # auto: env applies (bogus falls through to the scatter default — assert
-    # it selects *something* rather than crashing on the explicit path)
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_BACKEND", "matmul")
     out2 = hist_ops.build(binned, g, h, node, 1, 8, backend="auto")
     np.testing.assert_allclose(np.asarray(out), np.asarray(out2), atol=1e-4)
+    qg = jnp.ones(64, jnp.int32)
+    for name in ("bogus_backend", "pallas", ""):
+        with pytest.raises(ValueError, match="auto.*scatter.*matmul"):
+            hist_ops.build(binned, g, h, node, 1, 8, backend=name)
+        with pytest.raises(ValueError, match="auto.*scatter.*matmul"):
+            hist_ops.build_quantized(binned, qg, qg, node, 1, 8,
+                                     backend=name)
 
 
 def test_vw_bfgs_stats_count_packed_nnz_per_partition():

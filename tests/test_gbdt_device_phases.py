@@ -89,10 +89,12 @@ def _phase(name):
 
 @pytest.fixture(scope="module")
 def chip_path():
-    """The environment switches that make the CPU trace the chip's path."""
+    """Makes the CPU trace the chip's path: the trainer and the histogram
+    dispatchers are told they run on a TPU."""
+    from mmlspark_tpu.ops import histogram as hist_ops
     with pytest.MonkeyPatch.context() as mp:
-        mp.setenv("MMLSPARK_TPU_HIST_BACKEND", "matmul")
-        mp.setenv("MMLSPARK_TPU_GBDT_CHUNK", "4")
+        mp.setattr(core, "platform", lambda: "tpu")
+        mp.setattr(hist_ops, "platform", lambda: "tpu")
         yield
 
 

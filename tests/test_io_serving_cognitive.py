@@ -266,8 +266,19 @@ def test_binary_file_stream(tmp_path):
     import time
     from mmlspark_tpu.io.binary import BinaryFileStream
 
-    (tmp_path / "a.bin").write_bytes(b"alpha")
-    stream = BinaryFileStream(str(tmp_path), poll_interval_s=0.05)
+    watched = tmp_path / "watched"
+    watched.mkdir()
+
+    def produce(name, data):
+        # the producers' convention the stream documents: write under a
+        # temporary name outside the directory, then rename into it.  A
+        # file written in place can be polled between its creation and
+        # its write, and is then delivered empty, once.
+        (tmp_path / (name + ".tmp")).write_bytes(data)
+        (tmp_path / (name + ".tmp")).rename(watched / name)
+
+    produce("a.bin", b"alpha")
+    stream = BinaryFileStream(str(watched), poll_interval_s=0.05)
     b1 = stream.get_batch()
     assert sorted(p.split("/")[-1] for p in b1.collect()["path"]) == ["a.bin"]
     assert stream.get_batch() is None  # no new files -> no batch
@@ -275,8 +286,8 @@ def test_binary_file_stream(tmp_path):
     got = []
     handle = stream.for_each_batch(
         lambda df: got.extend(bytes(b) for b in df.collect()["bytes"]))
-    (tmp_path / "b.bin").write_bytes(b"beta")
-    (tmp_path / "c.bin").write_bytes(b"gamma")
+    produce("b.bin", b"beta")
+    produce("c.bin", b"gamma")
     deadline = time.monotonic() + 10
     while time.monotonic() < deadline and len(got) < 2:
         time.sleep(0.05)

@@ -239,20 +239,20 @@ def test_estimator_growth_level_with_explicit_num_leaves():
     assert m.booster.num_leaves == 64
 
 
-def test_leafwise_matmul_backend_agrees(monkeypatch):
-    """Leaf-wise growth through the MXU matmul histogram backend (the
-    accelerator default) must match the scatter build — the TPU-default
-    combination a LightGBM user gets with plain num_leaves params."""
+def test_leafwise_matmul_backend_agrees(as_platform):
+    """Leaf-wise growth through the float MXU matmul histogram backend
+    (the accelerator's, with quantization turned off) must match the
+    scatter build."""
     import numpy as np
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_BACKEND", "matmul")
     from mmlspark_tpu.lightgbm import GBDTParams, train
     rng = np.random.default_rng(17)
     X = rng.normal(size=(3000, 8)).astype(np.float32)
     y = (X[:, 0] + 0.4 * X[:, 1] > 0.2).astype(np.float32)
-    r_m = train(X, y, GBDTParams(num_iterations=6, num_leaves=15,
-                                 min_data_in_leaf=5))
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_BACKEND", "scatter")
-    r_s = train(X, y, GBDTParams(num_iterations=6, num_leaves=15,
-                                 min_data_in_leaf=5))
+    params = GBDTParams(num_iterations=6, num_leaves=15, min_data_in_leaf=5,
+                        use_quantized_grad=False)
+    as_platform("tpu")
+    r_m = train(X, y, params)
+    as_platform("cpu")
+    r_s = train(X, y, params)
     a, b = r_m.booster.predict(X), r_s.booster.predict(X)
     assert np.allclose(a, b, atol=5e-4), float(np.abs(a - b).max())

@@ -182,8 +182,7 @@ def test_histogram_backends_agree(p):
     assert float(jnp.max(jnp.abs(a - m))) < 1e-3
     # count channel must be exactly integral
     assert float(jnp.max(jnp.abs(m[..., 2] - jnp.round(m[..., 2])))) == 0.0
-    # every tuning-knob combination (production-reachable via the
-    # MMLSPARK_TPU_HIST_LO / _RESID / _BLOCK_ROWS envs) must agree too:
+    # every combination of the float builder's arguments must agree too:
     # residual channels keep f32-exactness, bf16-rounded inputs bound 2e-3
     for lo in (32, 64, 128):
         for resid, tol in ((True, 1e-3), (False, 2e-3)):
@@ -364,21 +363,23 @@ def test_wide_node_buffers_agree_with_scatter(p):
     assert float(jnp.max(jnp.abs(ref - fm))) < 1e-3
 
 
-def test_histogram_env_knobs_drive_training(monkeypatch):
-    # the env-tuned matmul path must produce an equivalent booster through
-    # the full train() flow (the jit cache is keyed on the knobs)
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_BACKEND", "matmul")
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_BLOCK_ROWS", "512")
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_LO", "64")
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_RESID", "0")
+def test_float_matmul_builder_drives_training(as_platform):
+    # the float matmul build (the chip's builder where a caller turns
+    # quantization off) must produce an equivalent booster through the
+    # full train() flow
     from mmlspark_tpu.lightgbm import GBDTParams, train
     rng = np.random.default_rng(11)
     X = rng.normal(size=(2000, 8)).astype(np.float32)
     y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.float32)
-    r = train(X, y, GBDTParams(num_iterations=5, max_depth=4,
-                               objective="binary"))
-    acc = ((r.booster.predict(X) > 0.5) == y).mean()
-    assert acc > 0.9, acc
+    accs = {}
+    for platform, backend in (("tpu", "matmul"), ("cpu", "scatter")):
+        as_platform(platform)
+        r = train(X, y, GBDTParams(num_iterations=5, max_depth=4,
+                                   objective="binary",
+                                   use_quantized_grad=False))
+        accs[backend] = ((r.booster.predict(X) > 0.5) == y).mean()
+    assert accs["matmul"] > 0.9, accs
+    assert abs(accs["matmul"] - accs["scatter"]) <= 0.01, accs
 
 
 def test_chunked_training_matches_unchunked(monkeypatch):

@@ -16,7 +16,7 @@ Guarantee layers, mirroring test_quantized_parity's structure:
    dataset exceeding a configured device-memory budget trains through
    forced small tiles with the transfer/overlap telemetry booked.
 4. **Leaf-wise int16 storage** — the narrowed stored-histogram carry is
-   lossless: bit-identical boosters with the knob on and off.
+   lossless: bit-identical boosters with an int16 and an int32 carry.
 """
 import threading
 import time
@@ -191,13 +191,17 @@ def test_prefetch_early_exit_retires_worker():
 
 # ------------------------------------------------------ integer exactness
 
-def test_tile_partial_accumulation_is_bit_exact():
+@pytest.mark.parametrize("builder,T", [
+    ("scatter", 1100),                       # 4000 % 1100 != 0
+    ("matmul", 4000), ("matmul", 1000), ("matmul", 1100)])
+def test_tile_partial_accumulation_is_bit_exact(builder, T):
     """Sum over per-tile quantized builds == the monolithic quantized build
-    (same integer gradients), including an uneven final tile — the property
-    the streamed driver's histogram accumulation rests on."""
+    (same integer gradients) over one tile, even tiles and an uneven final
+    tile — the property the streamed driver's histogram accumulation rests
+    on, for the packed scatter and for the chip's int8 matmul build."""
     import jax.numpy as jnp
     from mmlspark_tpu.ops import histogram as H
-    n, f, b, p, T = 4000, 5, 127, 8, 1100    # 4000 % 1100 != 0
+    n, f, b, p = 4000, 5, 127, 8
     rng = np.random.default_rng(3)
     binned = jnp.asarray(rng.integers(0, b, (n, f)).astype(np.uint8))
     g = jnp.asarray(rng.normal(size=n).astype(np.float32))
@@ -208,9 +212,9 @@ def test_tile_partial_accumulation_is_bit_exact():
     acc = jnp.zeros_like(mono)
     for lo in range(0, n, T):
         hi = min(lo + T, n)
-        acc = acc + H.build_histograms_quantized(
+        acc = acc + H.build_quantized(
             binned[lo:hi], qg[lo:hi], qh[lo:hi], node[lo:hi], p, b,
-            node_rows_bound=hi - lo)
+            backend=builder, node_rows_bound=hi - lo)
     assert acc.dtype == jnp.int32
     assert bool(jnp.all(acc == mono))
 
@@ -340,6 +344,34 @@ def test_streamed_leafwise_parity_quick():
     assert _acc(r_str, X, y) >= _acc(r_mem, X, y) - 0.02
 
 
+@pytest.mark.parametrize("growth", [dict(max_depth=4),
+                                    dict(num_leaves=11, min_data_in_leaf=5)],
+                         ids=["level", "leaf"])
+def test_streamed_training_identical_across_backends(as_platform, growth):
+    """The per-tile integer partials are bit-exact in both builders, and
+    every split decision downstream is a pure function of the accumulated
+    integers — so the streamed driver must produce the IDENTICAL booster
+    through the packed scatter and through the chip's int8 matmul build."""
+    from mmlspark_tpu.lightgbm import GBDTParams, train_streamed
+    rng = np.random.default_rng(17)
+    X = rng.normal(size=(3000, 6)).astype(np.float32)
+    y = (3 * X[:, 0] - 2 * X[:, 1] + X[:, 2] ** 2
+         + rng.normal(scale=0.3, size=3000)).astype(np.float32)
+    boosters = {}
+    for platform in ("cpu", "tpu"):
+        as_platform(platform)
+        boosters[platform] = train_streamed(
+            X, y, GBDTParams(num_iterations=4, objective="regression",
+                             seed=3, use_quantized_grad=True, **growth),
+            tile_rows=700).booster
+    a, b = boosters["cpu"], boosters["tpu"]
+    assert (a.split_feature >= 0).sum() >= 4 * 5
+    for key in ("split_feature", "threshold_bin", "left_child",
+                "right_child", "leaf_value", "leaf_count"):
+        np.testing.assert_array_equal(getattr(a, key), getattr(b, key),
+                                      err_msg=key)
+
+
 def test_dataset_larger_than_device_budget_trains():
     """ISSUE 7 acceptance: a dataset exceeding a configured device-memory
     budget trains through forced small tiles, with the transfer counters
@@ -405,7 +437,6 @@ def test_leafwise_store_dtype_gate():
     assert leafwise_store_dtype(11_000, True, 4) == jnp.int32
     assert leafwise_store_dtype(1_000_000, True, 16) == jnp.int32
     assert leafwise_store_dtype(None, True, 16) == jnp.int32
-    assert leafwise_store_dtype(2000, True, 16, enabled=False) == jnp.int32
     assert leafwise_store_dtype(2000, False, 16) == jnp.float32
 
 
@@ -413,19 +444,24 @@ def test_leafwise_int16_storage_is_lossless(monkeypatch):
     """int16 vs int32 stored carry must be indistinguishable in output —
     the narrowing is storage-only (arithmetic stays int32)."""
     from mmlspark_tpu.lightgbm import GBDTParams, train
+    import jax.numpy as jnp
+    from mmlspark_tpu.lightgbm import core
     X, y = _parity_data(seed=31, n=1500)   # 1500*15 < 2^15: int16 engages
-    boosters = {}
-    for knob in ("", "0"):
-        if knob:
-            monkeypatch.setenv("MMLSPARK_TPU_HIST_STORE16", knob)
-        else:
-            monkeypatch.delenv("MMLSPARK_TPU_HIST_STORE16", raising=False)
+    assert core.leafwise_store_dtype(len(y), True, 16) == jnp.int16
+    boosters, asked = {}, []
+    for carry in ("int16", "int32"):
+        if carry == "int32":
+            monkeypatch.setattr(core, "leafwise_store_dtype",
+                                lambda *a: asked.append(a) or jnp.int32)
+            core._JIT_CACHE.clear()     # the dtype is no part of the key
         r = train(X, y, GBDTParams(num_iterations=8, num_leaves=15,
                                    objective="binary", seed=3,
                                    min_data_in_leaf=5,
                                    use_quantized_grad=True))
-        boosters[knob or "on"] = r.booster
-    a, b = boosters["on"], boosters["0"]
+        boosters[carry] = r.booster
+    core._JIT_CACHE.clear()
+    assert asked == [(len(y), True, 16)]
+    a, b = boosters["int16"], boosters["int32"]
     for key in ("split_feature", "threshold_bin", "left_child",
                 "right_child", "leaf_value", "leaf_count", "split_gain"):
         assert np.array_equal(getattr(a, key), getattr(b, key)), key

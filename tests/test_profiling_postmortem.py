@@ -31,12 +31,22 @@ from mmlspark_tpu.core.logging import recent_events
 from mmlspark_tpu.observability import MetricsRegistry
 from mmlspark_tpu.observability.flightrecorder import (FlightRecorder,
                                                        get_flight_recorder)
-from mmlspark_tpu.observability.profiling import (MAX_HZ, ProfilerBusy,
+from mmlspark_tpu.observability.profiling import (MAX_HZ, UNATTRIBUTED,
+                                                  ProfilerBusy,
                                                   SamplingProfiler,
                                                   profile_window)
 from mmlspark_tpu.observability.tracing import (ambient_phase, thread_phases,
                                                 trace_span)
 from tests.serving_helpers import Doubler
+
+
+def _largest_attributed_span(rep):
+    """The span with the most samples in a window's report, leaving out
+    what no span claimed: whatever other threads the process runs (a test
+    runner's workers among them) are sampled too, and carry no phase."""
+    attributed = {span: count for span, count in rep["by_span"].items()
+                  if span != UNATTRIBUTED}
+    return max(attributed, key=attributed.get) if attributed else None
 
 
 def _frame_of(fn):
@@ -147,9 +157,10 @@ def test_trace_span_and_ambient_phase_maintain_thread_table():
 
 def test_profile_window_attributes_busy_thread_and_rejects_concurrent():
     """The worked contract at module level: a busy thread under an
-    ambient phase dominates the window's by-span rollup (the window's own
-    sleeping caller is idle-excluded), and a second concurrent window is
-    refused (two samplers would double the overhead both measure)."""
+    ambient phase is the largest attributed span of the window's by-span
+    rollup (the window's own sleeping caller is idle-excluded), and a
+    second concurrent window is refused (two samplers would double the
+    overhead both measure)."""
     reg = MetricsRegistry()
     stop = threading.Event()
 
@@ -167,7 +178,7 @@ def test_profile_window_attributes_busy_thread_and_rejects_concurrent():
         stop.set()
         t.join(timeout=5)
     assert rep["samples"] > 0
-    assert rep["by_span"].get("busy.phase", 0) >= rep["samples"] / 2
+    assert _largest_attributed_span(rep) == "busy.phase"
     assert rep["requested_seconds"] == 0.3
     assert any(e["span"] == "busy.phase" for e in rep["stacks"])
     # concurrency: hold the window lock, the next window must refuse
@@ -470,7 +481,8 @@ def test_debug_profile_endpoint_reports_clamps_and_409():
         base = f"http://127.0.0.1:{srv.port}"
         status, rep = _get(base + "/debug/profile?seconds=0.3&hz=200")
         assert status == 200
-        assert rep["by_span"].get("echo.busy", 0) >= rep["samples"] / 2
+        assert _largest_attributed_span(rep) == "echo.busy"
+        assert any(e["span"] == "echo.busy" for e in rep["stacks"])
         # bad params reply 400, a held window replies 409
         req = urllib.request.Request(base + "/debug/profile?seconds=abc")
         with pytest.raises(urllib.error.HTTPError) as err:

@@ -72,6 +72,62 @@ def test_packed_backends_agree_bit_for_bit():
     assert float(jnp.max(jnp.abs(ref - sc.astype(jnp.float32)))) == 0.0
 
 
+def _int8_matmul_cases():
+    """Inputs the chip's builder (``build_histograms_matmul_quantized``) must
+    take to the packed scatter's exact integers: name -> (n, f, bins, nodes,
+    quant_bins, block_rows, how the node ids are made)."""
+    def uniform(rng, n, p):
+        return rng.integers(-1, p, n)
+
+    def all_masked(rng, n, p):
+        return np.full(n, -1)
+
+    def first_node_empty(rng, n, p):
+        return rng.integers(1, p, n)
+
+    return {
+        # 1537 = 3 * 512 + 1: the last block holds one row
+        "ragged_last_block": (1537, 10, 255, 8, 16, 512, uniform),
+        # fewer rows than the smallest block the builder makes (256)
+        "rows_under_one_block": (100, 3, 63, 2, 16, 4096, uniform),
+        # a bagged-out level: every row carries node_id = -1
+        "every_row_masked": (700, 4, 63, 4, 16, 256, all_masked),
+        # the level-1 shape with no row in the left child
+        "empty_first_node": (900, 5, 63, 2, 16, 256, first_node_empty),
+        "one_feature": (1000, 1, 255, 4, 16, 256, uniform),
+        # 256 bins: the whole uint8 range, 16 hi x 16 lo with no remainder
+        "bins_256": (2000, 6, 256, 4, 16, 256, uniform),
+        # 17 bins: a second hi group of one bin
+        "bins_17": (1200, 7, 17, 4, 16, 256, uniform),
+        "quant_bins_4": (2000, 6, 255, 8, 4, 256, uniform),
+        "quant_bins_128": (2000, 6, 255, 8, 128, 256, uniform),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_int8_matmul_cases()))
+def test_int8_matmul_build_matches_packed_scatter(case):
+    import jax.numpy as jnp
+    from mmlspark_tpu.ops import histogram as H
+    n, f, b, p, qb, block_rows, nodes_of = _int8_matmul_cases()[case]
+    rng = np.random.default_rng(len(case))
+    binned = jnp.asarray(rng.integers(0, b, (n, f)).astype(np.uint8))
+    g = jnp.asarray(rng.normal(size=n).astype(np.float32))
+    h = jnp.asarray(rng.uniform(0.01, 1, n).astype(np.float32))
+    node = jnp.asarray(nodes_of(rng, n, p).astype(np.int32))
+    qg, qh, _, _ = H.quantize_gradients(g, h, qb, seed=3)
+    sc = H.build_histograms_quantized(binned, qg, qh, node, p, b,
+                                      quant_bins=qb)
+    mm = H.build_histograms_matmul_quantized(binned, qg, qh, node, p, b,
+                                             quant_bins=qb,
+                                             block_rows=block_rows)
+    assert mm.dtype == jnp.int32 and mm.shape == (p, f, b, 3)
+    assert bool(jnp.all(sc == mm))
+    kept = int((np.asarray(node) >= 0).sum())
+    assert int(mm[..., 2].sum()) == kept * f
+    if case == "bins_256":
+        assert int(mm[:, :, 255, 2].sum()) > 0      # the last bin is used
+
+
 def test_packed_lane_layouts_decode_identically():
     """all3 (one segment-sum) / 2ch / wide must be indistinguishable in
     output — the bit-width widening is a pure layout decision."""
@@ -121,9 +177,11 @@ def test_sibling_subtraction_exact_in_integer_space():
         assert bool(jnp.all(hp - hl == hr)), build
 
 
-def test_packed_histogram_psum_matches_global_build(mesh8):
+@pytest.mark.parametrize("builder", ["scatter", "matmul"])
+def test_packed_histogram_psum_matches_global_build(mesh8, builder):
     """The packed int32 allreduce (grad+hess lanes share one channel when
-    the global row bound allows) must equal the single-shard build."""
+    the global row bound allows) must equal the single-shard build, from
+    either builder's per-shard histograms."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -140,8 +198,8 @@ def test_packed_histogram_psum_matches_global_build(mesh8):
     qg, qh, _, _ = H.quantize_gradients(g, h, 16, seed=1)
 
     def local_then_psum(bq, qgq, qhq, nq):
-        local = H.build_histograms_quantized(bq, qgq, qhq, nq, p, b,
-                                             quant_bins=16)
+        local = H.build_quantized(bq, qgq, qhq, nq, p, b, quant_bins=16,
+                                  backend=builder)
         return histogram_psum(local, AXIS_DATA, row_bound=n, quant_bins=16)
 
     sharded = jax.jit(jax.shard_map(
@@ -158,6 +216,12 @@ def test_packed_histogram_psum_matches_global_build(mesh8):
 def _frame(X, y):
     return DataFrame.from_dict({"features": vector_column(list(X)),
                                 "label": y.astype(float)}, 2)
+
+
+def _trees(booster):
+    return {k: np.asarray(getattr(booster, k)) for k in (
+        "split_feature", "threshold_bin", "threshold", "split_gain",
+        "leaf_value", "internal_count", "leaf_count")}
 
 
 def test_quantized_classifier_parity_quick():
@@ -192,38 +256,42 @@ def test_quantized_regressor_parity_quick():
     assert mses[True] <= mses[False] * 1.35 + 0.05, mses
 
 
-def test_quant_env_hatch_and_phase_labels(monkeypatch):
-    """MMLSPARK_TPU_HIST_QUANT overrides the param in BOTH directions, and
-    the phase histogram books attributable (backend, quantized) children."""
+def test_quantization_follows_the_param_and_phase_labels(as_platform):
+    """``use_quantized_grad`` decides in BOTH directions whatever the
+    platform's own choice is (unset, the platform decides), and the phase
+    histogram books attributable (backend, quantized) children."""
     from mmlspark_tpu.lightgbm import GBDTParams, train
     from mmlspark_tpu.observability import get_registry
     rng = np.random.default_rng(0)
     X = rng.normal(size=(600, 5)).astype(np.float32)
     y = (X[:, 0] > 0).astype(np.float32)
 
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_QUANT", "1")
-    train(X, y, GBDTParams(num_iterations=3, max_depth=3, objective="binary"))
+    def counts():
+        fam = get_registry().family("mmlspark_lightgbm_phase_seconds")
+        return {k[1:]: child.count for k, child in fam._snapshot()
+                if k[0] == "histogram_split_update"} if fam else {}
+
+    def booked(**kw):
+        before = counts()
+        train(X, y, GBDTParams(num_iterations=3, max_depth=3,
+                               objective="binary", **kw))
+        return {k for k, c in counts().items() if c != before.get(k, 0)}
+
+    assert booked() == {("scatter", "0")}                 # the CPU's own
+    assert booked(use_quantized_grad=True) == {("scatter", "1")}
+    as_platform("tpu")
+    assert booked() == {("matmul", "1")}                  # the chip's own
+    assert booked(use_quantized_grad=False) == {("matmul", "0")}
     fam = get_registry().family("mmlspark_lightgbm_phase_seconds")
     assert fam.label_names == ("phase", "backend", "quantized")
-    keys = {k for k, _ in fam._snapshot()}
-    assert ("histogram_split_update", "scatter", "1") in keys
-    # env=0 beats an explicit param True (operational kill switch), and
-    # the comparison is case-insensitive — QUANT=OFF must never fail open
-    # into force-enabling the feature
-    for off_token in ("0", "OFF", " False "):
-        monkeypatch.setenv("MMLSPARK_TPU_HIST_QUANT", off_token)
-        train(X, y, GBDTParams(num_iterations=3, max_depth=3,
-                               objective="binary", use_quantized_grad=True))
-        keys = {k for k, _ in fam._snapshot()}
-        assert ("histogram_split_update", "scatter", "0") in keys, off_token
 
 
-def test_matmul_and_scatter_backends_grow_the_same_trees(monkeypatch):
+def test_matmul_and_scatter_backends_grow_the_same_trees(as_platform):
     """Integer histograms are exact in both builders, so a quantized fit
     through the int8 matmul build (one node unsorted at the root, sorted
     block slices below) takes the same splits as through the packed
     scatter."""
-    from mmlspark_tpu.lightgbm import GBDTParams, core, train
+    from mmlspark_tpu.lightgbm import GBDTParams, train
     rng = np.random.default_rng(5)
     X = rng.normal(size=(700, 6)).astype(np.float32)
     y = (X[:, 0] + 0.5 * X[:, 1] + 0.3 * rng.normal(size=700) > 0)
@@ -231,17 +299,118 @@ def test_matmul_and_scatter_backends_grow_the_same_trees(monkeypatch):
                         min_data_in_leaf=5, use_quantized_grad=True,
                         bagging_fraction=0.8, bagging_freq=1)
 
-    def fit(backend):
-        monkeypatch.setenv("MMLSPARK_TPU_HIST_BACKEND", backend)
-        b = train(X, y.astype(np.float32), params).booster
-        return {k: np.asarray(getattr(b, k)) for k in (
-            "split_feature", "threshold_bin", "threshold", "leaf_value",
-            "internal_count", "leaf_count")}
+    def fit(platform):
+        as_platform(platform)
+        return _trees(train(X, y.astype(np.float32), params).booster)
 
-    mm, sc = fit("matmul"), fit("scatter")
+    mm, sc = fit("tpu"), fit("cpu")
     assert (mm["split_feature"] >= 0).sum() >= 4 * 3
     for key in mm:
         np.testing.assert_array_equal(mm[key], sc[key], err_msg=key)
+
+
+def _train_span_facts(trace_id):
+    from mmlspark_tpu.observability.collector import get_collector
+    (span,) = [s for s in get_collector().trace(trace_id)
+               if s.name == "lightgbm.train"]
+    return {k: span.attributes[k] for k in ("hist_backend", "quantized",
+                                            "chunk")}
+
+
+@pytest.mark.parametrize("platform,rows,facts", [
+    ("cpu", 600, dict(hist_backend="scatter", quantized=False, chunk=1)),
+    ("tpu", 600, dict(hist_backend="matmul", quantized=True, chunk=1)),
+    # what BENCHMARK.json's GBDT cells expect of a one-chip fit
+    ("tpu", 50_000, dict(hist_backend="matmul", quantized=True, chunk=4))])
+def test_the_platform_chooses_the_path_and_the_span_says_which(
+        as_platform, platform, rows, facts):
+    """The builder family is a function of the platform alone and is decided
+    in ``xla_backend``; ``train()`` writes the path it took on its span,
+    where ``chip_smoke.py`` and ``benchmark/families/gbdt.py`` read it."""
+    from mmlspark_tpu.lightgbm import GBDTParams, train
+    from mmlspark_tpu.observability.tracing import trace_span
+    from mmlspark_tpu.ops import histogram as H
+    as_platform(platform)
+    assert H.xla_backend() == H.xla_backend("auto") == facts["hist_backend"]
+    assert H.xla_backend("scatter") == "scatter"
+    assert H.xla_backend("matmul") == "matmul"
+    rng = np.random.default_rng(1)
+    X = rng.normal(size=(rows, 5)).astype(np.float32)
+    y = (X[:, 0] > 0).astype(np.float32)
+    with trace_span("test.path") as sp:
+        r = train(X, y, GBDTParams(num_iterations=8, max_depth=3, max_bin=63,
+                                   objective="binary"))
+    assert _train_span_facts(sp.trace_id) == facts
+    assert ((r.booster.predict(X) > 0.5) == y).mean() > 0.9
+
+
+def _gbdt_cache_entries():
+    from mmlspark_tpu.lightgbm import core
+    return set(core._JIT_CACHE)
+
+
+def test_two_platforms_in_one_process_share_no_program(as_platform):
+    """The resolved backend is part of the jit-cache key: a fit told it
+    runs on a TPU and a plain one, at the same shape and with the SAME
+    explicit params, get a program each, and each grows its own builder's
+    trees.  (Keyed on the environment alone, the second was served the
+    first one's program.)"""
+    from mmlspark_tpu.lightgbm import GBDTParams, train
+    rng = np.random.default_rng(9)
+    X = rng.normal(size=(900, 6)).astype(np.float32)
+    y = (X[:, 0] - 0.5 * X[:, 2] > 0).astype(np.float32)
+    params = GBDTParams(num_iterations=3, max_depth=3, objective="binary",
+                        use_quantized_grad=False, seed=4)
+    before = _gbdt_cache_entries()
+    as_platform("tpu")
+    b_tpu = train(X, y, params).booster
+    on_tpu = _gbdt_cache_entries() - before
+    as_platform("cpu")
+    b_cpu = train(X, y, params).booster
+    on_cpu = _gbdt_cache_entries() - before - on_tpu
+
+    def keyed_on(entries, backend):
+        return {k for k in entries if repr(backend) in repr(k)}
+
+    # (the tree walker and the valid-score update depend on no builder)
+    assert keyed_on(on_tpu, "matmul") and not keyed_on(on_tpu, "scatter")
+    assert keyed_on(on_cpu, "scatter") and not keyed_on(on_cpu, "matmul")
+    assert len(keyed_on(on_cpu, "scatter")) == len(keyed_on(on_tpu, "matmul"))
+    # the float builders round differently, so shared programs would show
+    # as identical gains: same splits, gains that differ in the last bits
+    t, c = _trees(b_tpu), _trees(b_cpu)
+    np.testing.assert_array_equal(t["split_feature"], c["split_feature"])
+    assert not np.array_equal(t["split_gain"], c["split_gain"])
+    np.testing.assert_allclose(t["split_gain"], c["split_gain"], rtol=1e-3)
+
+
+def test_the_former_histogram_switches_change_nothing(monkeypatch):
+    """MMLSPARK_TPU_HIST_BACKEND / _BLOCK_ROWS / _LO / _RESID / _QUANT /
+    _STORE16 chose the builder once and were keyed into every jit cache.
+    Nothing reads them now: set to nonsense, a fit returns the same trees
+    bit for bit from the same cached programs."""
+    from mmlspark_tpu.lightgbm import GBDTParams, train
+    rng = np.random.default_rng(13)
+    X = rng.normal(size=(800, 5)).astype(np.float32)
+    y = (X[:, 0] + X[:, 1] > 0).astype(np.float32)
+    fits = [dict(max_depth=3, use_quantized_grad=True),
+            dict(num_leaves=7, use_quantized_grad=True),
+            dict(max_depth=3)]
+
+    def run():
+        return [_trees(train(X, y, GBDTParams(
+            num_iterations=3, objective="binary", seed=2, **kw)).booster)
+            for kw in fits]
+
+    plain = run()
+    entries = _gbdt_cache_entries()
+    for name in ("BACKEND", "BLOCK_ROWS", "LO", "RESID", "QUANT", "STORE16"):
+        monkeypatch.setenv("MMLSPARK_TPU_HIST_" + name, "nonsense")
+    again = run()
+    assert _gbdt_cache_entries() == entries
+    for a, b in zip(plain, again):
+        for key in a:
+            np.testing.assert_array_equal(a[key], b[key], err_msg=key)
 
 
 def test_sharded_overflow_guard_uses_global_row_bound():
