@@ -8,9 +8,12 @@ XLA pipelines HBM loads and the MXU convolutions in one program, and head
 truncation is the model's ``features=True`` path.
 
 Pixels keep the dtype the table holds them in until they are on the device.
-The host copies a partition's images exactly once, into one dense
-``(n, H, W, C)`` array (``_ImageScorer._stack_input``): ``uint8`` when
-every image is ``uint8``, else ``float32`` made in that same copy.  The
+The host copies a partition's images exactly once, and not all at once:
+``_ImageScorer._stack_input`` hands the runner the rows and their dtype
+(``uint8`` when every image is ``uint8``, else ``float32`` made in that
+same copy), and ``ModelRunner.apply_batch`` stacks them one device batch
+at a time into two staging buffers it keeps between transforms, filling
+one while the device scores the batch read from the other (PR 32).  The
 widening to float32
 is the first operation of the jitted ``fused`` program, never the host's:
 it is exact, the device does it inside the fusion that already reads the
@@ -26,6 +29,7 @@ import numpy as np
 
 from ..core import ComplexParam, DataFrame, HasInputCol, HasOutputCol, Model, Param
 from ..core.schema import ColumnType
+from ..models.runner import RowSource
 from ..ops import image as image_ops
 from .jax_model import FlaxModelPayload, JaxModel
 
@@ -47,25 +51,21 @@ class _ImageScorer(JaxModel):
         super().__init__()
         self.channels = channels
 
-    def _stack_input(self, col: np.ndarray) -> np.ndarray:
-        """A partition's image column as one dense ``(n, H, W, C)`` array,
-        in the ONE host copy the images get: ``uint8`` if every image is,
-        else ``float32``.  A dense column is used as it is, with no per-row
-        work."""
+    def _stack_input(self, col: np.ndarray):
+        """A partition's image column as the runner takes it.  An object
+        column becomes a ``RowSource`` of ``(n, H, W, C)``: ``uint8`` if
+        every image is, else ``float32``, decided here from the rows'
+        dtypes (no pixel is read); the ONE host copy the images get is the
+        runner's, chunk by chunk into its staging buffers.  A dense column
+        is used as it is, with no per-row work and no staging copy."""
         c = self.channels
         if col.dtype != object:
             x = col.reshape(len(col), *_as_hwc(col[0], c).shape)
             return x if x.dtype == np.uint8 else x.astype(np.float32, copy=False)
-        rows = [_as_hwc(np.asarray(v), c) for v in col]
-        shape = rows[0].shape
-        as_uint8 = all(r.dtype == np.uint8 for r in rows)
-        out = np.empty((len(rows), *shape), np.uint8 if as_uint8 else np.float32)
-        for i, r in enumerate(rows):
-            if r.shape != shape:            # the assignment would broadcast
-                raise ValueError(f"image {i} has shape {r.shape}, the "
-                                 f"partition's first has {shape}")
-            out[i] = r
-        return out
+        as_uint8 = all(getattr(v, "dtype", None) == np.uint8 for v in col)
+        return RowSource(col, _as_hwc(np.asarray(col[0]), c).shape,
+                         np.uint8 if as_uint8 else np.float32,
+                         as_row=lambda v: _as_hwc(np.asarray(v), c))
 
 
 class ImageFeaturizer(Model, HasInputCol, HasOutputCol):

@@ -143,13 +143,21 @@ class JaxModel(Model, HasInputCol, HasOutputCol):
                                        batch_size=self.get("batch_size"))
         return self._runner
 
-    def _stack_input(self, col: np.ndarray) -> np.ndarray:
+    def _stack_input(self, col: np.ndarray):
+        """What the runner scores: a dense column as one array in the
+        model's input dtype, sliced by the runner as it is; an object
+        column as a ``RowSource`` over its rows, which the runner stacks
+        chunk by chunk into its own staging buffers."""
         shape = self.get("input_shape")
         dtype = np.dtype(self.get("input_dtype"))
         if col.dtype == object:
-            x = np.stack([np.asarray(v) for v in col])
-        else:
-            x = np.asarray(col)
+            from ..models.runner import RowSource
+            row_shape = tuple(shape) if shape else \
+                (np.shape(col[0]) or (1,))
+            return RowSource(
+                col, row_shape, dtype,
+                as_row=lambda v: np.asarray(v).reshape(row_shape))
+        x = np.asarray(col)
         if x.ndim == 1:
             x = x[:, None]
         if shape:

@@ -4,11 +4,11 @@ from .transformer import TransformerEncoder, EncoderBlock, MultiHeadAttention
 from .gbdt import GBDTBooster
 from .runner import (ModelRunner, DecodeResult, PagePool,
                      ContinuousDecoder, StreamHandle, PagePoolExhausted,
-                     SlotsExhausted, ShedReply, bucket_rows)
+                     SlotsExhausted, ShedReply, RowSource, bucket_rows)
 
 __all__ = ["ResNet", "resnet18", "resnet34", "resnet50", "resnet101",
            "BiLSTMTagger", "LSTMLayer", "TransformerEncoder", "EncoderBlock",
            "MultiHeadAttention", "GBDTBooster", "ModelRunner", "DecodeResult",
            "PagePool", "ContinuousDecoder", "StreamHandle",
-           "PagePoolExhausted", "SlotsExhausted", "ShedReply",
+           "PagePoolExhausted", "SlotsExhausted", "ShedReply", "RowSource",
            "bucket_rows"]

@@ -49,6 +49,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -60,7 +61,7 @@ from ..core.schema import ColumnType
 
 __all__ = ["ModelRunner", "DecodeResult", "PagePool", "ContinuousDecoder",
            "StreamHandle", "PagePoolExhausted", "SlotsExhausted", "ShedReply",
-           "bucket_rows"]
+           "RowSource", "bucket_rows"]
 
 #: fronts a batch can arrive through; metric label values
 FRONTS = ("transform", "serving", "decode")
@@ -129,6 +130,34 @@ def _pad_rows(x: np.ndarray, target: int) -> np.ndarray:
         return x
     pad = np.repeat(x[-1:], target - m, axis=0)
     return np.concatenate([x, pad], axis=0)
+
+
+class RowSource:
+    """Rows a caller has NOT stacked: ``shape`` (``(n, *row_shape)``) and
+    ``dtype`` are known up front, the pixels are copied only when
+    :meth:`ModelRunner.apply_batch` asks for rows ``start:stop``, chunk by
+    chunk into its own staging buffers.  ``as_row`` views one element of
+    ``rows`` as an array of ``row_shape`` (no copy wanted: :meth:`fill`
+    makes the one copy, casting to ``dtype``).  ``apply_batch`` reads
+    ``shape``, ``dtype`` and ``fill`` and nothing else, so any object with
+    those three is a row source."""
+
+    def __init__(self, rows, row_shape: Tuple[int, ...], dtype,
+                 as_row: Callable[[Any], np.ndarray] = np.asarray):
+        self.rows = rows
+        self.shape = (len(rows), *row_shape)
+        self.dtype = np.dtype(dtype)
+        self.as_row = as_row
+
+    def fill(self, out: np.ndarray, start: int, stop: int) -> None:
+        """Copy rows ``start:stop`` into ``out[:stop - start]``."""
+        row_shape = self.shape[1:]
+        for j, i in enumerate(range(start, stop)):
+            r = self.as_row(self.rows[i])
+            if r.shape != row_shape:        # the assignment would broadcast
+                raise ValueError(f"row {i} has shape {r.shape}, the "
+                                 f"partition's first has {row_shape}")
+            out[j] = r
 
 
 def _greedy_freeze(logits, finished, eos_id):
@@ -551,6 +580,17 @@ class ModelRunner:
             labels=("runner", "front"))
         self._c_input_bytes = {f: c_input_bytes.labels(runner=name, front=f)
                                for f in FRONTS}
+        c_staged = reg.counter(
+            "mmlspark_runner_staged_chunks_total",
+            "chunks of a row source stacked into host staging buffers, by "
+            "whether the buffer was the runner's kept pair or a fresh one",
+            labels=("runner", "buffer"))
+        self._c_staged = {b: c_staged.labels(runner=name, buffer=b)
+                          for b in ("reused", "fresh")}
+        #: the idle pair of host staging buffers (``batch_size`` rows of
+        #: the last row shape and dtype staged); None while a call has it
+        #: checked out, and before the first row source
+        self._staging: Optional[Tuple[np.ndarray, np.ndarray]] = None
         self._c_pad = reg.counter(
             "mmlspark_runner_pad_rows_total",
             "padding rows added by bucketing (wasted device work)",
@@ -715,12 +755,48 @@ class ModelRunner:
         }
 
     # ------------------------------------------------------------ batch front
-    def apply_batch(self, x: np.ndarray, front: str = "transform",
+    @contextmanager
+    def _staging_pair(self, rows: int, row_shape: Tuple[int, ...], dtype):
+        """Check the two host staging buffers out for one call: the kept
+        pair when it is idle and fits (``"reused"``), else a fresh one
+        (``"fresh"``: the first call, another row shape or dtype, or a
+        second thread while the pair is out; nobody waits).  The pair this
+        call used is the one kept afterwards."""
+        with self._lock:
+            pair, self._staging = self._staging, None
+        label = "reused"
+        if pair is None or pair[0].shape[1:] != row_shape \
+                or pair[0].dtype != dtype or pair[0].shape[0] < rows:
+            pair = (np.empty((rows, *row_shape), dtype),
+                    np.empty((rows, *row_shape), dtype))
+            label = "fresh"
+        try:
+            yield pair, label
+        finally:
+            with self._lock:
+                self._staging = pair
+
+    def apply_batch(self, x, front: str = "transform",
                     batch_size: Optional[int] = None) -> np.ndarray:
-        """Score a stacked host batch of any row count: chunk to
-        ``batch_size``, pad each chunk to its power-of-two bucket, run the
-        cached executable, unpad, concatenate.  This is the ONE copy of the
-        pad/bucket glue the per-model transformers used to hand-roll."""
+        """Score a host batch of any row count: chunk to ``batch_size``,
+        pad each chunk to its power-of-two bucket, run the cached
+        executable, unpad, concatenate.  This is the ONE copy of the
+        pad/bucket glue the per-model transformers used to hand-roll.
+
+        ``x`` is a dense ``ndarray``, sliced as it is, or a
+        :class:`RowSource`, which this loop stacks itself, chunk by chunk,
+        into two host staging buffers the runner keeps between calls
+        (2 x ``batch_size`` rows of the last row shape and dtype staged).
+
+        The loop is a two-deep pipeline: a chunk is dispatched and NOT
+        waited for; the next one is staged and dispatched while the device
+        scores it; the output of chunk ``k - 2`` is fetched just before
+        chunk ``k`` is staged.  So at most two chunks are in flight, and a
+        staging buffer is refilled only after the OUTPUT of the program
+        that read it is ready: that proves the input consumed, whether the
+        backend was still uploading from the host buffer (TPU) or aliasing
+        it without a copy (CPU).  A one-chunk call is stage, dispatch,
+        fetch."""
         bs = int(batch_size or self.batch_size)
         n = x.shape[0]
         if n == 0:
@@ -728,16 +804,48 @@ class ModelRunner:
         variables = self.variables
         outs = []
         pad_total = 0
-        for start in range(0, n, bs):
-            chunk = x[start:start + bs]
-            m = chunk.shape[0]
-            bucket = bucket_rows(m, bs)
-            pad_total += bucket - m
-            chunk = _pad_rows(chunk, bucket)
-            fn = self.executable(bucket, chunk.shape[1:])
-            outs.append(np.asarray(fn(variables, chunk))[:m])
-            self._c_batches[front].inc()
-            self._c_input_bytes[front].inc(chunk.nbytes)
+        #: (device output, real rows) of the dispatched chunks, oldest first
+        in_flight: deque = deque()
+
+        def retire():
+            # the wait for the oldest chunk's output, ALL of it (a fetch
+            # alone reads one replica of a sharded program's), and its fetch
+            y, rows = in_flight.popleft()
+            outs.append(np.asarray(y.block_until_ready())[:rows])
+
+        staging = nullcontext((None, None)) if isinstance(x, np.ndarray) \
+            else self._staging_pair(bs, tuple(x.shape[1:]), x.dtype)
+        with staging as (pair, label):
+            try:
+                for k, start in enumerate(range(0, n, bs)):
+                    m = min(bs, n - start)
+                    bucket = bucket_rows(m, bs)
+                    pad_total += bucket - m
+                    if len(in_flight) == 2:
+                        retire()
+                    if pair is None:
+                        chunk = _pad_rows(x[start:start + m], bucket)
+                    else:
+                        chunk = pair[k % 2][:bucket]
+                        x.fill(chunk, start, start + m)
+                        chunk[m:] = chunk[m - 1]
+                        self._c_staged[label].inc()
+                    fn = self.executable(bucket, chunk.shape[1:])
+                    y = fn(variables, chunk)
+                    # queued behind the program now: the fetch in retire()
+                    # finds the copy done or under way, not still to be
+                    # asked for after a wait
+                    y.copy_to_host_async()
+                    in_flight.append((y, m))
+                    self._c_batches[front].inc()
+                    self._c_input_bytes[front].inc(chunk.nbytes)
+                while in_flight:
+                    retire()
+            finally:
+                # after an error too, no buffer goes back while a program
+                # may still be reading it
+                for y, _ in in_flight:
+                    y.block_until_ready()
         self._c_rows[front].inc(n)
         if pad_total:
             self._c_pad.inc(pad_total)

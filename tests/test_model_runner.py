@@ -55,8 +55,11 @@ def _tiny_lm(vocab=48, layers=2, seed=0):
 # runner-vs-legacy bit parity
 # ---------------------------------------------------------------------------
 
-def _legacy_apply(pure, variables, x, batch_size):
-    """The pre-runner JaxModel glue, verbatim: per-bucket jit + pad."""
+def _legacy_apply(pure, variables, x, batch_size, executable=None):
+    """The pre-runner JaxModel glue, verbatim: per-bucket jit + pad, one
+    chunk at a time with a fetch after each.  ``executable(bucket,
+    feat_shape)`` in place of the per-bucket jit scores through a runner's
+    own programs."""
     import jax
     from mmlspark_tpu.models.runner import bucket_rows
     cache = {}
@@ -70,7 +73,8 @@ def _legacy_apply(pure, variables, x, batch_size):
                 [chunk, np.repeat(chunk[-1:], bucket - m, axis=0)])
         fn = cache.get(bucket)
         if fn is None:
-            fn = cache[bucket] = jax.jit(pure)
+            fn = cache[bucket] = jax.jit(pure) if executable is None \
+                else executable(bucket, chunk.shape[1:])
         outs.append(np.asarray(fn(variables, chunk))[:m])
     return np.concatenate(outs)
 
@@ -115,6 +119,242 @@ def test_runner_vs_legacy_bit_parity_bilstm():
     got = runner.apply_batch(toks)                    # chunks 4/4(pad 1)
     ref = _legacy_apply(pure, variables, toks, 4)
     np.testing.assert_array_equal(got, ref)
+
+
+# ---------------------------------------------------------------------------
+# the batch front as a two-deep staging pipeline (ISSUE 32)
+# ---------------------------------------------------------------------------
+
+_BS, _WIDTH = 16, 64
+
+
+def _rows(n, dtype=np.float32, seed=0, width=_WIDTH):
+    """An object column of ``n`` rows, as a table holds them."""
+    rng = np.random.default_rng(seed)
+    col = np.empty(n, dtype=object)
+    for i in range(n):
+        col[i] = (rng.integers(0, 256, width).astype(dtype)
+                  if np.dtype(dtype) == np.uint8
+                  else rng.normal(size=width).astype(dtype))
+    return col
+
+
+class _Pipe:
+    """A runner whose program takes milliseconds on the CPU (dispatch is
+    asynchronous there too), watched for the one rule the pipeline rests
+    on: a staging buffer is refilled only after the OUTPUT of the program
+    that read it is ready."""
+
+    def __init__(self):
+        import jax
+        import jax.numpy as jnp
+        from mmlspark_tpu.models import ModelRunner
+        from mmlspark_tpu.observability import MetricsRegistry
+        w = np.random.default_rng(1).normal(
+            size=(_WIDTH, _WIDTH)).astype(np.float32) / 8
+
+        def apply_fn(variables, x):
+            h = x.astype(jnp.float32) / 16
+            return jax.lax.fori_loop(
+                0, 2000, lambda i, h: jnp.tanh(h @ variables["w"] + 0.1), h)
+
+        self.reg = MetricsRegistry()
+        self.runner = ModelRunner(apply_fn=apply_fn, variables={"w": w},
+                                  name="test.pipe", batch_size=_BS,
+                                  registry=self.reg)
+        self.refilled_too_early = []
+        #: address of a staged chunk -> the last output computed from it
+        self._read_by = {}
+        #: unwatched, for the reference (its chunks are temporaries whose
+        #: addresses come back)
+        self.executable = self.runner.executable
+
+        def watched_executable(bucket, feat_shape):
+            fn = self.executable(bucket, feat_shape)
+
+            def call(variables, chunk):
+                y = fn(variables, chunk)
+                self._read_by[chunk.ctypes.data] = y
+                return y
+            return call
+        self.runner.executable = watched_executable
+
+    def source(self, col, dtype=np.float32, width=_WIDTH):
+        from mmlspark_tpu.models import RowSource
+        src, pipe = RowSource(col, (width,), dtype), self
+
+        class Watched:
+            shape, dtype = src.shape, src.dtype
+
+            def fill(self, out, start, stop):
+                y = pipe._read_by.get(out.ctypes.data)
+                if y is not None and not y.is_ready():
+                    pipe.refilled_too_early.append(start)
+                src.fill(out, start, stop)
+        return Watched()
+
+    def counter(self, family, **labels):
+        fam = self.reg.family(f"mmlspark_runner_{family}_total")
+        return sum(c.value for key, c in fam._children.items()
+                   if all(v in key for v in labels.values()))
+
+    def staged(self):
+        return (self.counter("staged_chunks", buffer="reused"),
+                self.counter("staged_chunks", buffer="fresh"))
+
+    def one_chunk_at_a_time(self, col, dtype, bs=_BS):
+        """The plain reference: today's loop before the pipeline, through
+        the runner's own programs."""
+        x = np.stack([np.asarray(v) for v in col]).astype(dtype)
+        return _legacy_apply(None, self.runner.variables, x, bs,
+                             executable=self.executable)
+
+    def check(self, n, dtype=np.float32, seed=0, dense=False, bs=None,
+              staged=None):
+        """``apply_batch`` over ``n`` rows is BIT-equal to the reference;
+        ``staged`` = the expected rise of (reused, fresh)."""
+        col = _rows(n, dtype, seed)
+        want = self.one_chunk_at_a_time(col, dtype, bs or _BS)
+        before = self.staged()
+        x = np.stack(list(col)) if dense else self.source(col, dtype)
+        got = self.runner.apply_batch(x, batch_size=bs)
+        assert got.dtype == want.dtype and np.array_equal(got, want)
+        assert not self.refilled_too_early
+        if staged is not None:
+            after = self.staged()
+            assert (after[0] - before[0], after[1] - before[1]) == staged
+        return got
+
+
+def _case_source_chunks(k):
+    def case(pipe):
+        pipe.check(k * _BS, staged=(0, k))
+    return case
+
+
+def _case_ragged_last_chunk(pipe):
+    pipe.check(2 * _BS + 5, staged=(0, 3))          # 16, 16, 5 in a bucket of 8
+    assert pipe.counter("batches", front="transform") == 3
+    assert pipe.counter("rows", front="transform") == 2 * _BS + 5
+    assert pipe.counter("pad_rows") == 3
+    assert pipe.counter("input_bytes", front="transform") \
+        == (2 * _BS + 8) * _WIDTH * 4
+
+
+def _case_dense_three_chunks(pipe):
+    pipe.check(3 * _BS, dense=True, staged=(0, 0))   # nothing to stage
+    pipe.check(2 * _BS + 3, dense=True, staged=(0, 0))
+    assert pipe.counter("batches", front="transform") == 6
+
+
+def _case_one_row(pipe):
+    got = pipe.check(1, staged=(0, 1))
+    assert got.shape == (1, _WIDTH)
+    pipe.check(1, dense=True, staged=(0, 0))
+
+
+def _case_second_call_reuses_the_pair(pipe):
+    pipe.check(3 * _BS, seed=1, staged=(0, 3))
+    pipe.check(5 * _BS + 2, seed=2, staged=(6, 0))
+    pipe.check(1, seed=3, staged=(1, 0))            # a leading view of it
+
+
+def _case_other_dtype_replaces_the_pair(pipe):
+    pipe.check(3 * _BS, np.float32, staged=(0, 3))
+    pipe.check(3 * _BS, np.uint8, staged=(0, 3))
+    pipe.check(4 * _BS, np.uint8, seed=5, staged=(4, 0))
+    pipe.check(2 * _BS, np.float32, staged=(0, 2))  # the float pair is gone
+
+
+def _case_smaller_batch_uses_a_leading_view(pipe):
+    pipe.check(3 * _BS, staged=(0, 3))
+    pipe.check(3 * _BS, bs=_BS // 2, staged=(6, 0))
+    pipe.check(3 * _BS, bs=2 * _BS, staged=(0, 2))   # too small: replaced
+
+
+def _case_two_threads_at_once(pipe):
+    import threading
+    pipe.check(3 * _BS, staged=(0, 3))              # the pair exists
+    barrier, failures = threading.Barrier(2), []
+
+    def call(seed):
+        try:
+            barrier.wait(timeout=30)
+            for _ in range(3):
+                pipe.check(4 * _BS, seed=seed)
+        except BaseException as e:       # read by the asserting thread
+            failures.append(e)
+    before = pipe.staged()
+    threads = [threading.Thread(target=call, args=(s,)) for s in (7, 8)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in threads) and not failures
+    after = pipe.staged()
+    assert sum(after) - sum(before) == 2 * 3 * 4    # nobody waited or skipped
+    pipe.check(3 * _BS, staged=(3, 0))              # a pair was handed back
+
+
+def _case_fill_raises_midway(pipe):
+    pipe.check(3 * _BS, staged=(0, 3))
+    src = pipe.source(_rows(4 * _BS))
+    fill = src.fill
+
+    def failing_fill(out, start, stop):
+        if start == 2 * _BS:                        # two chunks in flight
+            raise OSError("row store went away")
+        fill(out, start, stop)
+    src.fill = failing_fill
+    with pytest.raises(OSError, match="went away"):
+        pipe.runner.apply_batch(src)
+    assert not pipe.refilled_too_early
+    pipe.check(3 * _BS, seed=9, staged=(3, 0))      # handed back, and clean
+
+
+def _case_row_of_another_shape_raises(pipe):
+    col = _rows(2 * _BS)
+    col[_BS + 3] = np.zeros(1, np.float32)          # would broadcast
+    with pytest.raises(ValueError, match=f"row {_BS + 3} has shape"):
+        pipe.runner.apply_batch(pipe.source(col))
+    pipe.check(2 * _BS, staged=(2, 0))
+
+
+def _case_another_row_shape_replaces_the_pair(pipe):
+    pipe.check(2 * _BS, staged=(0, 2))
+    wide = _rows(2 * _BS, width=2 * _WIDTH)
+    with pytest.raises(TypeError):                   # (rows, 128) @ (64, 64)
+        pipe.runner.apply_batch(pipe.source(wide, width=2 * _WIDTH))
+    assert pipe.staged() == (0, 3)                   # staged fresh, then failed
+    pipe.check(2 * _BS, staged=(0, 2))
+
+
+_PIPELINE_CASES = {
+    "source_1_chunk": _case_source_chunks(1),
+    "source_2_chunks": _case_source_chunks(2),
+    "source_3_chunks": _case_source_chunks(3),
+    "source_7_chunks": _case_source_chunks(7),
+    "ragged_last_chunk_padded": _case_ragged_last_chunk,
+    "dense_3_chunks": _case_dense_three_chunks,
+    "one_row": _case_one_row,
+    "second_call_reuses_the_pair": _case_second_call_reuses_the_pair,
+    "other_dtype_replaces_the_pair": _case_other_dtype_replaces_the_pair,
+    "smaller_batch_uses_a_leading_view":
+        _case_smaller_batch_uses_a_leading_view,
+    "two_threads_at_once": _case_two_threads_at_once,
+    "fill_raises_midway": _case_fill_raises_midway,
+    "row_of_another_shape_raises": _case_row_of_another_shape_raises,
+    "another_row_shape_replaces_the_pair":
+        _case_another_row_shape_replaces_the_pair,
+}
+
+
+@pytest.mark.parametrize("case", list(_PIPELINE_CASES))
+def test_apply_batch_pipeline_matches_one_chunk_at_a_time(case):
+    """Every case BIT-equal to scoring the same rows one chunk at a time
+    with a fetch after each, and never a staging buffer refilled before
+    the output of the program that read it was ready."""
+    _PIPELINE_CASES[case](_Pipe())
 
 
 # ---------------------------------------------------------------------------

@@ -305,7 +305,7 @@ def test_runner_books_front_and_decode_metrics():
     rows/batches/padding, decode must book steps/tokens, and every
     executable must be built through the instrumented path (a raw jax.jit
     in the runner would silently drop compile accounting for every model it
-    serves).  Live: construction registers all five families."""
+    serves).  Live: construction registers all seven families."""
     import inspect as _inspect
 
     from mmlspark_tpu.models import runner as runner_mod
@@ -329,6 +329,7 @@ def test_runner_books_front_and_decode_metrics():
     for family in ("mmlspark_runner_batches_total",
                    "mmlspark_runner_rows_total",
                    "mmlspark_runner_input_bytes_total",
+                   "mmlspark_runner_staged_chunks_total",
                    "mmlspark_runner_pad_rows_total",
                    "mmlspark_runner_decode_steps_total",
                    "mmlspark_runner_decode_tokens_total"):
