@@ -47,10 +47,16 @@ class Run:
         self.devices: List[Any] = []
         self.peaks: Optional[Dict[str, float]] = None
         self.failures: List[str] = []
+        #: every number a check compared, beside its limit, by a short name
+        self.checks: Dict[str, Dict[str, float]] = {}
+        self._memory_peak: Optional[int] = None
         #: facts the family and the traffic kind record for the readers
         self.facts: Dict[str, Any] = {}
         self.setup_s: Optional[float] = None
         self.window_start_s = self.window_end_s = 0.0
+        #: wall-clock ns at ``window_start_s``: lays a reading of the trace
+        #: over the window
+        self.window_start_wall_ns = 0
         self.counters_before: Dict = {}
         self.counters_after: Dict = {}
         self.builds_before = self.builds_after = 0
@@ -66,6 +72,24 @@ class Run:
         self.failures.append(text)
         self.note("CHECK FAILED: " + text)
 
+    def check(self, name: str, value: float, limit: float) -> bool:
+        """Hold ``value`` to ``limit`` (at most it; a NaN fails), keep both
+        for the result line, and fail the run where it does not hold."""
+        value, limit = float(value), float(limit)
+        self.checks[name] = {"value": value, "limit": limit}
+        ok = value <= limit
+        if not ok:
+            self.fail(f"{name} = {value!r} over its limit {limit!r}")
+        return ok
+
+    def memory_peak_bytes(self) -> int:
+        """``_memory_peak_bytes`` of this run, read once: a traffic kind
+        whose check runs a reference on the device after the window reads
+        it before that, so that the reference is not counted."""
+        if self._memory_peak is None:
+            self._memory_peak = _memory_peak_bytes(self)
+        return self._memory_peak
+
     # ------------------------------------------------------------- window
     def setup_done(self) -> None:
         """Called by the traffic kind when everything is loaded and warm:
@@ -77,9 +101,6 @@ class Run:
         """The measured window.  With ``--trace 1`` the profiler runs for
         exactly this window; starting and stopping it stay outside."""
         import jax
-        self.counters_before = measure.snapshot_registry(self.registry)
-        self.builds_before = self.clock.builds
-        self.misses_before = self.clock.misses
         trace_dir = None
         if self.trace:
             trace_dir = self.manifest.path(TRACE_DIR, self.cell["name"])
@@ -94,18 +115,25 @@ class Run:
             # operations themselves are host events.
             opts.host_tracer_level = 1 if self.platform == "cpu" else 0
             jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        # read after the profiler has started (which takes a while) and
+        # before it stops: where an engine runs on through both, what it
+        # counts in the meantime is not the window's
+        self.counters_before = measure.snapshot_registry(self.registry)
+        self.builds_before = self.clock.builds
+        self.misses_before = self.clock.misses
         # the same instant on the two clocks: spans are on perf_counter, the
         # trace counts from its start on the wall clock
-        self.window_start_s, start_ns = time.perf_counter(), time.time_ns()
+        self.window_start_s, self.window_start_wall_ns = \
+            time.perf_counter(), time.time_ns()
         try:
             yield
         finally:
             self.window_end_s = time.perf_counter()
-            if self.trace:
-                jax.profiler.stop_trace()
             self.builds_after = self.clock.builds
             self.misses_after = self.clock.misses
             self.counters_after = measure.snapshot_registry(self.registry)
+            if self.trace:
+                jax.profiler.stop_trace()
         if self.trace:
             from . import trace_reduce
             files = glob.glob(os.path.join(trace_dir, "plugins", "profile",
@@ -114,7 +142,8 @@ class Run:
                 raise RuntimeError(f"expected one trace file, found {files}")
 
             def wall_ns(t_s: float) -> float:
-                return start_ns + (t_s - self.window_start_s) * 1e9
+                return self.window_start_wall_ns \
+                    + (t_s - self.window_start_s) * 1e9
             self.trace_summary = trace_reduce.reduce_file(
                 files[0],
                 window_wall_ns=(wall_ns(self.window_start_s),
@@ -273,7 +302,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool,
             metrics[m["name"]] = {"value": float(values[m["name"]]),
                                   "unit": m["unit"]}
 
-    device = dict(stamp, memory_peak_bytes=_memory_peak_bytes(run))
+    device = dict(stamp, memory_peak_bytes=run.memory_peak_bytes())
     result = {"correct": not run.failures,
               "attempted": int(outcome["attempted"]),
               "failed": int(outcome["failed"]),
@@ -286,4 +315,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool,
                                "idle_gaps": ts.top_gaps(10)}
     if run.failures:
         result["failures"] = run.failures
+    if run.checks:
+        # last, where the driver's record of a run at fault keeps it
+        result["checks"] = run.checks
     return result

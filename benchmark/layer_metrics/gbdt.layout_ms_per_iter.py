@@ -1,7 +1,11 @@
 """Device milliseconds per boosting iteration under the ``gbdt.layout`` scope:
-the sort of the rows into node-pure blocks that the matmul histogram builders
-run before every build (``ops/histogram._node_pure_layout``).  Own time of the
-traced operations whose scope path names it (``benchmark/phase_times.py``)."""
+the rows laid out as node-pure blocks for the matmul histogram builders
+(``ops/histogram._node_pure_layout``): one stable sort by node, the blocks as
+slices of the sorted order, the binned rows gathered by the blocks' row ids.
+Since PR 27 a build of ONE node (the root of every tree, and sharded the level
+below it) is not sorted at all and pays only the pad of the binned matrix to
+whole blocks.  Own time of the traced operations whose scope path names it
+(``benchmark/phase_times.py``)."""
 from benchmark import phase_times
 
 

@@ -49,6 +49,11 @@ def main(argv=None) -> int:
         print(f"benchmark: {type(e).__name__}: {e}; no result",
               file=sys.stderr)
         return 1
+    # each number compared beside its limit: the last lines of standard error
+    for name, c in result.get("checks", {}).items():
+        print(f"check {name}: {c['value']!r} (limit {c['limit']!r})",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

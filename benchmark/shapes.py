@@ -1,6 +1,7 @@
 """What the algorithms need, from their shapes alone: operations and bytes of
-one GBDT boosting iteration and of one ResNet forward pass, and the least time
-a chip with given peaks could take for them.  A roofline share is this least
+one GBDT boosting iteration, of one ResNet forward pass and of a causal
+language model's decode steps and prefills, and the least time a chip with
+given peaks could take for them.  A roofline share is this least
 time over the device time a trace shows.
 """
 from __future__ import annotations
@@ -82,5 +83,86 @@ def resnet_forward_least_s(need: Dict[str, float],
                            peaks: Dict[str, float]) -> Tuple[float, str]:
     compute = need["flops"] / peaks["bf16_flops_per_s"]
     memory = need["hbm_bytes"] / peaks["hbm_bytes_per_s"]
+    return (compute, "bf16 compute") if compute >= memory \
+        else (memory, "HBM bandwidth")
+
+
+# ------------------------------------------------- causal language models
+
+def causal_lm_params(layers: int, width: int, mlp: int, vocab: int,
+                     positions: int, untied_head: bool = True
+                     ) -> Dict[str, float]:
+    """Parameters of a GPT-2-shaped decoder (Radford et al. 2019): per layer
+    the fused QKV (``3 w^2 + 3 w``), the attention's output projection
+    (``w^2 + w``), two MLP matrices (``2 w mlp + mlp + w``) and two
+    LayerNorms (``4 w``); a token and a learned position embedding; a final
+    LayerNorm; and, where the head is not tied to the token embedding, a
+    ``w x vocab`` head with its bias.  ``matmul`` counts the parameters that
+    every token multiplies (the layers' matrices and the head's): two
+    operations a token each.  GPT-2 XL with an untied head: 1,638,072,657 in
+    all, 1,554,971,200 in matmuls."""
+    per_layer = 4 * width * width + 2 * width * mlp \
+        + (3 * width + width) + (mlp + width) + 4 * width
+    head = width * vocab + vocab if untied_head else 0
+    total = layers * per_layer + vocab * width + positions * width \
+        + 2 * width + head
+    matmul = layers * (4 * width * width + 2 * width * mlp) + width * vocab
+    return {"total": float(total), "matmul": float(matmul)}
+
+
+def causal_lm_need(sizes: Dict[str, float]) -> Dict[str, float]:
+    """What the shares of a causal language model's cells are priced from,
+    out of a configuration's ``sizes`` (``layers``, ``width``, ``mlp``,
+    ``vocab``, ``positions``, ``bytes_per_value``, ``untied_head``)."""
+    params = causal_lm_params(sizes["layers"], sizes["width"], sizes["mlp"],
+                              sizes["vocab"], sizes["positions"],
+                              sizes.get("untied_head", True))
+    return {"param_bytes": params["total"] * sizes["bytes_per_value"],
+            "matmul_params": params["matmul"],
+            "kv_token_bytes": float(kv_bytes_per_token(
+                sizes["layers"], sizes["width"], sizes["bytes_per_value"])),
+            "layers": sizes["layers"], "width": sizes["width"]}
+
+
+def kv_bytes_per_token(layers: int, width: int, bytes_per_value: int) -> int:
+    """Bytes of keys and values one position holds over all layers (full
+    multi-head attention: a key and a value of the model's width a layer)."""
+    return layers * 2 * width * bytes_per_value
+
+
+def decode_steps_least_bytes(steps: float, context_tokens: float,
+                             param_bytes: float, kv_token_bytes: float
+                             ) -> float:
+    """The least HBM traffic of ``steps`` decode steps: every step reads the
+    weights once, and every sequence alive in a step reads its keys and
+    values at its TRUE length (``context_tokens`` is the sum, over the steps
+    and the sequences alive in each, of the positions attended to).  What an
+    ideal paged read would move: no table width, no page padding, no copy of
+    the pool."""
+    return steps * param_bytes + context_tokens * kv_token_bytes
+
+
+def causal_lm_flops(matmul_params: float, tokens: float,
+                    attended_positions: float, layers: int, width: int
+                    ) -> float:
+    """Operations of ``tokens`` positions through the model (generated and
+    prefilled alike, at their true count): two per matmul parameter a
+    position, and for attention two products (scores, and the weighted sum
+    of values) of ``width`` multiply-accumulates per layer for every
+    position attended to (``attended_positions``: the sum over the
+    positions of their causal context)."""
+    return 2.0 * matmul_params * tokens \
+        + 4.0 * layers * width * attended_positions
+
+
+def prefill_attended_positions(length: int) -> float:
+    """Sum of the causal contexts of a prompt's positions: 1 + 2 + ... + n."""
+    return length * (length + 1) / 2.0
+
+
+def least_s(flops: float, hbm_bytes: float, peaks: Dict[str, float]
+            ) -> Tuple[float, str]:
+    compute = flops / peaks["bf16_flops_per_s"]
+    memory = hbm_bytes / peaks["hbm_bytes_per_s"]
     return (compute, "bf16 compute") if compute >= memory \
         else (memory, "HBM bandwidth")
