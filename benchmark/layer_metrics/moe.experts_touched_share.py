@@ -1,0 +1,14 @@
+"""Share of a layer's experts that a decode step's tokens were routed to, in
+percent: ``mmlspark_runner_moe_experts_touched_total`` (distinct experts with
+at least one token, summed over layers and steps) over steps x layers x
+experts.  What an expert layer that reads only touched experts has to
+read."""
+
+
+def read(run):
+    touched = run.counter("mmlspark_runner_moe_experts_touched_total")
+    steps = run.counter("mmlspark_runner_decode_steps_total")
+    sizes = run.config.get("sizes") or {}
+    if not touched or not steps or not sizes.get("experts"):
+        return None
+    return 100.0 * touched / (steps * sizes["layers"] * sizes["experts"])

@@ -1,6 +1,7 @@
 from .resnet import ResNet, resnet18, resnet34, resnet50, resnet101
 from .bilstm import BiLSTMTagger, LSTMLayer
 from .transformer import TransformerEncoder, EncoderBlock, MultiHeadAttention
+from .sparse_moe import SparseMoEDecoder
 from .gbdt import GBDTBooster
 from .runner import (ModelRunner, DecodeResult, PagePool,
                      ContinuousDecoder, StreamHandle, PagePoolExhausted,
@@ -8,7 +9,7 @@ from .runner import (ModelRunner, DecodeResult, PagePool,
 
 __all__ = ["ResNet", "resnet18", "resnet34", "resnet50", "resnet101",
            "BiLSTMTagger", "LSTMLayer", "TransformerEncoder", "EncoderBlock",
-           "MultiHeadAttention", "GBDTBooster", "ModelRunner", "DecodeResult",
+           "MultiHeadAttention", "SparseMoEDecoder", "GBDTBooster", "ModelRunner", "DecodeResult",
            "PagePool", "ContinuousDecoder", "StreamHandle",
            "PagePoolExhausted", "SlotsExhausted", "ShedReply", "RowSource",
            "bucket_rows"]

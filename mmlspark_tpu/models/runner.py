@@ -172,14 +172,24 @@ def _greedy_freeze(logits, finished, eos_id):
     return tok, finished
 
 
-def _cached_apply(module, variables, toks, positions, table, cache):
+def _cached_apply(module, variables, toks, positions, table, cache,
+                  logits_at=None):
     """One call shape for every decode executable: ``table`` is ``None`` on
     the dense layout (an empty pytree — part of the jit signature, no
     tracing cost) and the kwarg is withheld so modules that only know
-    ``init_cache`` keep working."""
+    ``init_cache`` keep working.  ``logits_at`` (B,) has the module apply
+    its head to that one row of each sequence (prefill: the last real
+    position), so the (B, P, vocab) logits are never built.  Returns
+    ``(logits, cache, sown)``: ``sown`` is what the module sowed into
+    ``intermediates`` (a routed model's ``experts_touched``) and ``{}`` —
+    no output of the compiled program — for a module that sows nothing."""
     kw = {} if table is None else {"page_table": table}
-    return module.apply(variables, toks, positions=positions,
-                        kv_cache=cache, **kw)
+    if logits_at is not None:
+        kw["logits_at"] = logits_at
+    (logits, cache), sown = module.apply(
+        variables, toks, positions=positions, kv_cache=cache,
+        mutable=["intermediates"], **kw)
+    return logits, cache, dict(sown.get("intermediates", {}))
 
 
 @dataclass
@@ -651,6 +661,27 @@ class ModelRunner:
         reg.histogram("mmlspark_runner_ttft_seconds",
                       "submit-to-first-token latency of continuous decode",
                       labels=("runner",))
+        # long-prompt joins and routed models (ISSUE 34): chunks a join's
+        # prefill took, its prompt tokens by where their k/v came from, and
+        # the distinct experts a decode step's tokens were routed to
+        self._c_prefill_chunks = reg.counter(
+            "mmlspark_runner_prefill_chunks_total",
+            "prefill dispatches of continuous-decode joins (one a "
+            "prompt_bucket chunk of the uncovered prompt)",
+            labels=("runner",)).labels(runner=name)
+        c_prefill_tokens = reg.counter(
+            "mmlspark_runner_prefill_tokens_total",
+            "prompt tokens of continuous-decode joins: computed by the "
+            "join's prefill, or cached (covered by the prefix index)",
+            labels=("runner", "source"))
+        self._c_prefill_tokens = {
+            src: c_prefill_tokens.labels(runner=name, source=src)
+            for src in ("computed", "cached")}
+        self._c_experts_touched = reg.counter(
+            "mmlspark_runner_moe_experts_touched_total",
+            "distinct experts with at least one token, summed over the "
+            "layers and the decode steps of a routed model",
+            labels=("runner",)).labels(runner=name)
         # tail-tolerance surface (ISSUE 16): stall + supervised-restart
         # families registered at construction so the telemetry sweep gates
         # on them even for runners that never stall; the stall watchdog
@@ -978,7 +1009,9 @@ class ModelRunner:
 
     def _cow_executable(self):
         """Device-side page copy for copy-on-write splits: clone one
-        physical page's k/v rows (every layer) from ``src`` into ``dst``.
+        physical page's rows in EVERY slab of the cache pytree (k and v of
+        every layer, and whatever else the module pages beside them) from
+        ``src`` into ``dst``.
         src/dst are traced scalars and the slabs are donated, so the copy
         is in-place-update-shaped and mints no per-page compile keys (one
         executable per pool geometry)."""
@@ -989,12 +1022,11 @@ class ModelRunner:
         with self._lock:
             fn = self._executables.get(key)
             if fn is None:
+                import jax
+
                 def _cow(cache, src, dst):
-                    out = []
-                    for k, v in cache:
-                        out.append((k.at[dst].set(k[src]),
-                                    v.at[dst].set(v[src])))
-                    return tuple(out)
+                    return jax.tree_util.tree_map(
+                        lambda slab: slab.at[dst].set(slab[src]), cache)
 
                 fn = self._executables[key] = self._instrumented(
                     _cow, suffix=".cow_copy", donate_argnums=(0,))
@@ -1044,8 +1076,9 @@ class ModelRunner:
         touches the stale references (the donation-safety regression test
         pins this).  ``fused=True`` builds the greedy/eos fast-path step
         that samples + freezes on device and returns the (B,) next token
-        instead of (B, V) logits; ``eos_id`` is baked into that executable
-        (part of its key — low-cardinality by construction)."""
+        instead of (B, V) logits, and beside it what the module sowed (see
+        ``_cached_apply``); ``eos_id`` is baked into that executable (part
+        of its key — low-cardinality by construction)."""
         import jax.numpy as jnp
         module = self.module
         dkey = self._device_key()
@@ -1069,14 +1102,14 @@ class ModelRunner:
             if prefill is None:
                 def _prefill(variables, toks, positions, lengths, table,
                              cache, _m=module):
-                    logits, cache = _cached_apply(_m, variables, toks,
-                                                  positions, table, cache)
-                    # last REAL token's logits per sequence — gathered
-                    # on-device so the (B, P, V) tensor never crosses to
-                    # host
-                    last = jnp.take_along_axis(
-                        logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
-                    return last, cache
+                    # the head runs on the last REAL position of each
+                    # sequence only: the (B, P, V) logits are never built,
+                    # let alone fetched (ISSUE 34: at a vocabulary of 152k
+                    # they were 311 MB a join for one row of use)
+                    logits, cache, _ = _cached_apply(
+                        _m, variables, toks, positions, table, cache,
+                        logits_at=lengths - 1)
+                    return logits[:, 0], cache
 
                 prefill = self._executables[kp] = self._instrumented(
                     _prefill, suffix=f".prefill{sfx}", donate_argnums=(5,))
@@ -1085,12 +1118,12 @@ class ModelRunner:
                 if fused:
                     def _step(variables, tok, positions, table, finished,
                               cache, _m=module, _eos=eos_id):
-                        logits, cache = _cached_apply(
+                        logits, cache, sown = _cached_apply(
                             _m, variables, tok[:, None], positions[:, None],
                             table, cache)
                         nxt, finished = _greedy_freeze(logits[:, 0],
                                                        finished, _eos)
-                        return nxt, finished, cache
+                        return nxt, finished, cache, sown
 
                     step = self._instrumented(
                         _step, suffix=f".decode_step{sfx}",
@@ -1098,9 +1131,8 @@ class ModelRunner:
                 else:
                     def _step(variables, tok, positions, table, cache,
                               _m=module):
-                        logits, cache = _cached_apply(_m, variables, tok,
-                                                      positions, table,
-                                                      cache)
+                        logits, cache, _ = _cached_apply(
+                            _m, variables, tok, positions, table, cache)
                         return logits[:, 0], cache
 
                     step = self._instrumented(
@@ -1186,7 +1218,10 @@ class ModelRunner:
         that complete ok are retained into the index for the next
         request's hit.  Greedy tokens stay bit-identical to a cold
         decode — docs/runner.md "Prefix caching" states the argument."""
-        if self.module is None or not hasattr(self.module, "init_cache"):
+        if self.module is None or not (
+                hasattr(self.module, "init_cache")
+                or (kv_layout == "paged" or pool is not None)
+                and hasattr(self.module, "init_paged_cache")):
             raise TypeError(
                 "decode() needs a module with init_cache (a KV-cache-capable "
                 "model, e.g. models.TransformerEncoder with causal=True, "
@@ -1521,9 +1556,9 @@ class ModelRunner:
                     # donated dispatch: fin_d/cache are CONSUMED here — the
                     # loop rebinds all three outputs and must never touch
                     # the stale references again
-                    tok_d, fin_d, cache = step(variables, tok_d,
-                                               jnp.asarray(pos), table_dev,
-                                               fin_d, cache)
+                    tok_d, fin_d, cache, _ = step(variables, tok_d,
+                                                  jnp.asarray(pos),
+                                                  table_dev, fin_d, cache)
                 else:
                     last, cache = step(variables, jnp.asarray(tok[:, None]),
                                        jnp.asarray(pos[:, None]), table_dev,
@@ -1683,7 +1718,8 @@ class ModelRunner:
                       pool: Optional[PagePool] = None,
                       clock: Optional[Callable[[], float]] = None,
                       stall_timeout_s: Optional[float] = None,
-                      prefix_cache: bool = False
+                      prefix_cache: bool = False,
+                      max_prompt_len: Optional[int] = None
                       ) -> "ContinuousDecoder":
         """A persistent in-flight decode loop over the paged pool (ISSUE 13
         tentpole): a fixed ``slots``-wide batch whose per-slot state (page-
@@ -1710,6 +1746,12 @@ class ModelRunner:
         arrival's hit.  Greedy tokens stay bit-identical to cold-cache
         :meth:`decode` across hit/partial-hit/miss/CoW traffic.
 
+        ``max_prompt_len`` (default: ``prompt_bucket``) is the longest
+        prompt admitted; ``prompt_bucket`` is the prefill CHUNK.  A longer
+        prompt joins chunk after chunk through the same (1, prompt_bucket)
+        prefill executable with offset positions (ISSUE 34), and the page
+        table's width follows ``max_prompt_len + max_new_tokens``.
+
         Drive it with :meth:`ContinuousDecoder.submit` + either
         :meth:`ContinuousDecoder.start` (background engine thread — what
         serving uses) or manual :meth:`ContinuousDecoder.step` calls
@@ -1720,7 +1762,8 @@ class ModelRunner:
                                  eos_id=eos_id, page_size=page_size,
                                  pool=pool, clock=clock,
                                  stall_timeout_s=stall_timeout_s,
-                                 prefix_cache=prefix_cache)
+                                 prefix_cache=prefix_cache,
+                                 max_prompt_len=max_prompt_len)
 
 
 class StreamHandle:
@@ -1837,7 +1880,8 @@ class ContinuousDecoder:
                  pool: Optional[PagePool] = None,
                  clock: Optional[Callable[[], float]] = None,
                  stall_timeout_s: Optional[float] = None,
-                 prefix_cache: bool = False):
+                 prefix_cache: bool = False,
+                 max_prompt_len: Optional[int] = None):
         module = runner.module
         if module is None or not hasattr(module, "init_paged_cache"):
             raise TypeError(
@@ -1849,6 +1893,14 @@ class ContinuousDecoder:
         self.runner = runner
         self.slots = int(slots)
         self.prompt_bucket = int(prompt_bucket)
+        #: the longest prompt admitted; one longer than the prefill chunk
+        #: (``prompt_bucket``) joins in several chunks
+        self.max_prompt_len = self.prompt_bucket if max_prompt_len is None \
+            else int(max_prompt_len)
+        if self.max_prompt_len < self.prompt_bucket:
+            raise ValueError(
+                f"max_prompt_len {self.max_prompt_len} is below the prefill "
+                f"chunk (prompt_bucket {self.prompt_bucket})")
         self.max_new_tokens = int(max_new_tokens)
         self.eos_id = eos_id
         self.clock = clock or time.monotonic
@@ -1857,14 +1909,14 @@ class ContinuousDecoder:
         if page_size < 1:
             raise ValueError("page_size must be >= 1")
         self.page_size = int(page_size)
-        self.table_w = -(-(self.prompt_bucket + self.max_new_tokens)
+        self.table_w = -(-(self.max_prompt_len + self.max_new_tokens)
                          // self.page_size)
         max_len = getattr(module, "max_len", None)
         if max_len is not None and \
-                self.prompt_bucket + self.max_new_tokens > max_len:
+                self.max_prompt_len + self.max_new_tokens > max_len:
             raise ValueError(
-                f"prompt_bucket + max_new_tokens = "
-                f"{self.prompt_bucket + self.max_new_tokens} exceeds the "
+                f"longest prompt + max_new_tokens = "
+                f"{self.max_prompt_len + self.max_new_tokens} exceeds the "
                 f"module's max_len {max_len} (positional table bound)")
         self._explicit_pool = pool is not None
         self.pool = pool if pool is not None else runner._auto_pool(
@@ -2034,10 +2086,10 @@ class ContinuousDecoder:
         corrupt decode."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         length = int(prompt.size)
-        if not 1 <= length <= self.prompt_bucket:
+        if not 1 <= length <= self.max_prompt_len:
             raise ValueError(
                 f"prompt length {length} outside [1, "
-                f"{self.prompt_bucket}] (the stream's prompt bucket)")
+                f"{self.max_prompt_len}] (the stream's longest prompt)")
         budget = (self.max_new_tokens if max_new_tokens is None
                   else int(max_new_tokens))
         if not 1 <= budget <= self.max_new_tokens:
@@ -2129,7 +2181,7 @@ class ContinuousDecoder:
                 variables, jnp.zeros((1, P_b), jnp.int32), positions,
                 jnp.ones(1, jnp.int32), table1, self._cache)
             self._sample1(last, jnp.ones(1, bool))
-            _t, _f, self._cache = self._step(
+            _t, _f, self._cache, _ = self._step(
                 variables, jnp.zeros(S, jnp.int32),
                 jnp.zeros(S, jnp.int32),
                 jnp.zeros((S, self.table_w), jnp.int32),
@@ -2236,9 +2288,6 @@ class ContinuousDecoder:
                     # same retryable verdict as a mid-flight denial
                     self._cancel_arrival(h, "denied", leavers)
                     continue
-            suffix = h.length - off
-            toks = np.zeros((1, P_b), np.int32)
-            toks[0, :suffix] = h.prompt[off:]
             jtable = np.zeros((1, W), np.int32)
             n = len(h.pages)
             jtable[0, :n] = h.pages
@@ -2248,14 +2297,28 @@ class ContinuousDecoder:
             self._handles[s] = h
             if self.watchdog is not None:
                 self.watchdog.arm("runner.decode.join")
-            # positions offset past the cached prefix: traced DATA at the
-            # same (1, prompt_bucket) signature, so a hit join reuses the
-            # cold join's executable — no new compile key per hit length
-            last, self._cache = self._prefill1(
-                runner.variables, jnp.asarray(toks),
-                jnp.asarray(positions + off) if off else pos_dev,
-                jnp.asarray([suffix], np.int32), jnp.asarray(jtable),
-                self._cache)
+            # the uncovered part of the prompt, chunk after chunk: positions
+            # offset past the cached prefix and the chunks before are traced
+            # DATA at the same (1, prompt_bucket) signature, so a hit join
+            # and a long prompt reuse the cold join's executable — no new
+            # compile key per hit length or prompt length.  Only the last
+            # chunk's logits are sampled
+            jtable_dev = jnp.asarray(jtable)
+            for at in range(off, h.length, P_b):
+                n = min(P_b, h.length - at)
+                toks = np.zeros((1, P_b), np.int32)
+                toks[0, :n] = h.prompt[at:at + n]
+                last, self._cache = self._prefill1(
+                    runner.variables, jnp.asarray(toks),
+                    jnp.asarray(positions + at) if at else pos_dev,
+                    jnp.asarray([n], np.int32), jtable_dev, self._cache)
+                runner._c_prefill_chunks.inc()
+                if self.watchdog is not None and at + P_b < h.length:
+                    # the timeout bounds ONE dispatch, not a long join
+                    last.block_until_ready()
+                    self.watchdog.heartbeat()
+            runner._c_prefill_tokens["computed"].inc(h.length - off)
+            runner._c_prefill_tokens["cached"].inc(off)
             tok_d, fin_d = self._sample1(last, jnp.zeros(1, bool))
             tok0 = int(np.asarray(tok_d)[0])
             fin0 = bool(np.asarray(fin_d)[0])
@@ -2291,6 +2354,7 @@ class ContinuousDecoder:
         spend a dispatch on a dead client), page-boundary extends (a
         denial leaves the slot with its partial generation), then the
         SAME donated step executable one-shot decode dispatches."""
+        import jax
         import jax.numpy as jnp
         runner = self.runner
         now = self.clock()
@@ -2351,7 +2415,7 @@ class ContinuousDecoder:
             # stalls the fetch; a dead runtime stalls the enqueue)
             self.watchdog.arm("runner.decode.step")
         t_disp0 = time.perf_counter()
-        tok_d, fin_d, self._cache = self._step(
+        tok_d, fin_d, self._cache, sown_d = self._step(
             runner.variables, tok_in, jnp.asarray(pos),
             self._table_dev, fin_in, self._cache)
         # dispatch/device split (ISSUE 15): the step call above is the
@@ -2363,7 +2427,9 @@ class ContinuousDecoder:
         # copies to the step's outputs; a release below invalidates them
         self._tok_dev, self._fin_dev = tok_d, fin_d
         t_dev0 = time.perf_counter()
-        tok, fin = np.asarray(tok_d), np.asarray(fin_d)
+        # the one host fetch of a step: the tokens, the finished flags and
+        # what the module sowed (nothing, but for a routed model's count)
+        tok, fin, sown = jax.device_get((tok_d, fin_d, sown_d))
         # the fetch IS the device wait (already a sync) — measuring it
         # every step costs one clock read, so the attribution charge below
         # uses the true per-step device time, not a sampled estimate
@@ -2375,6 +2441,8 @@ class ContinuousDecoder:
         if dte and self.steps % dte == 0:
             runner._h_phase_device.observe(dev_s)
         runner._c_decode_steps.inc()
+        for n in sown.get("experts_touched", ()):
+            runner._c_experts_touched.inc(float(n))
         # attribution (ISSUE 17): the whole step's host-observed device
         # work (enqueue + device wait) is amortized over the slots that
         # had a live request behind them at dispatch; the rest of the

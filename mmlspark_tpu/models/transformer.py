@@ -213,7 +213,8 @@ class TransformerEncoder(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, features: bool = False,
-                 positions=None, kv_cache=None, page_table=None):
+                 positions=None, kv_cache=None, page_table=None,
+                 logits_at=None):
         B, L = tokens.shape
         x = nn.Embed(self.vocab_size, self.embed_dim, dtype=self.dtype)(tokens)
         pos = self.param("pos_embed", nn.initializers.normal(0.02),
@@ -238,6 +239,10 @@ class TransformerEncoder(nn.Module):
                 new_cache.append(layer_kv)
             else:
                 x = block(x)
+        if logits_at is not None:
+            # (B,) one row a sequence (a prefill's last real position): the
+            # final norm and the head run on it alone, (B, 1, C) out
+            x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
         x = nn.LayerNorm(dtype=self.dtype)(x)
         if features:
             x = x.astype(jnp.float32)
