@@ -216,11 +216,12 @@ class PagePool:
     behind ``ModelRunner.decode(kv_layout="paged")`` (ISSUE 12 tentpole).
 
     The pool owns ``num_pages`` pages of ``page_size`` token slots each,
-    materialized on device as ``module.init_paged_cache`` slabs of
-    ``(num_pages, page_size, heads, head_dim)`` per layer, plus the
-    host-side free list that hands pages to sequences: allocate by TRUE
-    prompt length at prefill, extend one page at a time when a decode
-    frontier crosses a page boundary, free on eos/completion.  Page 0 is
+    materialized on device as the slabs ``module.init_paged_cache``
+    returns (for ``TransformerEncoder`` a k and a v of ``(num_pages,
+    page_size, heads * head_dim)`` per layer), plus the host-side free list
+    that hands pages to sequences: allocate by TRUE prompt length at
+    prefill, extend one page at a time when a decode frontier crosses a
+    page boundary, free on eos/completion.  Page 0 is
     the reserved trash page (pad rows and unallocated table entries point
     there; it is never handed out), so ``capacity == num_pages - 1``.
     Sequences therefore share cache HBM by actual length instead of

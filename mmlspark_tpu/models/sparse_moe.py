@@ -56,6 +56,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .transformer import paged_write
+
 F32 = jnp.float32
 _NEG = -jnp.inf
 
@@ -81,19 +83,6 @@ def _rope(x, positions, theta):
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _paged_write(slab, new, positions, page_table):
-    """Scatter ``new`` (B, L, C) into ``slab`` (pages, page_size, C) at the
-    pages ``page_table`` (B, W) gives each position; a position past the
-    table's width goes to the trash page 0 (the offset-prefill contract of
-    ``transformer._paged_cache_update``)."""
-    page_size, W = slab.shape[1], page_table.shape[1]
-    bidx = jnp.arange(page_table.shape[0])[:, None]
-    logical = positions // page_size
-    phys = jnp.where(logical < W,
-                     page_table[bidx, jnp.minimum(logical, W - 1)], 0)
-    return slab.at[phys, positions % page_size].set(new.astype(slab.dtype))
 
 
 def _lanes(n: int) -> int:
@@ -314,7 +303,7 @@ class SparseMoEBlock(nn.Module):
 
         if page_table is not None:
             with jax.named_scope("lm.cache_write"):
-                ck, cv, ci = (_paged_write(slab, new, positions, page_table)
+                ck, cv, ci = (paged_write(slab, new, positions, page_table)
                               for slab, new in zip(
                                   kv_cache, (k, v, _to_lanes(k_i))))
             kv_cache = (ck, cv, ci)

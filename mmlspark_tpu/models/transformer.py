@@ -22,14 +22,21 @@ position, so padded prompt tails are overwritten before any real query can
 attend to them (see docs/runner.md, "Decode correctness").
 
 Paged decode (ISSUE 12): the same cached attention also runs over a PAGED
-cache — pool slabs of ``(num_pages, page_size, heads, head_dim)``
+cache — pool slabs of ``(num_pages, page_size, heads * head_dim)``
 (``init_paged_cache``) addressed through a per-sequence ``page_table``
 (B, W) int32, the serving pattern the TPU-vs-GPU Gemma study in PAPERS.md
-benchmarks.  The write scatters into ``(table[pos // ps], pos % ps)``; the
-read gathers each sequence's pages back into position order, so gathered
-slot s is absolute position s and the SAME strict ``s <= q_pos``
-admissibility mask applies — prefill logits are identical to the dense
-path.  Page 0 is the reserved trash page: pad rows and any write whose
+benchmarks.  Heads and head dimension are ONE minor axis (ISSUE 35): as two
+axes, GPT-2 XL's ``(25, 64)`` were tiled to ``(32, 128)`` on the chip, and
+every step and every join copied all 96 slabs of the pool into that padded
+form and back, 46 ms of a 75.7 ms step on a v5e (PERF.md, PR 35); a row of
+``heads * head_dim`` lanes is written in place.  The write scatters into
+``(table[pos // ps], pos % ps)``; the read gathers each sequence's pages
+back into position order, so gathered slot s is absolute position s and the
+SAME strict ``s <= q_pos`` admissibility mask applies — prefill logits are
+identical to the dense path.  A prefill splits the heads on that gathered
+context; a decode step (one query row a sequence) leaves the context in its
+merged lanes and lays the query out block-diagonally instead, because the
+split is a relayout of the whole table width on the chip.  Page 0 is the reserved trash page: pad rows and any write whose
 logical page is unallocated land there (unallocated table entries are 0)
 and no real sequence is ever given it, so garbage writes cannot corrupt
 live pages; pad-tail writes into a sequence's own allocated last page are
@@ -59,15 +66,16 @@ def _cache_update(cache_kv, k_new, v_new, positions):
     return ck, cv
 
 
-def _paged_cache_update(cache_kv, k_new, v_new, positions, page_table):
-    """Scatter this call's per-token k/v into shared POOL pages.
+def paged_write(slab, new, positions, page_table):
+    """Scatter this call's per-token rows ``new`` (B, L, C) into the shared
+    POOL slab (num_pages, page_size, C): the one paged write of ``models/``
+    (keys, values, and ``sparse_moe``'s indexer keys).
 
-    ``cache_kv`` = (k, v) each (num_pages, page_size, H, D) — pool-level,
-    shared by every sequence; ``page_table`` (B, W) int32 maps a sequence's
-    logical page j (absolute positions [j*page_size, (j+1)*page_size)) to
-    its physical pool page.  Unallocated table entries are 0, the reserved
-    trash page, so pad rows and pad-tail prompt positions write garbage
-    into a page no real sequence ever reads.
+    ``page_table`` (B, W) int32 maps a sequence's logical page j (absolute
+    positions [j*page_size, (j+1)*page_size)) to its physical pool page.
+    Unallocated table entries are 0, the reserved trash page, so pad rows
+    and pad-tail prompt positions write garbage into a page no real
+    sequence ever reads.
 
     Offset-prefill contract (ISSUE 20): ``positions`` need not start at 0
     — a prefix-cache hit prefills only the uncached suffix with positions
@@ -75,18 +83,18 @@ def _paged_cache_update(cache_kv, k_new, v_new, positions, page_table):
     cached pages.  Positions whose logical page falls PAST the table's
     width are routed to the trash page explicitly: a raw gather would
     clamp them to column W-1, and under prefix sharing that column's page
-    can be live shared state owned by other sequences."""
-    ck, cv = cache_kv
-    page_size = ck.shape[1]
-    W = page_table.shape[1]
+    can be live shared state owned by other sequences.
+
+    ``C`` is ONE minor axis so that the scatter writes a row in place (two
+    minor axes had the chip copy the whole slab in and out: see the module
+    header)."""
+    page_size, W = slab.shape[1], page_table.shape[1]
     bidx = jnp.arange(page_table.shape[0])[:, None]    # (B, 1)
     logical = positions // page_size                   # (B, L) logical page
     phys = jnp.where(logical < W,                      # (B, L) physical page
                      page_table[bidx, jnp.minimum(logical, W - 1)], 0)
     slot = positions % page_size                       # (B, L) slot in page
-    ck = ck.at[phys, slot].set(k_new.astype(ck.dtype))
-    cv = cv.at[phys, slot].set(v_new.astype(cv.dtype))
-    return ck, cv
+    return slab.at[phys, slot].set(new.astype(slab.dtype))
 
 
 class MultiHeadAttention(nn.Module):
@@ -116,21 +124,42 @@ class MultiHeadAttention(nn.Module):
                 raise ValueError("kv_cache requires explicit positions")
             q, k, v = jnp.split(qkv.reshape(B, L, 3, H, D), 3, axis=2)
             q, k, v = q[:, :, 0], k[:, :, 0], v[:, :, 0]   # (B, L, H, D)
+            # a decode step over the paged cache attends in the slab's own
+            # merged lanes (below); every other call splits the heads
+            merged = page_table is not None and L == 1
             if page_table is not None:
-                # paged path: k/v land in pool pages addressed through the
-                # table; the read gathers each sequence's pages back into
-                # (B, W*page_size, H, D), where gathered slot s IS absolute
-                # position s (logical page j covers [j*ps, (j+1)*ps)), so
-                # the admissibility mask below is identical to dense.
-                ck, cv = _paged_cache_update(kv_cache, k, v, positions,
-                                             page_table)
-                W, page_size = page_table.shape[1], ck.shape[1]
-                keys = ck[page_table].reshape(B, W * page_size, H, D)
-                vals = cv[page_table].reshape(B, W * page_size, H, D)
+                # paged path: k/v rows, heads merged into the lanes, land in
+                # pool pages addressed through the table; the read gathers
+                # each sequence's pages back into (B, W*page_size, C), where
+                # gathered slot s IS absolute position s (logical page j
+                # covers [j*ps, (j+1)*ps)), so the admissibility mask below
+                # is identical to dense.  The heads are split on the
+                # GATHERED context, never on the pool.
+                ck, cv = (paged_write(slab, new.reshape(B, L, H * D),
+                                      positions, page_table)
+                          for slab, new in zip(kv_cache, (k, v)))
+                S = page_table.shape[1] * ck.shape[1]
+                keys, vals = (slab[page_table].reshape(B, S, H * D)
+                              for slab in (ck, cv))
+                if not merged:
+                    keys, vals = (c.reshape(B, S, H, D) for c in (keys, vals))
             else:
                 ck, cv = _cache_update(kv_cache, k, v, positions)
                 keys, vals = ck, cv
-            s = jnp.einsum("blhd,bshd->bhls", q, keys) / jnp.sqrt(D)
+            if merged:
+                # one query row a sequence: splitting the (B, S, C) context
+                # into heads is a relayout of all of it on the chip (41% of
+                # the device's busy time, PERF.md PR 35), so the QUERY is
+                # laid out instead, block-diagonally as (C, H): lane c
+                # belongs to head c // D, every other entry is an exact
+                # zero, and scores and output are plain matrix products
+                # over the context as gathered.  Same products, same sums.
+                own = jnp.arange(H * D)[:, None] // D == jnp.arange(H)
+                s = jnp.einsum("bsc,bch->bhs", keys, jnp.where(
+                    own, q.reshape(B, H * D, 1), 0))[:, :, None]
+            else:
+                s = jnp.einsum("blhd,bshd->bhls", q, keys)
+            s = s / jnp.sqrt(D)
             # keys admissible strictly by absolute position: slot s serves
             # query l iff s <= positions[b, l].  Slots past a sequence's
             # frontier hold zeros or stale pad-token k/v, but every decode
@@ -140,10 +169,15 @@ class MultiHeadAttention(nn.Module):
             # construction, so the trash page is never admissible.)
             key_pos = jnp.arange(keys.shape[1])[None, None, None, :]
             admissible = key_pos <= positions[:, None, :, None]
-            s = jnp.where(admissible, s, -1e30)
-            out = jnp.einsum("bhls,bshd->blhd", nn.softmax(s, axis=-1),
-                             vals.astype(s.dtype))
-            out = out.reshape(B, L, H * D)
+            p = nn.softmax(jnp.where(admissible, s, -1e30), axis=-1)
+            if merged:
+                # (B, H, C): head h's mix of every lane; its own D are kept
+                out = jnp.einsum("bhs,bsc->bhc", p[:, :, 0],
+                                 vals.astype(p.dtype))
+                out = jnp.where(own.T, out, 0).sum(1)[:, None]
+            else:
+                out = jnp.einsum("bhls,bshd->blhd", p, vals.astype(p.dtype))
+                out = out.reshape(B, L, H * D)
             return nn.Dense(x.shape[-1], dtype=self.dtype,
                             name="proj")(out), (ck, cv)
         q, k, v = jnp.split(qkv.reshape(B, L, 3, H, D).transpose(2, 0, 3, 1, 4), 3)
@@ -269,11 +303,13 @@ class TransformerEncoder(nn.Module):
 
     def init_paged_cache(self, num_pages: int, page_size: int):
         """Zeroed PAGED KV-cache pytree: ``num_layers`` pairs of
-        ``(num_pages, page_size, heads, head_dim)`` pool slabs, shared by
+        ``(num_pages, page_size, heads * head_dim)`` pool slabs, shared by
         every sequence through a per-sequence page table (see
-        ``models/runner.py::PagePool``).  Page 0 is reserved as the trash
-        page for pad rows and pad-tail prompt writes, so a usable pool
-        needs ``num_pages >= 2``.  Unlike ``init_cache``, the pool is sized
+        ``models/runner.py::PagePool``).  Heads and head dimension are one
+        minor axis, as in ``SparseMoEDecoder.init_paged_cache``, so that
+        ``paged_write`` updates a page in place (module header).  Page 0 is
+        reserved as the trash page for pad rows and pad-tail prompt writes,
+        so a usable pool needs ``num_pages >= 2``.  Unlike ``init_cache``, the pool is sized
         by TOTAL tokens across sequences, not ``batch * cache_len`` — the
         memory model that lets concurrency scale with actual lengths."""
         if num_pages < 2:
@@ -283,6 +319,6 @@ class TransformerEncoder(nn.Module):
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         head_dim = self.embed_dim // self.num_heads
-        shape = (num_pages, page_size, self.num_heads, head_dim)
+        shape = (num_pages, page_size, self.num_heads * head_dim)
         return tuple((jnp.zeros(shape, self.dtype), jnp.zeros(shape, self.dtype))
                      for _ in range(self.num_layers))

@@ -30,35 +30,45 @@ import pytest
 DECODE_ATOL = 1e-4
 
 
-def _tiny_lm(vocab=48, layers=2, seed=0, max_len=128):
+def _tiny_lm(vocab=48, layers=2, seed=0, max_len=128, embed_dim=32,
+             num_heads=2):
     import jax
     import jax.numpy as jnp
     from mmlspark_tpu.models import TransformerEncoder
     mod = TransformerEncoder(vocab_size=vocab, num_classes=vocab,
-                             embed_dim=32, num_heads=2, num_layers=layers,
-                             mlp_dim=64, max_len=max_len, causal=True,
-                             pool="none")
+                             embed_dim=embed_dim, num_heads=num_heads,
+                             num_layers=layers, mlp_dim=64, max_len=max_len,
+                             causal=True, pool="none")
     variables = mod.init(jax.random.PRNGKey(seed),
                          jnp.zeros((1, 4), jnp.int32))
     return mod, variables
 
 
-def _runner(name, layers=2, registry=None):
+def _runner(name, layers=2, registry=None, **width):
     from mmlspark_tpu.models import ModelRunner
-    mod, variables = _tiny_lm(layers=layers)
+    mod, variables = _tiny_lm(layers=layers, **width)
     return ModelRunner(module=mod, variables=variables, name=name,
                        registry=registry)
 
 
-#: the pure-parity tests share one runner (warm dense executables across
-#: tests); tests that assert counters or compile deltas build their own
+#: the pure-parity tests share one runner a width (warm dense executables
+#: across tests); tests that assert counters or compile deltas build their
+#: own
 _SHARED = {}
 
+#: (embed_dim, num_heads) of the paged-against-dense checks: the slab's
+#: minor axis ``heads * head_dim`` under one 128-lane row, NOT in whole
+#: lane rows with an odd head count (GPT-2 XL's case: 25 heads of 64 are
+#: 12.5 rows), and exactly one row
+WIDTHS = [(32, 2), (80, 5), (128, 2)]
 
-def _shared_runner():
-    runner = _SHARED.get("runner")
+
+def _shared_runner(embed_dim=32, num_heads=2):
+    runner = _SHARED.get((embed_dim, num_heads))
     if runner is None:
-        runner = _SHARED["runner"] = _runner("paged.shared")
+        runner = _SHARED[embed_dim, num_heads] = _runner(
+            f"paged.shared.{embed_dim}", embed_dim=embed_dim,
+            num_heads=num_heads)
     return runner
 
 
@@ -66,13 +76,15 @@ def _shared_runner():
 # paged-vs-dense parity
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("embed_dim,num_heads", WIDTHS)
 @pytest.mark.parametrize("page_size", [3, 8])
-def test_paged_greedy_tokens_bit_identical_across_ragged_lengths(page_size):
+def test_paged_greedy_tokens_bit_identical_across_ragged_lengths(
+        page_size, embed_dim, num_heads):
     """The acceptance gate: greedy generation through the paged cache emits
     the SAME token ids as the dense reservation — ragged prompts, a pad
     row (B=3 buckets to 4), and decode frontiers that cross page
     boundaries (max_new_tokens=9 crosses every page_size here)."""
-    runner = _shared_runner()
+    runner = _shared_runner(embed_dim, num_heads)
     rng = np.random.default_rng(1)
     lengths = np.asarray([7, 4, 2], np.int32)
     prompts = rng.integers(0, 48, (3, 7)).astype(np.int32)
@@ -105,11 +117,12 @@ def test_paged_eos_early_stop_matches_dense():
     assert dense.extras["real_tokens"] == paged.extras["real_tokens"]
 
 
-def test_paged_logits_match_dense_within_committed_atol():
+@pytest.mark.parametrize("embed_dim,num_heads", WIDTHS)
+def test_paged_logits_match_dense_within_committed_atol(embed_dim, num_heads):
     """collect_logits rides the host-sampling (non-fused) path: the full
     per-step distributions must agree within the committed tolerance, and
     the sampled tokens must still match exactly."""
-    runner = _shared_runner()
+    runner = _shared_runner(embed_dim, num_heads)
     rng = np.random.default_rng(2)
     lengths = np.asarray([7, 4, 2], np.int32)
     prompts = rng.integers(0, 48, (3, 7)).astype(np.int32)
@@ -146,6 +159,25 @@ def test_paged_logits_match_dense_within_committed_atol():
 # ---------------------------------------------------------------------------
 # pool accounting
 # ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("embed_dim,num_heads", WIDTHS)
+def test_paged_slabs_are_pages_by_slots_by_merged_heads(embed_dim, num_heads):
+    """The ONE paged layout: per layer a (k, v) pair of 3-D slabs
+    ``(pages, page_size, heads * head_dim)``, heads merged into the minor
+    axis (``models/transformer.py``'s header says why), and the pool's
+    bytes a page are exactly layers x 2 x page_size x C x itemsize."""
+    from mmlspark_tpu.models import PagePool
+    mod, _ = _tiny_lm(layers=3, embed_dim=embed_dim, num_heads=num_heads)
+    pages, ps = 5, 4
+    cache = mod.init_paged_cache(pages, ps)
+    assert len(cache) == 3 and all(len(kv) == 2 for kv in cache)
+    for slab in (s for kv in cache for s in kv):
+        assert slab.shape == (pages, ps, embed_dim)
+    pool = PagePool(mod, num_pages=pages, page_size=ps, name="paged.shape")
+    assert pool.page_nbytes() == 0                  # no slabs built yet
+    pool.return_cache(pool.borrow_cache())
+    assert pool.page_nbytes() == 3 * 2 * ps * embed_dim * 4
+
 
 def test_pad_rows_never_allocate_pages():
     """B=3 buckets to 4: the pad row is born finished and must never hold
