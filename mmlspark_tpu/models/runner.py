@@ -172,20 +172,42 @@ def _greedy_freeze(logits, finished, eos_id):
     return tok, finished
 
 
+def _keeps_window_state(module) -> bool:
+    """Whether ``module`` keeps per-slot window state beside its pages
+    (``init_window_cache(rows)``; see :class:`PagePool`)."""
+    return hasattr(module, "init_window_cache")
+
+
+def _refuse_window_prefix(module) -> None:
+    """Prefix sharing over window-layer state is not built: refuse it by
+    name rather than serve a hit whose window layers never saw the prefix."""
+    if _keeps_window_state(module):
+        raise ValueError(
+            "prefix_cache=True is not supported for a module with window "
+            f"layers ({type(module).__name__}): the prefix index shares "
+            "PAGES, and a window layer keeps its last positions in a "
+            "per-slot ring that a hit would leave unwritten")
+
+
 def _cached_apply(module, variables, toks, positions, table, cache,
-                  logits_at=None):
+                  logits_at=None, rows=None):
     """One call shape for every decode executable: ``table`` is ``None`` on
     the dense layout (an empty pytree — part of the jit signature, no
     tracing cost) and the kwarg is withheld so modules that only know
     ``init_cache`` keep working.  ``logits_at`` (B,) has the module apply
     its head to that one row of each sequence (prefill: the last real
-    position), so the (B, P, vocab) logits are never built.  Returns
+    position), so the (B, P, vocab) logits are never built.  ``rows`` (B,)
+    names each sequence's row of the per-slot state a module with
+    ``init_window_cache`` keeps beside its pages (a join prefills ONE
+    sequence into its slot's row; without it sequence b uses row b).  Returns
     ``(logits, cache, sown)``: ``sown`` is what the module sowed into
     ``intermediates`` (a routed model's ``experts_touched``) and ``{}`` —
     no output of the compiled program — for a module that sows nothing."""
     kw = {} if table is None else {"page_table": table}
     if logits_at is not None:
         kw["logits_at"] = logits_at
+    if rows is not None:
+        kw["cache_rows"] = rows
     (logits, cache), sown = module.apply(
         variables, toks, positions=positions, kv_cache=cache,
         mutable=["intermediates"], **kw)
@@ -234,6 +256,17 @@ class PagePool:
     previous borrower returns.  The accounting half (allocate/extend/free/
     occupancy) is lock-protected and usable standalone — sizing studies
     never have to build device slabs.
+
+    Window state (ISSUE 36): a module whose window-attention layers keep a
+    bounded ring of positions a SLOT instead of pages (it has
+    ``init_window_cache(rows)``) gets that state from this pool too, built
+    for the borrower's row count and handed out and returned WITH the slabs
+    as ``(slabs, window state)``.  Pages keep their meaning: capacity,
+    occupancy, ``page_nbytes`` and the ``mmlspark_runner_page_*`` series
+    count pages of the paged layers only; the window state's bytes are
+    :meth:`window_nbytes` and ``mmlspark_runner_window_state_bytes``.  It
+    needs no allocation and no free: a slot's ring is rewritten by whoever
+    holds the slot, and what a row holds is read by position alone.
     """
 
     #: booking ops — each books pages moved, not call count ("denied"
@@ -269,6 +302,10 @@ class PagePool:
         self._cond = make_condition("PagePool._cond")
         self._cache = None          # built lazily, rebuilt if dropped
         self._cache_nbytes = 0
+        #: rows (slots) the window state in ``_cache`` was built for, and
+        #: its bytes; 0 for a module without ``init_window_cache``
+        self._window_rows = 0
+        self._window_nbytes = 0
         self._borrowed = False
         self.high_water = 0
         #: True when the owning runner sized this pool implicitly (from a
@@ -296,6 +333,11 @@ class PagePool:
         self._g_hw = reg.gauge(
             "mmlspark_runner_page_pool_high_water_pages",
             "max KV pages ever simultaneously held",
+            labels=("runner", "page_size"))
+        self._g_window = reg.gauge(
+            "mmlspark_runner_window_state_bytes",
+            "device bytes of the per-slot window state kept beside the "
+            "pages (0 for a module all of whose layers are paged)",
             labels=("runner", "page_size"))
         # page-seconds integral (ISSUE 17): pages held x wall time,
         # integrated exactly at the alloc/extend/free edges — the memory
@@ -448,10 +490,22 @@ class PagePool:
         return self._cache_nbytes // self.num_pages if self._cache_nbytes \
             else 0
 
-    def borrow_cache(self):
+    def window_nbytes(self) -> int:
+        """Device bytes of the window state (0 until built, and for a
+        module that keeps none): bounded by the window and the borrower's
+        rows, whatever the pool's pages."""
+        return self._window_nbytes
+
+    def borrow_cache(self, window_rows: int = 0):
         """Take exclusive ownership of the device slabs (building them on
         first use), blocking while another decode holds them — the step
-        executables donate the buffers, so exactly one loop may own them."""
+        executables donate the buffers, so exactly one loop may own them.
+        For a module with ``init_window_cache`` the result is ``(slabs,
+        window state)``, the state built for ``window_rows`` sequences (the
+        borrower's slots); it is rebuilt when the row count changes, and
+        what it held is nobody's: no request outlives a borrow.  A borrower
+        that names no rows (one that only drops the slabs) gets the state as
+        it stands, ``None`` if none was built."""
         if self.module is None:
             raise TypeError("this PagePool was built without a module — "
                             "accounting only, no device slabs")
@@ -461,18 +515,33 @@ class PagePool:
             self._borrowed = True
             cache = self._cache
             self._cache = None
-        if cache is None:
-            try:
-                cache = self.module.init_paged_cache(self.num_pages,
+        windowed = _keeps_window_state(self.module)
+        try:
+            import jax
+
+            def nbytes(tree):
+                return sum(int(l.nbytes)
+                           for l in jax.tree_util.tree_leaves(tree))
+            if cache is None:
+                slabs = self.module.init_paged_cache(self.num_pages,
                                                      self.page_size)
-                import jax
-                self._cache_nbytes = sum(
-                    int(l.nbytes) for l in jax.tree_util.tree_leaves(cache))
-            except Exception:
-                # a failed slab build (HBM exhaustion) must not leave the
-                # pool borrowed forever — every later borrower would block
-                self.return_cache(None)
-                raise
+                self._cache_nbytes = nbytes(slabs)
+                cache = (slabs, None) if windowed else slabs
+                self._window_rows = 0
+            if windowed and window_rows \
+                    and self._window_rows != int(window_rows):
+                state = self.module.init_window_cache(int(window_rows))
+                cache = (cache[0], state)
+                self._window_rows = int(window_rows)
+                self._window_nbytes = nbytes(state)
+                self._g_window.set(float(self._window_nbytes),
+                                   runner=self._name,
+                                   page_size=str(self.page_size))
+        except Exception:
+            # a failed slab build (HBM exhaustion) must not leave the
+            # pool borrowed forever — every later borrower would block
+            self.return_cache(None)
+            raise
         return cache
 
     def resized(self, num_pages: int) -> "PagePool":
@@ -682,6 +751,12 @@ class ModelRunner:
             "mmlspark_runner_moe_experts_touched_total",
             "distinct experts with at least one token, summed over the "
             "layers and the decode steps of a routed model",
+            labels=("runner",)).labels(runner=name)
+        self._c_local_assignments = reg.counter(
+            "mmlspark_runner_moe_local_assignments_total",
+            "token-expert assignments that landed on an expert held here, "
+            "summed over the layers and the decode steps of a routed model "
+            "that holds a share of its experts",
             labels=("runner",)).labels(runner=name)
         # tail-tolerance surface (ISSUE 16): stall + supervised-restart
         # families registered at construction so the telemetry sweep gates
@@ -1102,14 +1177,14 @@ class ModelRunner:
             prefill = self._executables.get(kp)
             if prefill is None:
                 def _prefill(variables, toks, positions, lengths, table,
-                             cache, _m=module):
+                             cache, rows=None, _m=module):
                     # the head runs on the last REAL position of each
                     # sequence only: the (B, P, V) logits are never built,
                     # let alone fetched (ISSUE 34: at a vocabulary of 152k
                     # they were 311 MB a join for one row of use)
                     logits, cache, _ = _cached_apply(
                         _m, variables, toks, positions, table, cache,
-                        logits_at=lengths - 1)
+                        logits_at=lengths - 1, rows=rows)
                     return logits[:, 0], cache
 
                 prefill = self._executables[kp] = self._instrumented(
@@ -1252,6 +1327,8 @@ class ModelRunner:
         if prefix_cache and not paged:
             raise ValueError("prefix_cache=True needs kv_layout='paged' — "
                              "the cache shares resident PagePool pages")
+        if prefix_cache:
+            _refuse_window_prefix(self.module)
         if prefix_cache and (sample_fn is not None or collect_logits):
             raise ValueError(
                 "prefix_cache=True supports the greedy fused path only: "
@@ -1330,7 +1407,7 @@ class ModelRunner:
                         suffix = int(lengths[b]) - covered
                         toks[b, :] = 0
                         toks[b, :suffix] = prompts[b, covered:int(lengths[b])]
-                cache = pool.borrow_cache()
+                cache = pool.borrow_cache(B_b)
             except Exception:
                 # a failed allocation or slab build must not leak the pages
                 # already handed to earlier rows (borrow_cache resets its
@@ -1891,6 +1968,8 @@ class ContinuousDecoder:
         if slots < 1 or prompt_bucket < 1 or max_new_tokens < 1:
             raise ValueError("slots, prompt_bucket and max_new_tokens "
                              "must all be >= 1")
+        if prefix_cache:
+            _refuse_window_prefix(module)
         self.runner = runner
         self.slots = int(slots)
         self.prompt_bucket = int(prompt_bucket)
@@ -1958,6 +2037,14 @@ class ContinuousDecoder:
         #: next dispatch re-uploads the mutated host state
         self._tok_dev = None
         self._fin_dev = None
+        #: per slot, the device-resident (1,) row index a join's prefill
+        #: names its slot's window state by; None for a module all of whose
+        #: layers are paged (its prefill takes no such argument)
+        self._slot_rows = None
+        if _keeps_window_state(module):
+            import jax.numpy as jnp
+            self._slot_rows = [jnp.asarray([s], jnp.int32)
+                               for s in range(self.slots)]
         self._handles: List[Optional[StreamHandle]] = [None] * self.slots
         self._free: List[int] = list(range(self.slots - 1, -1, -1))
         self._arrivals: "deque[StreamHandle]" = deque()
@@ -2147,7 +2234,12 @@ class ContinuousDecoder:
     # ----------------------------------------------------------------- engine
     def _borrow(self) -> None:
         if self._cache is None:
-            self._cache = self.pool.borrow_cache()
+            self._cache = self.pool.borrow_cache(self.slots)
+
+    def _row_arg(self, s: int) -> Tuple:
+        """The join prefill's trailing argument for slot ``s``: its row of
+        the window state, or nothing for a module that keeps none."""
+        return (self._slot_rows[s],) if self._slot_rows else ()
 
     def _return_cache_if_idle(self) -> None:
         """Hand the borrowed slabs back while the engine is EMPTY (no live
@@ -2180,7 +2272,8 @@ class ContinuousDecoder:
             table1 = jnp.zeros((1, self.table_w), jnp.int32)
             last, self._cache = self._prefill1(
                 variables, jnp.zeros((1, P_b), jnp.int32), positions,
-                jnp.ones(1, jnp.int32), table1, self._cache)
+                jnp.ones(1, jnp.int32), table1, self._cache,
+                *self._row_arg(0))
             self._sample1(last, jnp.ones(1, bool))
             _t, _f, self._cache, _ = self._step(
                 variables, jnp.zeros(S, jnp.int32),
@@ -2305,6 +2398,7 @@ class ContinuousDecoder:
             # compile key per hit length or prompt length.  Only the last
             # chunk's logits are sampled
             jtable_dev = jnp.asarray(jtable)
+            row = self._row_arg(s)
             for at in range(off, h.length, P_b):
                 n = min(P_b, h.length - at)
                 toks = np.zeros((1, P_b), np.int32)
@@ -2312,7 +2406,8 @@ class ContinuousDecoder:
                 last, self._cache = self._prefill1(
                     runner.variables, jnp.asarray(toks),
                     jnp.asarray(positions + at) if at else pos_dev,
-                    jnp.asarray([n], np.int32), jtable_dev, self._cache)
+                    jnp.asarray([n], np.int32), jtable_dev, self._cache,
+                    *row)
                 runner._c_prefill_chunks.inc()
                 if self.watchdog is not None and at + P_b < h.length:
                     # the timeout bounds ONE dispatch, not a long join
@@ -2444,6 +2539,8 @@ class ContinuousDecoder:
         runner._c_decode_steps.inc()
         for n in sown.get("experts_touched", ()):
             runner._c_experts_touched.inc(float(n))
+        for n in sown.get("local_assignments", ()):
+            runner._c_local_assignments.inc(float(n))
         # attribution (ISSUE 17): the whole step's host-observed device
         # work (enqueue + device wait) is amortized over the slots that
         # had a live request behind them at dispatch; the rest of the
@@ -2554,7 +2651,8 @@ class ContinuousDecoder:
             "page_size": self.pool.page_size,
             "capacity": self.pool.capacity,
             "pages_in_use": self.pool.pages_in_use(),
-            "occupancy_pct": round(self.pool.occupancy_pct(), 2)}
+            "occupancy_pct": round(self.pool.occupancy_pct(), 2),
+            "window_state_bytes": self.pool.window_nbytes()}
         if self.index is not None:
             state["prefix_cache"] = self.index.stats()
         return state

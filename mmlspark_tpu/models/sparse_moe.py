@@ -174,20 +174,31 @@ def masked_attention(q, k, v, select):
     return jnp.moveaxis(out[:, :, :, 0], 0, 2).reshape(B, L, H * d)
 
 
-def routed_experts(x, gate, up, down, expert_ids, weights, block_rows=64):
+def routed_experts(x, gate, up, down, expert_ids, weights, block_rows=64,
+                   first=0):
     """``sum_k weights[t, k] * FFN_{expert_ids[t, k]}(x[t])`` for ``x`` (T, D):
     float32 (T, D), and the number of experts that had a token.
 
     Assignments are sorted by expert; each expert's rows go through its
     matrices in blocks of ``block_rows``; the loop takes one turn a
     NON-EMPTY block, so an expert without a token is never read and no
-    token is dropped whatever the imbalance."""
+    token is dropped whatever the imbalance.
+
+    The stacked matrices may be ONE CHIP'S SHARE of a wider router (ISSUE
+    36): they are experts ``first ... first + E - 1`` and ``expert_ids`` run
+    over the router's whole width.  An assignment to an expert not held
+    sorts behind every held one, no block serves it and it adds nothing: the
+    sum is this share's part of the layer's result, and with no assignment
+    landing here no expert is read at all.  With every expert held nothing
+    sorts behind and the sums are bit for bit those of the layer before it
+    took a share."""
     T, D = x.shape
     k, E = expert_ids.shape[1], gate.shape[0]
     A, Tb = T * k, min(T, block_rows)
-    flat = expert_ids.reshape(A)
+    flat = expert_ids.reshape(A) - first
+    flat = jnp.where((flat >= 0) & (flat < E), flat, E)      # E: not held
+    sizes = jnp.zeros(E + 1, jnp.int32).at[flat].add(1)[:E]
     order = jnp.argsort(flat, stable=True)
-    sizes = jnp.zeros(E, jnp.int32).at[flat].add(1)
     ends = jnp.cumsum(sizes)
     blocks = (sizes + Tb - 1) // Tb                  # of each expert
     block_ends = jnp.cumsum(blocks)
@@ -241,10 +252,13 @@ class RMSNorm(nn.Module):
 
 
 class Experts(nn.Module):
-    """The stacked matrices of a layer's routed experts."""
+    """The stacked matrices of the routed experts a layer holds:
+    ``num_experts`` of them, from ``first_expert`` of the router's width
+    on."""
     num_experts: int
     expert_dim: int
     dtype: Any = jnp.float32
+    first_expert: int = 0
 
     @nn.compact
     def __call__(self, x, expert_ids, weights):
@@ -255,7 +269,7 @@ class Experts(nn.Module):
         down = self.param("down", init, (E, Fe, D))
         return routed_experts(x.astype(self.dtype), gate.astype(self.dtype),
                               up.astype(self.dtype), down.astype(self.dtype),
-                              expert_ids, weights)
+                              expert_ids, weights, first=self.first_expert)
 
 
 class SparseMoEBlock(nn.Module):
