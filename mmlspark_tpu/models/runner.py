@@ -684,6 +684,19 @@ class ModelRunner:
             "per-sequence real generated tokens (unfrozen steps only; "
             "eos-frozen tails and pad rows are not generated work)",
             labels=("runner",)).labels(runner=name)
+        # the continuous engine's one step in flight (ISSUE 37): how often
+        # a step was dispatched beside the one before it, and the rows that
+        # costs (learnt too late that they had nothing left to compute)
+        self._c_steps_overlapped = reg.counter(
+            "mmlspark_runner_decode_steps_overlapped_total",
+            "continuous-decode steps dispatched while the step before them "
+            "was still unfetched (the engine thread's one step in flight)",
+            labels=("runner",)).labels(runner=name)
+        self._c_stale_rows = reg.counter(
+            "mmlspark_runner_decode_stale_rows_total",
+            "continuous-decode step rows computed for nothing: retired "
+            "against a handle that had left, or stepped after their last "
+            "token", labels=("runner",)).labels(runner=name)
         # decode-loop dispatch/device split (ISSUE 15): dispatch = host
         # time to enqueue each step program, device = sampled
         # block_until_ready wait every device_time_every steps — the
@@ -1913,6 +1926,19 @@ class StreamHandle:
             extras={"status": self.status, "ttft_s": self.ttft_s})
 
 
+@dataclass
+class _StepInFlight:
+    """What :meth:`ContinuousDecoder._dispatch` hands to ``_retire``."""
+    #: the handles by slot AS THEY WERE at the dispatch; None for a row
+    #: that was not stepped (no request, or its last token already taken)
+    rows: List[Optional["StreamHandle"]]
+    stepped: int          # rows with a handle
+    dead: int             # rows stepped after their last token
+    tok_d: Any            # the step's tokens and what the module sowed, on
+    sown_d: Any           # the device; neither is donated to the next step
+    t0: float             # perf_counter at the dispatch
+
+
 class ContinuousDecoder:
     """Slot-level continuous batching on the paged KV pool (ISSUE 13).
 
@@ -1940,11 +1966,17 @@ class ContinuousDecoder:
     partial generation.
 
     Metrics: ``mmlspark_runner_slots_{joined,left}_total``,
-    ``mmlspark_runner_slot_occupancy_pct``, and the
-    ``mmlspark_runner_ttft_seconds`` histogram, all labelled by runner.
+    ``mmlspark_runner_slot_occupancy_pct``, the
+    ``mmlspark_runner_ttft_seconds`` histogram, and
+    ``mmlspark_runner_decode_{steps_overlapped,stale_rows}_total``, all
+    labelled by runner.
 
-    Threading: ``submit`` is thread-safe; :meth:`step` must have ONE
-    driver — the :meth:`start` engine thread, or a single test/bench loop.
+    Threading: ``submit`` is thread-safe; the engine must have ONE
+    driver — the :meth:`start` engine thread, or a single test/bench loop
+    calling :meth:`step`.  Both run the same round (:meth:`_round`: splice
+    arrivals, dispatch a step, retire the step before it); ``step()``
+    retires what it dispatched before it returns, the thread leaves it in
+    flight for the next round (docs/runner.md, "The engine's round").
     The decoder borrows the pool's device slabs at the first join and
     returns them at :meth:`close` (one-shot paged decodes on the same pool
     block until then, by the PR 12 borrow contract).
@@ -2033,10 +2065,17 @@ class ContinuousDecoder:
         self._table_dirty = True
         #: device-resident copies of _tok/_fin for the steady state — the
         #: previous step's outputs feed the next dispatch directly (as the
-        #: one-shot fused loop does); a join/leave invalidates them so the
-        #: next dispatch re-uploads the mutated host state
+        #: one-shot fused loop does); a join invalidates them so the next
+        #: dispatch re-uploads the host state it spliced into
         self._tok_dev = None
         self._fin_dev = None
+        #: the step dispatched and not yet retired: held by the start()
+        #: thread across the dispatch of the next one; None between two
+        #: step() calls
+        self._in_flight: Optional[_StepInFlight] = None
+        #: perf_counter at the last retirement's fetch: the attribution
+        #: clock charges an overlapped step from here, not from its dispatch
+        self._t_retired = 0.0
         #: per slot, the device-resident (1,) row index a join's prefill
         #: names its slot's window state by; None for a module all of whose
         #: layers are paged (its prefill takes no such argument)
@@ -2294,27 +2333,60 @@ class ContinuousDecoder:
             self._return_cache_if_idle()
 
     def step(self) -> int:
-        """One engine round: splice queued arrivals (join prefill), advance
-        every live slot one fused step, release finished slots (leave).
-        ONE driver only — the :meth:`start` thread or a single test/bench
-        loop.  Returns the number of live slots remaining.
+        """One engine round, whole: splice queued arrivals (join prefill),
+        advance every live slot one fused step, release finished slots
+        (leave).  When it returns, every step it dispatched is RETIRED:
+        its tokens are on their handles, its leaves have left.  ONE driver
+        only — the :meth:`start` thread or a single test/bench loop.
+        Returns the number of live slots remaining.
 
-        The round runs under the ``runner.decode.step`` ambient phase
-        (ISSUE 15): host-stack samples from ``/debug/profile`` attribute
-        the engine thread's time to the decode step loop by name — a span
-        per round would flood the export ring at token cadence, the phase
-        table costs two dict writes."""
-        from ..observability.tracing import _enter_phase, _exit_phase
+        The :meth:`start` thread drives the same :meth:`_round` without the
+        retirement that closes this one: it keeps one step in flight, so
+        the host's work between two steps runs beside the device."""
+        with self._engine_work() as leavers:
+            self._round(leavers)
+            self._retire_in_flight(leavers)
+        if self._live == 0:
+            self._return_cache_if_idle()
+        return self._live
+
+    def _round(self, leavers: List[StreamHandle]) -> None:
+        """Splice arrivals, dispatch the next step, THEN retire the step
+        that was in flight before it: the device runs step N+1 while the
+        host fetches and books step N.  Nothing the dispatch needs comes
+        from that fetch (the sampled tokens feed the next step on the
+        device, positions and page extends follow from counting); only an
+        eos is learnt late, and costs its row one step it did not need
+        (``mmlspark_runner_decode_stale_rows_total``).  The step dispatched
+        here is left in flight."""
         with self._cond:
             joiners = list(self._arrivals)
             self._arrivals.clear()
+        if joiners:
+            self._join(joiners, leavers)
+        prev, self._in_flight = self._in_flight, self._dispatch(leavers)
+        if prev is not None:
+            self._retire(prev, leavers)
+
+    def _retire_in_flight(self, leavers: List[StreamHandle]) -> None:
+        flight, self._in_flight = self._in_flight, None
+        if flight is not None:
+            self._retire(flight, leavers)
+
+    @contextmanager
+    def _engine_work(self):
+        """The bracket of everything the engine's driver does: the
+        ``runner.decode.step`` ambient phase (ISSUE 15: host-stack samples
+        from ``/debug/profile`` attribute the driver's time to the decode
+        loop by name — a span per round would flood the export ring at
+        token cadence, the phase table costs two dict writes), the poison
+        on a failure, and the leavers resolved at the end whatever
+        happened.  Yields the list that collects the leavers."""
+        from ..observability.tracing import _enter_phase, _exit_phase
         leavers: List[StreamHandle] = []
         _phase = _enter_phase("runner.decode.step")
         try:
-            if joiners:
-                self._join(joiners, leavers)
-            if self._live:
-                self._advance(leavers)
+            yield leavers
         except Exception:
             # a failed dispatch leaves the donated slab state unknown —
             # poison the borrow so close()/abort return None and the next
@@ -2326,10 +2398,7 @@ class ContinuousDecoder:
             raise
         finally:
             _exit_phase(_phase)
-        self._finish(leavers)
-        if self._live == 0:
-            self._return_cache_if_idle()
-        return self._live
+            self._finish(leavers)
 
     def _finish(self, leavers: List[StreamHandle]) -> None:
         for h in leavers:
@@ -2389,7 +2458,7 @@ class ContinuousDecoder:
             self._table[s, :n] = h.pages
             self._table_dirty = True
             self._handles[s] = h
-            if self.watchdog is not None:
+            if self.watchdog is not None and self._in_flight is None:
                 self.watchdog.arm("runner.decode.join")
             # the uncovered part of the prompt, chunk after chunk: positions
             # offset past the cached prefix and the chunks before are traced
@@ -2409,6 +2478,15 @@ class ContinuousDecoder:
                     jnp.asarray([n], np.int32), jtable_dev, self._cache,
                     *row)
                 runner._c_prefill_chunks.inc()
+                if self._in_flight is not None:
+                    # the start() thread's step in flight: this prefill is
+                    # queued behind it, so the host retires it while the
+                    # prefill runs.  The splice below needs the host's
+                    # state whole, and the fetch of the joiner's first
+                    # token drains the device anyway
+                    self._retire_in_flight(leavers)
+                    if self.watchdog is not None:
+                        self.watchdog.arm("runner.decode.join")
                 if self.watchdog is not None and at + P_b < h.length:
                     # the timeout bounds ONE dispatch, not a long join
                     last.block_until_ready()
@@ -2445,12 +2523,22 @@ class ContinuousDecoder:
             if fin0 or h.max_new_tokens <= 1:
                 self._release(s, "ok", leavers)
 
-    def _advance(self, leavers: List[StreamHandle]) -> None:
-        """One fused step over the batch: deadline leaves first (never
-        spend a dispatch on a dead client), page-boundary extends (a
-        denial leaves the slot with its partial generation), then the
-        SAME donated step executable one-shot decode dispatches."""
-        import jax
+    def _dispatch(self, leavers: List[StreamHandle]
+                  ) -> Optional[_StepInFlight]:
+        """The first half of a step: deadline leaves (never spend a
+        dispatch on a dead client), positions, page-boundary extends (a
+        denial leaves the slot with its partial generation), uploads, and
+        the SAME donated step executable one-shot decode dispatches.
+        Returns what was dispatched, for :meth:`_retire`; None when no
+        slot has a step left to take.
+
+        Everything here follows from counting: a slot's position is its
+        prompt length + the steps DISPATCHED for it (``_emitted``), the
+        token it feeds is the step before's output, still on the device.
+        A slot whose last token is already dispatched (``max_new_tokens``
+        reached by count) waits for its retirement as a dead row: it gets
+        no page and its write lands one position past its last, in its
+        own tail page or on the trash page."""
         import jax.numpy as jnp
         runner = self.runner
         now = self.clock()
@@ -2458,14 +2546,17 @@ class ContinuousDecoder:
             if h is not None and h.deadline_s is not None \
                     and now > h.deadline_s:
                 self._release(s, "expired", leavers)
-        if not self._live:
-            return
         pos = np.zeros(self.slots, np.int32)
+        rows: List[Optional[StreamHandle]] = [None] * self.slots
+        stepped = dead = 0
         for s, h in enumerate(self._handles):
             if h is None:
                 continue
             p = int(self._lens[s] + self._emitted[s] - 1)
             pos[s] = p
+            if self._emitted[s] >= h.max_new_tokens:
+                dead += 1
+                continue
             pi = p // self.page_size
             needs_page = pi >= len(h.pages)
             # step-site CoW guard (ISSUE 20): this step writes position p;
@@ -2496,8 +2587,11 @@ class ContinuousDecoder:
                         h.cost.page_edge(now, 1)
                 self._table[s, pi] = new_page
                 self._table_dirty = True
-        if not self._live:
-            return
+            rows[s] = h
+            stepped += 1
+            self._emitted[s] += 1
+        if not stepped:
+            return None
         if self._table_dirty or self._table_dev is None:
             self._table_dev = jnp.asarray(self._table)
             self._table_dirty = False
@@ -2506,66 +2600,96 @@ class ContinuousDecoder:
         fin_in = self._fin_dev if self._fin_dev is not None \
             else jnp.asarray(self._fin)
         if self.watchdog is not None:
-            # the armed section covers the dispatch AND the host fetch
-            # below — both are the hang shapes (a hung device dispatch
-            # stalls the fetch; a dead runtime stalls the enqueue)
+            # armed from here until NO step is in flight: the dispatch and
+            # the host fetch are both the hang shapes (a hung device
+            # dispatch stalls the fetch; a dead runtime stalls the
+            # enqueue).  Each retirement restarts the clock, so the
+            # timeout still bounds one step
             self.watchdog.arm("runner.decode.step")
-        t_disp0 = time.perf_counter()
+        t0 = time.perf_counter()
         tok_d, fin_d, self._cache, sown_d = self._step(
             runner.variables, tok_in, jnp.asarray(pos),
             self._table_dev, fin_in, self._cache)
         # dispatch/device split (ISSUE 15): the step call above is the
-        # host enqueue; the token fetch below IS the device wait — already
-        # a sync, so sampling it costs nothing extra
-        disp_s = time.perf_counter() - t_disp0
-        runner._h_phase_dispatch.observe(disp_s)
-        # fin_in was donated (consumed) by the dispatch: rebind both device
-        # copies to the step's outputs; a release below invalidates them
+        # host enqueue; the device's part is waited for in _retire
+        runner._h_phase_dispatch.observe(time.perf_counter() - t0)
+        # fin_in was donated (consumed) by the dispatch, and fin_d will be
+        # by the next: the finished mask lives on the device alone and no
+        # host code reads it.  A release keeps both device copies (its row
+        # has no handle, a zeroed table row and position 0: whatever it
+        # computes lands on the trash page and is dropped at retirement);
+        # a join re-uploads the host's state
         self._tok_dev, self._fin_dev = tok_d, fin_d
-        t_dev0 = time.perf_counter()
-        # the one host fetch of a step: the tokens, the finished flags and
-        # what the module sowed (nothing, but for a routed model's count)
-        tok, fin, sown = jax.device_get((tok_d, fin_d, sown_d))
-        # the fetch IS the device wait (already a sync) — measuring it
-        # every step costs one clock read, so the attribution charge below
-        # uses the true per-step device time, not a sampled estimate
-        dev_s = time.perf_counter() - t_dev0
+        if self._in_flight is not None:
+            runner._c_steps_overlapped.inc()
+        return _StepInFlight(rows, stepped, dead, tok_d, sown_d, t0)
+
+    def _retire(self, flight: _StepInFlight,
+                leavers: List[StreamHandle]) -> None:
+        """The second half of a step: the one host fetch (the tokens, and
+        what the module sowed: nothing, but for a routed model's counts),
+        then tokens onto their handles, counters, attribution and leaves.
+        Rows are retired against the handles the step was DISPATCHED for:
+        a row whose handle has left since (eos, deadline, denial, cancel)
+        is stale and its token is dropped."""
+        import jax
+        runner = self.runner
+        t_wait0 = time.perf_counter()
+        # tok_d is an argument of the step after this one but not a donated
+        # one; the finished mask IS donated there, so it is derived here as
+        # the device derives it: a live row finishes on emitting eos
+        tok, sown = jax.device_get((flight.tok_d, flight.sown_d))
+        t_done = time.perf_counter()
         if self.watchdog is not None:
-            self.watchdog.disarm()
+            if self._in_flight is None:
+                self.watchdog.disarm()
+            else:
+                self.watchdog.heartbeat()   # this step's fetch returned
         self.steps += 1
         dte = runner.device_time_every
         if dte and self.steps % dte == 0:
-            runner._h_phase_device.observe(dev_s)
+            # what was left of the step when the host came to fetch it:
+            # the whole device wait under step(), the remainder of it
+            # behind the start() thread's overlapped host work
+            runner._h_phase_device.observe(t_done - t_wait0)
         runner._c_decode_steps.inc()
         for n in sown.get("experts_touched", ()):
             runner._c_experts_touched.inc(float(n))
         for n in sown.get("local_assignments", ()):
             runner._c_local_assignments.inc(float(n))
-        # attribution (ISSUE 17): the whole step's host-observed device
-        # work (enqueue + device wait) is amortized over the slots that
-        # had a live request behind them at dispatch; the rest of the
-        # batch width was pad cells — dispatched-but-wasted by definition
-        live = self._live
-        step_s = disp_s + dev_s
-        share = step_s / live if live else 0.0
+        # attribution (ISSUE 17): the wall time this step held the device
+        # alone — from its dispatch, or from the retirement before it when
+        # it was dispatched earlier than that and waited behind that step —
+        # to its own fetch's return, amortized over the slots that had a
+        # live request behind them at ITS dispatch; the rest of the batch
+        # width was pad cells — dispatched-but-wasted by definition
+        step_s = t_done - max(flight.t0, self._t_retired)
+        self._t_retired = t_done
+        share = step_s / flight.stepped
         if step_s > 0:
             self._c_device_s.inc(step_s)
-        pad = self.slots - live
-        if pad > 0:
-            self._c_tok_outcome.inc(pad, outcome="pad_row")
-        for s, h in enumerate(self._handles):
+        booked = 0
+        for s, h in enumerate(flight.rows):
             if h is None:
                 continue
-            self._tok[s] = tok[s]
-            self._fin[s] = bool(fin[s])
-            self._emitted[s] += 1
-            h.tokens.append(int(tok[s]))
+            if h.cost is not None:
+                h.cost.device_s += share
+            if self._handles[s] is not h:
+                continue            # left while this step was in flight
+            booked += 1
+            t = int(tok[s])
+            self._tok[s] = t
+            h.tokens.append(t)
             if h.cost is not None:
                 h.cost.decode_tokens += 1
-                h.cost.device_s += share
             runner._c_decode_tokens.inc()
-            if self._fin[s] or len(h.tokens) >= h.max_new_tokens:
+            if t == self.eos_id or len(h.tokens) >= h.max_new_tokens:
                 self._release(s, "ok", leavers)
+        if booked < self.slots:
+            self._c_tok_outcome.inc(self.slots - booked, outcome="pad_row")
+        stale = flight.stepped - booked + flight.dead
+        if stale:
+            runner._c_stale_rows.inc(stale)
 
     def _release(self, s: int, outcome: str,
                  leavers: List[StreamHandle]) -> None:
@@ -2602,8 +2726,9 @@ class ContinuousDecoder:
         self._tok[s] = 0
         self._lens[s] = 1
         self._emitted[s] = 0
-        self._tok_dev = None     # host state mutated: next dispatch
-        self._fin_dev = None     # re-uploads instead of reusing device copies
+        # _tok_dev/_fin_dev stay: with a step in flight the host's tokens
+        # are one step behind the device's, and the row needs no edit there
+        # (see _dispatch)
         self._c_left[outcome].inc()
         self.left += 1
         self._live -= 1
@@ -2638,6 +2763,7 @@ class ContinuousDecoder:
                 "live": self._live,
                 "queued_arrivals": len(self._arrivals),
                 "steps": self.steps,
+                "step_in_flight": self._in_flight is not None,
                 "joined": self.joined,
                 "left": self.left,
                 "closed": self._closed,
@@ -2679,18 +2805,30 @@ class ContinuousDecoder:
         return self
 
     def _run(self) -> None:
-        while True:
-            with self._cond:
-                while not self._closed and not self._arrivals \
-                        and self._live == 0:
-                    self._cond.wait(0.1)
-                if self._closed:
-                    return
-            try:
-                self.step()
-            except Exception:  # noqa: BLE001 — a poisoned step must not
-                self._abort()  # strand clients on done.wait
-                raise
+        """The pipelined driver: rounds with one step left in flight
+        between them, drained when the engine closes."""
+        try:
+            while self._wait_for_work():
+                with self._engine_work() as leavers:
+                    self._round(leavers)
+                if self._live == 0 and self._in_flight is None:
+                    self._return_cache_if_idle()
+            with self._engine_work() as leavers:
+                # close() tears down once this thread has ended: no page is
+                # freed and no slab returned under a step in flight
+                self._retire_in_flight(leavers)
+        except Exception:  # noqa: BLE001 — a poisoned step must not
+            self._abort()  # strand clients on done.wait
+            raise
+
+    def _wait_for_work(self) -> bool:
+        """Sleep on the condition while there is nothing to splice, step
+        or retire; False once the engine is closed."""
+        with self._cond:
+            while not self._closed and not self._arrivals \
+                    and self._live == 0 and self._in_flight is None:
+                self._cond.wait(0.1)
+            return not self._closed
 
     def _stall_abort(self, label: str, elapsed: float) -> None:
         """Watchdog trip (runs on the MONITOR thread — the engine thread
@@ -2730,6 +2868,10 @@ class ContinuousDecoder:
             self._torn = True
             arrivals = list(self._arrivals)
             self._arrivals.clear()
+        # a step still in flight here has no driver left to retire it (the
+        # engine failed or hangs inside it): dropped, its rows released
+        # below like every other
+        self._in_flight = None
         leavers: List[StreamHandle] = []
         for h in arrivals:
             self._cancel_arrival(h, outcome, leavers)

@@ -864,3 +864,375 @@ def test_engine_thread_crash_dumps_via_excepthook_without_deadlock(tmp_path):
             trigger="crash", result="ok") == 1
     finally:
         rec.close()
+
+
+# ---------------------------------------------------------------------------
+# one step in flight: the start() thread's pipelined rounds (ISSUE 37)
+# ---------------------------------------------------------------------------
+
+def _in_flight_engine(name, *, slots=3, budget=14, eos_id=None, clock=None,
+                      stall_timeout_s=None, prefix=False, bucket=8,
+                      longest=None):
+    """A fresh runner and stream on pages of 4, so that answers cross page
+    boundaries; with ``prefix`` an explicit pool that the index may fill."""
+    from mmlspark_tpu.observability import MetricsRegistry
+    runner = _runner(name, layers=1, registry=MetricsRegistry())
+    pool = runner.page_pool(4, num_pages=96) if prefix else None
+    dec = runner.decode_stream(
+        slots=slots, prompt_bucket=bucket, max_prompt_len=longest,
+        max_new_tokens=budget, eos_id=eos_id, page_size=4, pool=pool,
+        prefix_cache=prefix, clock=clock, stall_timeout_s=stall_timeout_s)
+    return runner, dec
+
+
+def _mixed_requests(seed=37, n=10, shared=0):
+    """Prompts of 2-8 tokens (the first ``shared`` of them one document's)
+    and budgets of 3-14: joins and leaves mid-flight, page boundaries
+    crossed by prompts and by answers."""
+    rng = np.random.default_rng(seed)
+    doc = rng.integers(0, 48, shared).astype(np.int32)
+    out = []
+    for _ in range(n):
+        own = rng.integers(0, 48, int(rng.integers(2, 9 - shared)))
+        out.append((np.concatenate([doc, own]).astype(np.int32),
+                    int(rng.integers(3, 15))))
+    return out
+
+
+@pytest.mark.parametrize("prefix", [False, True],
+                         ids=["cold_joins", "prefix_cache"])
+def test_step_by_hand_and_the_start_thread_serve_the_same_tokens(prefix):
+    """Satellite (a), (b) and (f) for ``TransformerEncoder``: the same
+    seeded requests through both drivers, with joins and leaves mid-flight,
+    answers over page boundaries and an eos some answers hit: the same
+    tokens, every page back, the same retained ids, no compile key after
+    warm-up; the overlap engages on the thread alone."""
+    from tests.decode_drivers import serve_through_both_drivers
+
+    runs = serve_through_both_drivers(
+        lambda name, eos: _in_flight_engine(
+            f"flight.{name}.{prefix}", eos_id=eos, prefix=prefix),
+        _mixed_requests(shared=4 if prefix else 0))
+    if prefix:
+        assert runs["hand"]["retained"], "nothing was retained"
+
+
+def _three_requests_and_their_eos():
+    """A (budget 3), B (budget 9) and C (budget 12, cut to 5 tokens by the
+    eos), none of A's or B's tokens the eos: with three slots every leave's
+    step is known in advance."""
+    runner, dec = _in_flight_engine("flight.pick")
+    rng = np.random.default_rng(41)
+    for _ in range(200):
+        prompts = [rng.integers(0, 48, 5).astype(np.int32) for _ in range(3)]
+        hs = [dec.submit(p, max_new_tokens=b)
+              for p, b in zip(prompts, (3, 9, 12))]
+        _drain(dec)
+        a, b, c = (list(h.tokens) for h in hs)
+        eos = c[4]
+        if eos not in c[:4] and eos not in a and eos not in b:
+            dec.close()
+            return list(zip(prompts, (3, 9, 12))), eos, [a, b, c[:5]]
+    raise AssertionError("no such three requests in 200 draws")
+
+
+def test_overlap_and_stale_counters_count_what_the_step_in_flight_does():
+    """Satellite (b).  Under ``start()`` with A, B, C joined in one round:
+    eight steps, all but the first dispatched beside the one before; A is a
+    dead row in step 3 (its last token dispatched in step 2, B and C still
+    stepping), C's eos in step 4 is learnt after step 5 went out with it, B
+    ends last and alone: two stale rows.  Under ``step()`` none of either.
+    The ledger's laws hold under both."""
+    from mmlspark_tpu.observability.attribution import OUTCOMES
+    from tests.decode_drivers import counter, feed
+    requests, eos, want = _three_requests_and_their_eos()
+    for on_thread in (False, True):
+        runner, dec = _in_flight_engine(f"flight.count.{on_thread}",
+                                        eos_id=eos)
+        dec.warmup()
+        handles = feed(dec, requests, on_thread)
+        dec.close()
+        assert [list(h.tokens) for h in handles] == want
+        assert dec.steps == 8 and dec.joined == 3
+        assert counter(runner, "mmlspark_runner_decode_steps_total") == 8
+        assert counter(runner, "mmlspark_runner_decode_tokens_total") \
+            == 3 + 9 + 5
+        assert counter(
+            runner, "mmlspark_runner_decode_steps_overlapped_total") \
+            == (7 if on_thread else 0)
+        assert counter(runner, "mmlspark_runner_decode_stale_rows_total") \
+            == (2 if on_thread else 0)
+        # every step cell in exactly one bucket; device seconds charged to
+        # requests are the counter's
+        fam = runner.registry.family("mmlspark_decode_tokens_outcome_total")
+        cells = {o: fam.labels(outcome=o).value for o in OUTCOMES}
+        assert cells["useful"] == 17
+        assert sum(cells.values()) == dec.steps * dec.slots + dec.joined
+        dev = runner.registry.family(
+            "mmlspark_decode_device_seconds_total").value()
+        assert dev == pytest.approx(sum(h.cost.device_s for h in handles),
+                                    rel=1e-6)
+        assert dev > 0
+
+
+def _rounds(dec, n=1):
+    """``n`` of the engine thread's rounds, by hand: the step a round
+    dispatches stays in flight."""
+    for _ in range(n):
+        with dec._engine_work() as leavers:
+            dec._round(leavers)
+
+
+def test_a_deadline_leave_with_a_step_in_flight_drops_that_steps_token():
+    """Satellite (b), (c): the row of a request that expired while its step
+    was in flight is retired against a handle that has left: one stale row,
+    no token after the leave, resolved once, every page back."""
+    from tests.decode_drivers import counter
+    from mmlspark_tpu.utils.resilience import FakeClock
+    clk = FakeClock()
+    runner, dec = _in_flight_engine("flight.expire", clock=clk)
+    done = []
+    p = np.asarray([5, 7, 11], np.int32)
+    h = dec.submit(p, deadline_s=clk() + 0.5, on_done=done.append)
+    other = dec.submit(p + 1, on_done=done.append)
+    _rounds(dec)                         # joins; step 1 in flight
+    assert dec._in_flight is not None and len(h.tokens) == 1
+    clk.advance(1.0)
+    _rounds(dec)                         # expires h, dispatches 2, retires 1
+    assert h.status == "expired" and done == [h]
+    assert len(h.tokens) == 1 and len(other.tokens) == 2
+    assert counter(runner, "mmlspark_runner_decode_stale_rows_total") == 1
+    while not other.done.is_set():
+        _rounds(dec)
+    _rounds(dec)                         # the last step, stale, retires
+    assert dec._in_flight is None and done == [h, other]
+    assert len(other.tokens) == 14 and dec.pool.pages_in_use() == 0
+    dec.close()
+    assert done == [h, other] and dec.left == 2
+
+
+def test_close_and_drain_retire_the_step_in_flight_before_the_teardown():
+    """Satellite (c): ``close()`` lets the thread retire the step in flight
+    (every dispatched step's token is on its handle), cancels what is live
+    once, and leaves no page in use; ``drain()`` runs every slot to its
+    end."""
+    runner, dec = _in_flight_engine("flight.close", budget=100)
+    done = []
+    h = dec.submit(np.asarray([5, 7], np.int32), max_new_tokens=100,
+                   on_done=done.append)
+    real = dec._step
+
+    def slow_step(*a):
+        time.sleep(0.005)
+        return real(*a)
+
+    dec._step = slow_step
+    dec.start()
+    deadline = time.monotonic() + 120
+    while dec.steps < 3:
+        assert time.monotonic() < deadline
+        time.sleep(0.001)
+    dec.close()
+    assert h.status == "cancelled" and done == [h]
+    assert dec._in_flight is None
+    assert len(h.tokens) == 1 + dec.steps < 100
+    assert dec.pool.pages_in_use() == 0 and dec.left == 1
+    dec.close()                          # torn once: nothing left to free
+    assert dec.left == 1 and done == [h]
+
+    runner, dec = _in_flight_engine("flight.drain")
+    handles = [dec.submit(np.asarray([3, 1, 4], np.int32) + i,
+                          max_new_tokens=6 + i) for i in range(3)]
+    dec.start()
+    assert dec.drain(timeout_s=120) is True
+    assert [h.status for h in handles] == ["ok"] * 3
+    assert [len(h.tokens) for h in handles] == [6, 7, 8]
+    assert dec._in_flight is None and dec.pool.pages_in_use() == 0
+
+
+def test_a_raising_dispatch_with_a_step_in_flight_resolves_every_handle_once():
+    """Satellite (c): step 2's dispatch raises with step 1 in flight, in a
+    round that had already released an expired request: that one resolves
+    as expired, the others as errors, each once; the teardown runs once and
+    no page is freed twice (``PagePool.free`` raises on a double free)."""
+    from mmlspark_tpu.utils.resilience import FakeClock
+    clk = FakeClock()
+    runner, dec = _in_flight_engine("flight.raise", clock=clk)
+    done = []
+    p = np.asarray([5, 7, 11], np.int32)
+    expiring = dec.submit(p, deadline_s=clk() + 0.5, on_done=done.append)
+    live = dec.submit(p + 1, on_done=done.append)
+    _rounds(dec)
+    queued = dec.submit(p + 2, on_done=done.append)
+    clk.advance(1.0)
+
+    def boom(*a):
+        raise RuntimeError("step executable poisoned")
+
+    dec._step = boom                    # the join of ``queued`` still runs
+    with pytest.raises(RuntimeError, match="poisoned"):
+        _rounds(dec)
+    # the round's own leaver is resolved although the round failed
+    assert expiring.status == "expired" and done == [expiring]
+    dec._abort()                         # what the engine thread does next
+    assert dec.abort_reason == "error" and dec._in_flight is None
+    assert live.status == queued.status == "error"
+    assert sorted(map(id, done)) == sorted(map(id, (expiring, live, queued)))
+    assert dec.pool.pages_in_use() == 0 and dec.left == 3
+    dec.close()
+    assert dec.left == 3 and len(done) == 3
+
+
+def test_the_watchdog_bounds_one_step_while_a_step_is_in_flight():
+    """Satellite (d), on a ``FakeClock``: armed from a dispatch until no
+    step is in flight, its clock restarted by every retirement; a join of
+    four chunks, each slower than half the timeout, does not trip it; a
+    fetch that never returns trips ``runner.decode.step`` and aborts."""
+    from mmlspark_tpu.utils.resilience import FakeClock
+    clk = FakeClock()
+    runner, dec = _in_flight_engine("flight.watch", clock=clk,
+                                    stall_timeout_s=2.0, longest=32)
+    wd = dec.watchdog
+    tripped = []
+    abort = wd.on_stall
+    wd.on_stall = lambda label, s: (tripped.append(label), abort(label, s))
+    rng = np.random.default_rng(43)
+    first = dec.submit(rng.integers(0, 48, 5).astype(np.int32))
+    try:
+        _rounds(dec)
+        assert wd.as_dict() == dict(wd.as_dict(), armed=True,
+                                    label="runner.decode.step")
+        for _ in range(4):               # 6 s of steps, 1.5 s each
+            clk.advance(1.5)
+            assert wd.check() is False
+            _rounds(dec)
+        # a long join beside the step in flight: every chunk takes 1.5 s
+        real = dec._prefill1
+
+        def slow_chunk(*a):
+            clk.advance(1.5)
+            assert wd.check() is False
+            return real(*a)
+
+        dec._prefill1 = slow_chunk
+        long_one = dec.submit(rng.integers(0, 48, 30).astype(np.int32))
+        _rounds(dec)
+        assert long_one.status == "live" and not tripped
+        assert runner.registry.family(
+            "mmlspark_runner_prefill_chunks_total").labels(
+                runner=runner.name).value == 1 + 4
+        assert dec._in_flight is not None and wd.as_dict()["armed"]
+        # the step in flight is never fetched: the monitor's next poll
+        clk.advance(2.5)
+        assert wd.check() is True
+        assert tripped == ["runner.decode.step"]
+        assert dec.abort_reason == "stall" and dec.closed
+        assert first.status == long_one.status == "error"
+        assert dec.pool.pages_in_use() == 0 and dec._in_flight is None
+        assert not wd.as_dict()["armed"]
+    finally:
+        dec.close()
+    # hand-driven step() leaves nothing armed behind it
+    runner, dec = _in_flight_engine("flight.watch.hand", clock=clk,
+                                    stall_timeout_s=2.0)
+    dec.submit(np.asarray([5, 7], np.int32))
+    dec.step()
+    assert not dec.watchdog.as_dict()["armed"] and dec._in_flight is None
+    dec.close()
+
+
+@pytest.mark.parametrize("on_thread", [False, True],
+                         ids=["step_by_hand", "start_thread"])
+def test_neither_driver_reads_a_donated_buffer(on_thread, monkeypatch):
+    """Satellite (e): the step donates its finished mask and the cache.
+    Every dispatch is handed live buffers (the cache is the output of the
+    dispatch before it), and what the engine fetches is never a buffer a
+    later dispatch consumed, although on the thread the mask step N
+    returned is already deleted when step N's tokens are fetched."""
+    import jax
+    from tests.decode_drivers import feed
+    runner, dec = _in_flight_engine(f"flight.donate.{on_thread}")
+    dec.warmup()
+    seen = {"steps": 0, "mask_gone_at_fetch": 0, "outputs": []}
+    real_step, real_get = dec._step, jax.device_get
+
+    def spy_step(variables, tok, pos, table, fin, cache):
+        leaves = jax.tree_util.tree_leaves(cache)
+        assert not any(x.is_deleted() for x in [tok, fin] + leaves), \
+            "a step was dispatched with a consumed buffer"
+        out = real_step(variables, tok, pos, table, fin, cache)
+        assert fin.is_deleted() and all(x.is_deleted() for x in leaves), \
+            "the step no longer donates: this test has lost its teeth"
+        seen["steps"] += 1
+        seen["outputs"].append(out[:2])
+        return out
+
+    def spy_get(tree):
+        fetched = jax.tree_util.tree_leaves(tree)
+        assert not any(x.is_deleted() for x in fetched
+                       if hasattr(x, "is_deleted")), \
+            "the engine fetched a donated buffer"
+        for tok_d, fin_d in seen["outputs"]:
+            if any(x is tok_d for x in fetched) and fin_d.is_deleted():
+                seen["mask_gone_at_fetch"] += 1
+        return real_get(tree)
+
+    dec._step = spy_step
+    monkeypatch.setattr(jax, "device_get", spy_get)
+    handles = feed(dec, _mixed_requests(seed=47, n=6), on_thread)
+    dec.close()
+    assert {h.status for h in handles} == {"ok"}
+    assert seen["steps"] == dec.steps > 10
+    if on_thread:
+        assert seen["mask_gone_at_fetch"] > 0
+    else:
+        assert seen["mask_gone_at_fetch"] == 0
+
+
+def test_many_submitting_threads_beside_the_step_in_flight():
+    """More submitters than cores, the interpreter switching threads every
+    10 us: every request served by the ``start()`` thread holds the tokens
+    ``step()`` by hand gave it, each resolved once, every page back."""
+    import sys
+    from mmlspark_tpu.models import SlotsExhausted
+    from tests.decode_drivers import feed
+    requests = _mixed_requests(seed=53, n=24)
+    _, dec = _in_flight_engine("flight.stress.hand", slots=4)
+    want = [list(h.tokens) for h in feed(dec, requests, False)]
+    dec.close()
+    runner, dec = _in_flight_engine("flight.stress", slots=4)
+    dec.warmup()
+    got, resolved = {}, []
+    deadline = time.monotonic() + 240
+
+    def submitter(mine):
+        for i in mine:
+            prompt, budget = requests[i]
+            while time.monotonic() < deadline:
+                try:
+                    got[i] = dec.submit(prompt, max_new_tokens=budget,
+                                        on_done=resolved.append)
+                    break
+                except SlotsExhausted:
+                    time.sleep(0.0002)
+
+    workers = [threading.Thread(target=submitter, args=(range(k, 24, 12),))
+               for k in range(12)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        dec.start()
+        for w in workers:
+            w.start()
+        for w in workers:
+            w.join(timeout=240)
+        assert not any(w.is_alive() for w in workers) and len(got) == 24
+        assert all(h.done.wait(max(0.0, deadline - time.monotonic()))
+                   for h in got.values())
+    finally:
+        sys.setswitchinterval(interval)
+        dec.close()
+    assert [list(got[i].tokens) for i in range(24)] == want
+    assert {h.status for h in got.values()} == {"ok"}
+    assert sorted(map(id, resolved)) == sorted(map(id, got.values()))
+    assert dec.pool.pages_in_use() == 0 and dec._in_flight is None

@@ -344,3 +344,31 @@ def test_the_prefill_head_runs_on_the_last_real_position_only():
     one = module.apply(variables, toks, logits_at=at)
     np.testing.assert_allclose(one[:, 0], whole[jnp.arange(2), at],
                                rtol=1e-5, atol=1e-6)
+
+
+# ------------------------------------- the engine's two drivers (ISSUE 37)
+
+def test_step_by_hand_and_the_start_thread_serve_the_same_tokens():
+    """Questions over one 8-token document through the prefix cache, prompts
+    of one and two prefill chunks, answers over page boundaries, an eos some
+    answers hit: ``step()`` by hand and the ``start()`` thread (one step in
+    flight) serve the same tokens, leave the same ids retained and every
+    other page free, and mint no compile key after warm-up."""
+    from tests.decode_drivers import serve_through_both_drivers
+    module, variables = _model()
+    rng = np.random.default_rng(37)
+    doc = rng.integers(0, 512, 8).astype(np.int32)
+    requests = [(np.concatenate(
+        [doc, rng.integers(0, 512, int(rng.integers(2, 9)))]).astype(np.int32),
+        int(rng.integers(3, 11))) for _ in range(8)]
+
+    def make_engine(name, eos):
+        runner = ModelRunner(module=module, variables=variables,
+                             name=f"sm.flight.{name}")
+        return runner, runner.decode_stream(
+            slots=3, prompt_bucket=8, max_prompt_len=16, max_new_tokens=10,
+            eos_id=eos, page_size=PAGE, prefix_cache=True,
+            pool=runner.page_pool(PAGE, num_pages=96))
+
+    runs = serve_through_both_drivers(make_engine, requests)
+    assert runs["thread"]["retained"]

@@ -749,10 +749,10 @@ def test_attribution_surface_books_metrics():
     from mmlspark_tpu.serving import distributed as dist_mod
     from mmlspark_tpu.serving import server as server_mod
 
-    adv_src = inspect.getsource(runner_mod.ContinuousDecoder._advance)
+    adv_src = inspect.getsource(runner_mod.ContinuousDecoder._retire)
     for needle in ("_c_device_s.inc", 'outcome="pad_row"',
                    "cost.device_s += share"):
-        assert needle in adv_src, f"_advance() lost {needle}"
+        assert needle in adv_src, f"_retire() lost {needle}"
     rel_src = inspect.getsource(runner_mod.ContinuousDecoder._release)
     assert "_outcome_map[outcome]" in rel_src, \
         "_release() no longer classifies terminal tokens"
@@ -961,7 +961,7 @@ def test_prefix_cache_surface_books_metrics():
     # shedding while refcount-0 pages sit retained
     for fn in (runner_mod.ModelRunner.decode,
                runner_mod.ContinuousDecoder.submit,
-               runner_mod.ContinuousDecoder._advance):
+               runner_mod.ContinuousDecoder._dispatch):
         assert "_alloc_with_reclaim" in inspect.getsource(fn), \
             f"{fn.__qualname__} lost the reclaim-then-allocate path"
     # the skipped-prefill lane rides the request record + capacity report

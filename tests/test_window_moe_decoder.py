@@ -426,3 +426,22 @@ def test_a_module_whose_share_nobody_chose_gives_the_shared_part_alone():
     local = int(sown["intermediates"]["local_assignments"][0])
     assert 0 < local < 2 * 24 * 4 * 4
     assert 0 < int(sown["intermediates"]["experts_touched"][0]) <= 4 * 8
+
+
+# ------------------------------------- the engine's two drivers (ISSUE 37)
+
+def test_step_by_hand_and_the_start_thread_serve_the_same_tokens():
+    """Prompts of one to three prefill chunks, answers that wrap the window
+    ring and cross page boundaries, an eos some answers hit, slots taken
+    again: ``step()`` by hand and the ``start()`` thread (one step in flight,
+    so a row that has left still writes its slot's ring once) serve the same
+    tokens, free every page and mint no compile key after warm-up."""
+    from tests.decode_drivers import serve_through_both_drivers
+    module, variables = _model()
+    rng = np.random.default_rng(37)
+    requests = [(rng.integers(0, 256, int(rng.integers(3, 41))).astype(
+        np.int32), int(rng.integers(3, 21))) for _ in range(8)]
+    serve_through_both_drivers(
+        lambda name, eos: _engine(module, variables, f"wm.flight.{name}",
+                                  longest=48, new=20, eos_id=eos),
+        requests)
