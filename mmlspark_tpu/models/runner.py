@@ -66,6 +66,18 @@ __all__ = ["ModelRunner", "DecodeResult", "PagePool", "ContinuousDecoder",
 #: fronts a batch can arrive through; metric label values
 FRONTS = ("transform", "serving", "decode")
 
+#: the laps of :meth:`ModelRunner.apply_batch`, the ``phase`` values of
+#: ``mmlspark_runner_batch_phase_seconds``: a chunk filled into its staging
+#: buffer (or a dense input's slice padded), the jitted call with the ask
+#: for its output's copy, the wait for the oldest chunk's output, its fetch,
+#: the final concatenation.  An upload has no lap: the call returns while it
+#: runs, so the device's idle time under an upload is the lap's the host is in
+BATCH_LAPS = ("stage", "dispatch", "wait", "fetch", "concat")
+#: the laps of the continuous engine's round (``ContinuousDecoder``), ``phase``
+#: values of ``mmlspark_runner_decode_phase_seconds`` beside ``device``
+DECODE_LAPS = ("join_prefill", "join_fetch", "join_splice", "prepare",
+               "dispatch", "fetch", "book", "notify")
+
 
 class PagePoolExhausted(RuntimeError):
     """The page pool cannot cover an allocation — admission control, not a
@@ -667,6 +679,16 @@ class ModelRunner:
             labels=("runner", "buffer"))
         self._c_staged = {b: c_staged.labels(runner=name, buffer=b)
                           for b in ("reused", "fresh")}
+        from ..observability.tracing import LapClock
+        h_batch = reg.histogram(
+            "mmlspark_runner_batch_phase_seconds",
+            "apply_batch's laps on the caller's thread: stage, dispatch, "
+            "wait, fetch of a chunk and the final concat",
+            labels=("runner", "front", "phase"))
+        self._batch_laps = {
+            f: LapClock("batch", "runner.apply_batch",
+                        {p: h_batch.labels(runner=name, front=f, phase=p)
+                         for p in BATCH_LAPS}, reg) for f in FRONTS}
         #: the idle pair of host staging buffers (``batch_size`` rows of
         #: the last row shape and dtype staged); None while a call has it
         #: checked out, and before the first row source
@@ -707,10 +729,17 @@ class ModelRunner:
         h_phase = reg.histogram(
             "mmlspark_runner_decode_phase_seconds",
             "decode-step breakdown: dispatch (host enqueue) vs device "
-            "(sampled block_until_ready wait)", labels=("runner", "phase"))
+            "(sampled block_until_ready wait), and the other laps of the "
+            "continuous engine's round", labels=("runner", "phase"))
         self._h_phase_dispatch = h_phase.labels(runner=name,
                                                 phase="dispatch")
         self._h_phase_device = h_phase.labels(runner=name, phase="device")
+        # the continuous engine's lap clock (ISSUE 39): every driver of a
+        # ContinuousDecoder laps through it, each thread its own phase
+        self._decode_laps = LapClock(
+            "decode", "runner.decode.step",
+            {p: h_phase.labels(runner=name, phase=p) for p in DECODE_LAPS},
+            reg)
         # page-pool surface (paged decode): families registered at
         # construction so the telemetry-coverage sweep gates on them even
         # for runners that never decode; PagePool binds the children
@@ -926,12 +955,17 @@ class ModelRunner:
         pad_total = 0
         #: (device output, real rows) of the dispatched chunks, oldest first
         in_flight: deque = deque()
+        laps = self._batch_laps[front]
+        lap = laps.lap
 
         def retire():
             # the wait for the oldest chunk's output, ALL of it (a fetch
             # alone reads one replica of a sharded program's), and its fetch
             y, rows = in_flight.popleft()
-            outs.append(np.asarray(y.block_until_ready())[:rows])
+            lap("wait")
+            y.block_until_ready()
+            lap("fetch")
+            outs.append(np.asarray(y)[:rows])
 
         staging = nullcontext((None, None)) if isinstance(x, np.ndarray) \
             else self._staging_pair(bs, tuple(x.shape[1:]), x.dtype)
@@ -943,6 +977,7 @@ class ModelRunner:
                     pad_total += bucket - m
                     if len(in_flight) == 2:
                         retire()
+                    lap("stage")
                     if pair is None:
                         chunk = _pad_rows(x[start:start + m], bucket)
                     else:
@@ -950,6 +985,7 @@ class ModelRunner:
                         x.fill(chunk, start, start + m)
                         chunk[m:] = chunk[m - 1]
                         self._c_staged[label].inc()
+                    lap("dispatch")
                     fn = self.executable(bucket, chunk.shape[1:])
                     y = fn(variables, chunk)
                     # queued behind the program now: the fetch in retire()
@@ -961,7 +997,10 @@ class ModelRunner:
                     self._c_input_bytes[front].inc(chunk.nbytes)
                 while in_flight:
                     retire()
+                lap("concat")
+                out = np.concatenate(outs, axis=0)
             finally:
+                laps.stop()
                 # after an error too, no buffer goes back while a program
                 # may still be reading it
                 for y, _ in in_flight:
@@ -969,7 +1008,7 @@ class ModelRunner:
         self._c_rows[front].inc(n)
         if pad_total:
             self._c_pad.inc(pad_total)
-        return np.concatenate(outs, axis=0)
+        return out
 
     # ---------------------------------------------------------- serving front
     def scorer(self, input_col: str = "request", reply_col: str = "reply",
@@ -2385,6 +2424,7 @@ class ContinuousDecoder:
         from ..observability.tracing import _enter_phase, _exit_phase
         leavers: List[StreamHandle] = []
         _phase = _enter_phase("runner.decode.step")
+        laps = self.runner._decode_laps
         try:
             yield leavers
         except Exception:
@@ -2397,8 +2437,13 @@ class ContinuousDecoder:
                 self._poisoned = True
             raise
         finally:
-            _exit_phase(_phase)
-            self._finish(leavers)
+            try:
+                if leavers:
+                    laps.lap("notify")
+                    self._finish(leavers)
+            finally:
+                laps.stop()
+                _exit_phase(_phase)
 
     def _finish(self, leavers: List[StreamHandle]) -> None:
         for h in leavers:
@@ -2422,6 +2467,8 @@ class ContinuousDecoder:
         table argument only names the joiner's pages."""
         import jax.numpy as jnp
         runner = self.runner
+        lap = runner._decode_laps.lap
+        lap("join_prefill")
         self._borrow()
         P_b, W = self.prompt_bucket, self.table_w
         ps = self.page_size
@@ -2429,6 +2476,7 @@ class ContinuousDecoder:
                                     (1, P_b))
         pos_dev = jnp.asarray(positions)
         for h in joiners:
+            lap("join_prefill")
             s = h.slot
             off = int(h.covered)
             if off and self.index is not None:
@@ -2485,17 +2533,22 @@ class ContinuousDecoder:
                     # state whole, and the fetch of the joiner's first
                     # token drains the device anyway
                     self._retire_in_flight(leavers)
+                    lap("join_prefill")
                     if self.watchdog is not None:
                         self.watchdog.arm("runner.decode.join")
                 if self.watchdog is not None and at + P_b < h.length:
                     # the timeout bounds ONE dispatch, not a long join
+                    lap("join_fetch")
                     last.block_until_ready()
+                    lap("join_prefill")
                     self.watchdog.heartbeat()
             runner._c_prefill_tokens["computed"].inc(h.length - off)
             runner._c_prefill_tokens["cached"].inc(off)
             tok_d, fin_d = self._sample1(last, jnp.zeros(1, bool))
+            lap("join_fetch")
             tok0 = int(np.asarray(tok_d)[0])
             fin0 = bool(np.asarray(fin_d)[0])
+            lap("join_splice")
             if self.watchdog is not None:
                 self.watchdog.disarm()
             runner._c_batches["decode"].inc()
@@ -2541,6 +2594,8 @@ class ContinuousDecoder:
         own tail page or on the trash page."""
         import jax.numpy as jnp
         runner = self.runner
+        lap = runner._decode_laps.lap
+        lap("prepare")
         now = self.clock()
         for s, h in enumerate(self._handles):
             if h is not None and h.deadline_s is not None \
@@ -2592,13 +2647,18 @@ class ContinuousDecoder:
             self._emitted[s] += 1
         if not stepped:
             return None
+        # uploaded from COPIES: on the CPU jnp.asarray may alias a small
+        # numpy buffer, and the host edits all three (a join's table row, a
+        # leave's zeroes, the retired tokens) while the step dispatched here
+        # may still be waiting to run: a pad row would then write position 0
+        # through the row a join has just filled, into a page others share
         if self._table_dirty or self._table_dev is None:
-            self._table_dev = jnp.asarray(self._table)
+            self._table_dev = jnp.asarray(self._table.copy())
             self._table_dirty = False
         tok_in = self._tok_dev if self._tok_dev is not None \
-            else jnp.asarray(self._tok)
+            else jnp.asarray(self._tok.copy())
         fin_in = self._fin_dev if self._fin_dev is not None \
-            else jnp.asarray(self._fin)
+            else jnp.asarray(self._fin.copy())
         if self.watchdog is not None:
             # armed from here until NO step is in flight: the dispatch and
             # the host fetch are both the hang shapes (a hung device
@@ -2606,13 +2666,14 @@ class ContinuousDecoder:
             # enqueue).  Each retirement restarts the clock, so the
             # timeout still bounds one step
             self.watchdog.arm("runner.decode.step")
-        t0 = time.perf_counter()
+        t0 = lap("dispatch")
         tok_d, fin_d, self._cache, sown_d = self._step(
             runner.variables, tok_in, jnp.asarray(pos),
             self._table_dev, fin_in, self._cache)
         # dispatch/device split (ISSUE 15): the step call above is the
-        # host enqueue; the device's part is waited for in _retire
-        runner._h_phase_dispatch.observe(time.perf_counter() - t0)
+        # host enqueue, and the lap that ends here observes it; the
+        # device's part is waited for in _retire
+        lap("book")
         # fin_in was donated (consumed) by the dispatch, and fin_d will be
         # by the next: the finished mask lives on the device alone and no
         # host code reads it.  A release keeps both device copies (its row
@@ -2634,12 +2695,13 @@ class ContinuousDecoder:
         is stale and its token is dropped."""
         import jax
         runner = self.runner
-        t_wait0 = time.perf_counter()
+        lap = runner._decode_laps.lap
+        t_wait0 = lap("fetch")
         # tok_d is an argument of the step after this one but not a donated
         # one; the finished mask IS donated there, so it is derived here as
         # the device derives it: a live row finishes on emitting eos
         tok, sown = jax.device_get((flight.tok_d, flight.sown_d))
-        t_done = time.perf_counter()
+        t_done = lap("book")
         if self.watchdog is not None:
             if self._in_flight is None:
                 self.watchdog.disarm()

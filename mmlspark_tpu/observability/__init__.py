@@ -33,10 +33,10 @@ breakers), ``lightgbm/core.train`` (per-iteration phase timings),
 """
 from .metrics import (Counter, DEFAULT_LATENCY_BUCKETS, Gauge, Histogram,
                       MetricsRegistry, get_registry, set_registry)
-from .tracing import (Span, TRACE_HEADER, TRACEPARENT_HEADER, ambient_phase,
-                      current_span, current_trace_id, format_traceparent,
-                      new_trace_id, parse_traceparent, thread_phases,
-                      trace_span)
+from .tracing import (LapClock, PhaseLog, Span, TRACE_HEADER,
+                      TRACEPARENT_HEADER, ambient_phase, current_span,
+                      current_trace_id, format_traceparent, new_trace_id,
+                      parse_traceparent, phase_log, thread_phases, trace_span)
 from .instruments import (BREAKER_STATE_CODES, instrument_breaker,
                           instrument_collector)
 from .collector import OTLP_ENDPOINT_ENV, SpanCollector, get_collector
@@ -58,7 +58,8 @@ __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
            "DEFAULT_LATENCY_BUCKETS", "get_registry", "set_registry",
            "Span", "TRACE_HEADER", "TRACEPARENT_HEADER", "current_span",
            "current_trace_id", "new_trace_id", "trace_span",
-           "ambient_phase", "thread_phases",
+           "ambient_phase", "thread_phases", "LapClock", "PhaseLog",
+           "phase_log",
            "parse_traceparent", "format_traceparent", "BREAKER_STATE_CODES",
            "instrument_breaker", "instrument_collector",
            "OTLP_ENDPOINT_ENV", "SpanCollector", "get_collector",

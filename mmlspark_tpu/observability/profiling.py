@@ -222,10 +222,13 @@ class SamplingProfiler:
         dropped = 0
         with self._lock:
             self._idle += idle
-            for span, stack in folded:
+            for phase, stack in folded:
                 self._samples += 1
+                # a LapClock's phase reads "<its loop's span>/<lap>": the
+                # rollup stays by span, the folded stacks keep the lap
+                span = phase.partition("/")[0]
                 self._by_span[span] = self._by_span.get(span, 0) + 1
-                key = (span, stack)
+                key = (phase, stack)
                 n = self._stacks.get(key)
                 if n is not None:
                     self._stacks[key] = n + 1

@@ -10,11 +10,12 @@ import numpy as np
 LIMIT_S = 300.0
 
 
-def feed(dec, requests, on_thread, limit_s=LIMIT_S):
+def feed(dec, requests, on_thread, limit_s=LIMIT_S, left=None):
     """Serve ``requests`` [(prompt, max_new_tokens)] with backpressure: a
     request is submitted as soon as a slot is free, so later ones join and
     earlier ones leave mid-flight.  Driven by ``step()`` calls, or by the
-    engine's own thread while this one sleeps.  Returns the handles."""
+    engine's own thread while this one sleeps.  Returns the handles; a list
+    given as ``left`` collects them in the order they left."""
     from mmlspark_tpu.models import SlotsExhausted
     handles, pending = [], list(requests)
     deadline = time.monotonic() + limit_s
@@ -23,7 +24,9 @@ def feed(dec, requests, on_thread, limit_s=LIMIT_S):
         while pending:
             try:
                 prompt, budget = pending[0]
-                handles.append(dec.submit(prompt, max_new_tokens=budget))
+                handles.append(dec.submit(
+                    prompt, max_new_tokens=budget,
+                    on_done=None if left is None else left.append))
                 pending.pop(0)
             except SlotsExhausted:
                 break
@@ -39,9 +42,9 @@ def counter(runner, family):
     return runner.registry.family(family).labels(runner=runner.name).value
 
 
-def retained_ids(index):
+def retained_ids(index, tails=True):
     """The token sequences the prefix index retains: every node's path from
-    the root, and every tail behind its node's path."""
+    the root, and (with ``tails``) every tail behind its node's path."""
     if index is None:
         return None
     out = set()
@@ -53,7 +56,7 @@ def retained_ids(index):
                 at = at.parent
             path = b"".join(reversed(path))
             out.add(path)
-            if node.tail is not None:
+            if tails and node.tail is not None:
                 out.add(path + np.asarray(node.tail[1], np.int32).tobytes())
     return out
 
@@ -94,11 +97,14 @@ def serve_through_both_drivers(make_engine, requests):
         runner, dec = make_engine(name, eos)
         dec.warmup()
         keys = runner.compile_stats()
-        handles = feed(dec, requests, on_thread)
+        left = []
+        handles = feed(dec, requests, on_thread, left=left)
         dec.close()
         assert dec._in_flight is None
         index = dec.index
         runs[name] = dict(
+            left=[handles.index(h) for h in left],
+            chunks=retained_ids(index, tails=False),
             tokens=[list(h.tokens) for h in handles],
             status=[h.status for h in handles],
             slots=len({h.slot for h in handles}),
@@ -119,7 +125,14 @@ def serve_through_both_drivers(make_engine, requests):
     for run in runs.values():
         assert run["pages_left"] == run["retained_pages"]
         assert not run["minted"], "a join or a leave minted a compile key"
-    assert thread["retained"] == hand["retained"]
+    # every full chunk is retained whoever drives.  A chunk's tail is its
+    # FIRST finisher's (first wins), and under the thread who finishes first
+    # follows when the feeder found the free slot: the tails are the same
+    # whenever the requests left in the same order
+    assert thread["chunks"] == hand["chunks"]
+    assert sorted(thread["left"]) == sorted(hand["left"])
+    if thread["left"] == hand["left"]:
+        assert thread["retained"] == hand["retained"]
     # step() retires what it dispatches: nothing overlaps, nothing is stale
     assert hand["overlapped"] == 0 and hand["stale"] == 0
     assert 0 < thread["overlapped"] < thread["steps"]
