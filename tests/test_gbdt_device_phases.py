@@ -171,6 +171,46 @@ def test_every_heavy_operation_lies_under_a_phase(programs, program):
     assert not bare, bare[:5]
 
 
+@pytest.fixture(scope="module")
+def cpu_multi_iter(programs):
+    """HLO text of the chunked program as the CPU's own ``scatter`` path
+    compiles it (the trainer's chunk override makes one there)."""
+    from mmlspark_tpu.ops import histogram as hist_ops
+    core._JIT_CACHE.clear()             # the chip path's multi_iter
+    with pytest.MonkeyPatch.context() as mp:
+        for module in (core, hist_ops):
+            mp.setattr(module, "platform", lambda: "cpu")
+        mp.setenv("MMLSPARK_TPU_GBDT_CHUNK", "4")
+        train(*_data(50_000), _params(8))
+        return _compiled_text("lightgbm.multi_iter")
+
+
+def _route_ops(text):
+    """``(opcode, type of the first operand)`` of every instruction under
+    ``gbdt.route``, those inside fused computations too."""
+    types = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", text))
+    return [(op, types.get(arg)) for op, arg, name in re.findall(
+                r"= \S+ ([\w\-]+)\(%([\w.\-]+)[^\n]*?op_name=\"([^\"]*)\"",
+                text)
+            if _phase(name) == "gbdt.route"]
+
+
+@pytest.mark.parametrize("program", ["lightgbm.multi_iter",
+                                     "lightgbm.sharded_grower"])
+def test_the_matmul_path_routes_by_a_product_and_gathers_nothing(
+        programs, cpu_multi_iter, program):
+    """On the ``matmul`` backend every row's bin comes from one product with
+    the level's split columns: the route holds a ``dot`` and no gather at
+    all.  The ``scatter`` backend keeps its gather of a byte a row from the
+    binned ``(rows, 8)`` matrix."""
+    ops = _route_ops(programs[program])
+    assert {op for op, _ in ops} & {"dot", "convolution"}
+    assert not [t for op, t in ops if op == "gather"]
+    binned = [t for op, t in _route_ops(cpu_multi_iter) if op == "gather"
+              and t == "u8[50000,8]"]
+    assert binned
+
+
 def _primitives(jaxpr, outer=""):
     """``(primitive, scope path)`` of every equation, nested ones too."""
     import jax

@@ -286,27 +286,103 @@ def test_quantization_follows_the_param_and_phase_labels(as_platform):
     assert fam.label_names == ("phase", "backend", "quantized")
 
 
-def test_matmul_and_scatter_backends_grow_the_same_trees(as_platform):
+def _fit_recording_leaves(monkeypatch, X, y, params, mesh=None):
+    """Trees of a fit, and the ``leaf_of_row`` of every tree as its grower
+    returned it, one list a shard in the order grown."""
+    import jax
+    from mmlspark_tpu.lightgbm import core, train
+    from mmlspark_tpu.parallel import active_mesh
+    leaves = {}
+    make = core._make_grower
+
+    def recording(p, F, B, axis_name=None, **kw):
+        grow = make(p, F, B, axis_name=axis_name, **kw)
+
+        def grow_and_record(*args):
+            out = grow(*args)
+            shard = 0 if axis_name is None else jax.lax.axis_index(axis_name)
+            jax.debug.callback(lambda s, leaf: leaves.setdefault(
+                int(s), []).append(np.asarray(leaf)), shard, out[-1])
+            return out
+        return grow_and_record
+
+    core._JIT_CACHE.clear()          # no program grown without the recorder
+    with monkeypatch.context() as mp:
+        mp.setattr(core, "_make_grower", recording)
+        if mesh is None:
+            booster = train(X, y, params).booster
+        else:
+            with active_mesh(mesh):
+                booster = train(X, y, params, shard_rows=True).booster
+    core._JIT_CACHE.clear()
+    return _trees(booster), leaves
+
+
+def _categorical_column(n_codes, n=1500):
+    """``x0`` a category code (``y`` follows a random half of the codes),
+    ``x1``-``x3`` normal."""
+    rng = np.random.default_rng(11)
+    codes = rng.integers(0, n_codes, size=n)
+    in_set = rng.permutation(n_codes) < n_codes // 2
+    X = np.column_stack([codes, rng.normal(size=(n, 3))]).astype(np.float32)
+    y = in_set[codes] ^ (X[:, 1] + 0.3 * rng.normal(size=n) > 1)
+    return X, y, dict(categorical_features=(0,))
+
+
+def _plain_columns(n=700, F=6):
+    rng = np.random.default_rng(5)
+    X = rng.normal(size=(n, F)).astype(np.float32)
+    y = X[:, 0] + 0.5 * X[:, 1] + 0.3 * rng.normal(size=n) > 0
+    return X, y, {}
+
+
+@pytest.mark.parametrize("data,over,sharded", [
+    (_plain_columns, {}, False),
+    # 16 nodes on the last level, the widest route
+    (lambda: _plain_columns(n=3000, F=20), dict(max_depth=5), False),
+    (_plain_columns, dict(use_quantized_grad=False), False),
+    (lambda: _categorical_column(4), {}, False),          # one-vs-rest
+    (lambda: _categorical_column(24), {}, False),         # sorted subset
+    (_plain_columns, {}, True)],                           # 8 CPU shards
+    ids=["depth3", "depth5", "float", "cat-onehot", "cat-subset", "sharded"])
+def test_matmul_and_scatter_backends_grow_the_same_trees(
+        as_platform, monkeypatch, mesh8, data, over, sharded):
     """Integer histograms are exact in both builders, so a quantized fit
     through the int8 matmul build (one node unsorted at the root, sorted
     block slices below) takes the same splits as through the packed
-    scatter."""
-    from mmlspark_tpu.lightgbm import GBDTParams, train
-    rng = np.random.default_rng(5)
-    X = rng.normal(size=(700, 6)).astype(np.float32)
-    y = (X[:, 0] + 0.5 * X[:, 1] + 0.3 * rng.normal(size=700) > 0)
-    params = GBDTParams(num_iterations=4, max_depth=3, objective="binary",
-                        min_data_in_leaf=5, use_quantized_grad=True,
-                        bagging_fraction=0.8, bagging_freq=1)
+    scatter (a float fit the same splits, and gains and values to
+    rounding); and the route, a product with the level's split columns on
+    the matmul side and a per-row gather on the scatter side, sends every
+    row to the same leaf."""
+    from mmlspark_tpu.lightgbm import GBDTParams
+    X, y, cat = data()
+    kw = dict(num_iterations=4, max_depth=3, objective="binary",
+              min_data_in_leaf=5, use_quantized_grad=True,
+              bagging_fraction=0.8, bagging_freq=1)
+    params = GBDTParams(**{**kw, **cat, **over})
 
     def fit(platform):
         as_platform(platform)
-        return _trees(train(X, y.astype(np.float32), params).booster)
+        return _fit_recording_leaves(monkeypatch, X, y.astype(np.float32),
+                                     params, mesh8 if sharded else None)
 
-    mm, sc = fit("tpu"), fit("cpu")
+    (mm, mm_leaves), (sc, sc_leaves) = fit("tpu"), fit("cpu")
     assert (mm["split_feature"] >= 0).sum() >= 4 * 3
+    if cat:
+        assert (mm["split_feature"] == 0).any()
     for key in mm:
-        np.testing.assert_array_equal(mm[key], sc[key], err_msg=key)
+        if key in ("split_gain", "leaf_value") and not params.use_quantized_grad:
+            # float sums: the two builders add in different orders
+            np.testing.assert_allclose(mm[key], sc[key], rtol=1e-4,
+                                       err_msg=key)
+        else:
+            np.testing.assert_array_equal(mm[key], sc[key], err_msg=key)
+    assert sorted(mm_leaves) == sorted(sc_leaves) == \
+        list(range(8 if sharded else 1))
+    for shard in mm_leaves:
+        assert len(mm_leaves[shard]) == 4
+        np.testing.assert_array_equal(np.stack(mm_leaves[shard]),
+                                      np.stack(sc_leaves[shard]))
 
 
 def _train_span_facts(trace_id):
