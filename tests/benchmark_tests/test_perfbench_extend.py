@@ -1,13 +1,22 @@
 """A later PR adds a configuration, a traffic mix and a per-layer metric as
-new files and new entries, and edits no file that is there."""
+new files and new entries, and edits no file that is there: a trainer cell
+with metrics of its own, and a decode cell that joins the decode readers by
+its name appended to their cells."""
 import hashlib
 import json
 import os
+import shutil
+
+import pytest
 
 from benchmark.harness import run_cell
 from benchmark.manifest import Manifest
 
 from perfbench_tiny import TINY_GBDT, tiny_root
+from test_perfbench_causal_lm import tiny_lm_root
+from test_perfbench_host_phases import NINE, assert_the_manifest_lists_the_nine
+from test_perfbench_overlap import (DECODE_CELLS, METRIC,
+                                    assert_the_manifest_lists_the_metric)
 
 
 def _digests(root):
@@ -21,9 +30,18 @@ def _digests(root):
     return out
 
 
-def test_a_new_cell_needs_new_files_and_entries_only(tmp_path):
+@pytest.fixture(autouse=True)
+def _leave_the_process_as_found():
     import jax
     from mmlspark_tpu.parallel import get_active_mesh, set_active_mesh
+    mesh = get_active_mesh()
+    floor = jax.config.jax_persistent_cache_min_compile_time_secs
+    yield
+    set_active_mesh(mesh)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", floor)
+
+
+def test_a_new_cell_needs_new_files_and_entries_only(tmp_path):
     root = tiny_root(tmp_path)
     before = _digests(root)
 
@@ -72,16 +90,8 @@ def test_a_new_cell_needs_new_files_and_entries_only(tmp_path):
         json.dump(bench, f)
 
     assert Manifest(root).problems() == []
-    mesh = get_active_mesh()
-    floor = jax.config.jax_persistent_cache_min_compile_time_secs
-    try:
-        plain = run_cell(root, "gbdt-narrow-refit", 0, 0.5, False,
-                         platform="cpu")
-        traced = run_cell(root, "gbdt-narrow-refit", 0, 0.5, True,
-                          platform="cpu")
-    finally:
-        set_active_mesh(mesh)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", floor)
+    plain = run_cell(root, "gbdt-narrow-refit", 0, 0.5, False, platform="cpu")
+    traced = run_cell(root, "gbdt-narrow-refit", 0, 0.5, True, platform="cpu")
     assert plain["correct"] and plain["attempted"] >= 3
     assert set(plain["metrics"]) == {"rows_per_s", "setup_s"}
     assert traced["correct"], traced
@@ -96,3 +106,63 @@ def test_a_new_cell_needs_new_files_and_entries_only(tmp_path):
         "benchmark/layer_metrics/gbdt.fits.py",
         "benchmark/layer_metrics/gbdt.none.py",
         "benchmark/workloads/refit_many.json"]
+
+
+def test_a_new_decode_cell_joins_the_decode_readers_by_its_name(tmp_path):
+    """The next model on the decode path: its configuration and traffic as
+    new files, its name appended to the cells of every entry that lists the
+    three decode cells, one new per-layer entry at the end of ``per_layer``
+    with its reader, and no file that is there edited."""
+    root = tiny_lm_root(tmp_path)
+    before = _digests(root)
+    cell, config, traffic = "tiny-lm-backlog", "tiny-lm-b", "backlog_b"
+    shutil.copy(os.path.join(root, "benchmark/configs/gpt2-xl-bf16.json"),
+                os.path.join(root, f"benchmark/configs/{config}.json"))
+    shutil.copy(os.path.join(root, "benchmark/workloads/generate_backlog.json"),
+                os.path.join(root, f"benchmark/workloads/{traffic}.json"))
+    with open(os.path.join(
+            root, "benchmark/layer_metrics/decode.steps_in_window.py"),
+            "w") as f:
+        f.write('"""Step programs the window ran."""\n\n\n'
+                'def read(run):\n'
+                '    return run.counter("mmlspark_runner_decode_steps_total")\n')
+
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        bench = json.load(f)
+    reduced = Manifest(root).config("gpt2-xl-bf16")["reduced"]
+    bench["configs"].append({"name": config, "source": "a test",
+                             "file": f"benchmark/configs/{config}.json",
+                             "reduced": reduced, "why": "a test"})
+    bench["workloads"].append({"name": cell, "config": config,
+                               "traffic": traffic, "chips": 1,
+                               "why": "a test"})
+    joined = []
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if set(DECODE_CELLS) <= set(m.get("workloads", [])):
+            m["workloads"].append(cell)
+            joined.append(m["name"])
+    bench["per_layer"].append({
+        "name": "decode.steps_in_window", "unit": "steps", "better": "higher",
+        "source": "program_counter", "layer": "scheduler",
+        "moves": "tokens_per_s", "workloads": [cell]})
+    with open(path, "w") as f:
+        json.dump(bench, f, indent=1)
+
+    manifest = Manifest(root)
+    assert manifest.problems() == []
+    assert {"tokens_per_s", METRIC, *NINE[:5]} <= set(joined)
+    assert_the_manifest_lists_the_metric(manifest)
+    assert_the_manifest_lists_the_nine(manifest)
+    traced = run_cell(root, cell, seed=2**31 + 41, seconds=1.0, trace=True,
+                      platform="cpu")
+    assert traced["correct"] is True, traced
+    assert traced["metrics"]["decode.steps_in_window"]["value"] > 0
+    # the decode readers it joined read it too, the device's own aside
+    assert {METRIC, *NINE[:5]} <= set(traced["metrics"])
+    after = _digests(root)
+    assert {k: after[k] for k in before} == before
+    assert sorted(set(after) - set(before)) == [
+        f"benchmark/configs/{config}.json",
+        "benchmark/layer_metrics/decode.steps_in_window.py",
+        f"benchmark/workloads/{traffic}.json"]

@@ -1,8 +1,7 @@
 """``benchmark/host_phases.py``, its nine readers and ``tools/line_check.py``:
 the arithmetic on hand-made gaps and phases (no trace file), the readers'
-promise of a number for every traced run, the nine entries held back in
-``benchmark/host_phases_entries.json`` admitted to a copy's manifest, and the
-tiny traced cells that print every one of them there."""
+promise of a number for every traced run, the nine entries in the manifest,
+and the tiny traced cells that print every one of them."""
 import json
 import os
 import subprocess
@@ -14,7 +13,7 @@ import pytest
 from benchmark import host_phases
 from benchmark.harness import Run, run_cell
 from benchmark.manifest import Manifest
-from benchmark.tools import admit_entries, line_check
+from benchmark.tools import line_check
 
 from perfbench_tiny import REPO, copy_benchmark, tiny_root
 from test_perfbench_causal_lm import tiny_lm_root
@@ -46,15 +45,6 @@ def _leave_the_process_as_found():
 
 def _read(name, run):
     return Manifest(REPO).module("layer_metrics", name).read(run)
-
-
-def _admitted(root):
-    """``root`` (a copy) with the nine entries appended to its manifest, as
-    the benchmark PR that admits them will leave the repo's."""
-    added = admit_entries.admit(
-        root, os.path.join(root, "benchmark", "host_phases_entries.json"))
-    assert added == NINE
-    return root
 
 
 # ------------------------------------------------------------- the arithmetic
@@ -266,27 +256,21 @@ def test_a_program_without_a_phase_ring_reads_none(monkeypatch):
 
 # ------------------------------------------------- the manifest and the tool
 
-def test_the_nine_are_held_back_and_fit_the_manifest_by_name(tmp_path):
-    """``BENCHMARK.json`` names none of the nine (an appended entry fails
-    ``test_perfbench_overlap``'s look-up by place, and nothing under the
-    manifest's ``paths`` is edited here); admitted to a copy they hold to the
-    contract, each found by its NAME, and what was there stays as it was."""
-    held = json.load(open(os.path.join(REPO, "benchmark",
-                                       "host_phases_entries.json")))
-    assert held["why_held_out"] and held["to_admit"]
-    before = Manifest(REPO).data["per_layer"]
-    assert not {m["name"] for m in before} & set(NINE)
-    manifest = Manifest(_admitted(copy_benchmark(tmp_path)))
-    assert manifest.problems() == []
+def assert_the_manifest_lists_the_nine(manifest):
+    """Each of the nine, found by its name, with its unit, source, layer and
+    the end-to-end metric it moves; the decode entries list the three decode
+    cells among their cells (a later decode cell may join them), the batch
+    entries the bulk cell."""
     by_name = {m["name"]: m for m in manifest.data["per_layer"]}
-    assert {n: by_name[n] for n in by_name if n not in NINE} == {
-        m["name"]: m for m in before}
     for name in NINE:
         m, decode = by_name[name], name.startswith("decode.")
         assert (m["unit"], m["better"], m["source"]) == (
             "ms", "lower", "program_span")
-        assert m["workloads"] == (DECODE_CELLS if decode
-                                  else ["resnet50-bulk"])
+        cells = set(m["workloads"])
+        if decode:
+            assert set(DECODE_CELLS) <= cells and "resnet50-bulk" not in cells
+        else:
+            assert "resnet50-bulk" in cells and not cells & set(DECODE_CELLS)
         assert m["moves"] == ("tokens_per_s" if decode else "images_per_s")
         assert m["layer"] == {"decode.idle_unphased_ms_per_step": "scheduler",
                               "runner.idle_outside_ms_per_batch": "pipeline"
@@ -299,8 +283,16 @@ def test_the_nine_are_held_back_and_fit_the_manifest_by_name(tmp_path):
             if n.startswith("decode.") == (cell != "resnet50-bulk")}
 
 
+def test_the_nine_are_held_back_and_fit_the_manifest_by_name():
+    """The nine idle-by-phase entries stand in ``BENCHMARK.json``, each found
+    by its name, and the manifest holds to the contract."""
+    manifest = Manifest(REPO)
+    assert manifest.problems() == []
+    assert_the_manifest_lists_the_nine(manifest)
+
+
 def test_line_check_names_what_a_line_lacks(tmp_path):
-    root = _admitted(copy_benchmark(tmp_path))
+    root = copy_benchmark(tmp_path)
     manifest = Manifest(root)
     cell = "gpt2xl-generate-backlog"
     traced = {"device": {"window_s": 8.0, "busy_s": 7.0}, "metrics": {
@@ -343,7 +335,7 @@ def _lacks_only_device_metrics(cell, line, root):
 def test_the_tiny_traced_decode_cell_prints_the_five_and_they_add_up(
         tmp_path):
     cell, seed = "gpt2xl-generate-backlog", 2**31 + 39
-    root = _admitted(tiny_lm_root(tmp_path))
+    root = tiny_lm_root(tmp_path)
     line = json.loads(json.dumps(run_cell(
         root, cell, seed=seed, seconds=1.0, trace=True, platform="cpu")))
     assert line["correct"] is True, line
@@ -357,7 +349,7 @@ def test_the_tiny_traced_decode_cell_prints_the_five_and_they_add_up(
 
 
 def test_the_tiny_traced_bulk_cell_prints_the_four_and_they_add_up(tmp_path):
-    root = _admitted(tiny_root(tmp_path))
+    root = tiny_root(tmp_path)
     cell = "resnet50-bulk"
     line = json.loads(json.dumps(run_cell(
         root, cell, seed=39, seconds=1.0, trace=True, platform="cpu")))

@@ -27,7 +27,7 @@ def test_a_held_out_cell_is_out_of_the_manifest_and_fits_back_in(tmp_path):
     for name in os.listdir(os.path.join(REPO, "benchmark", "held_out")):
         with open(os.path.join(REPO, "benchmark", "held_out", name)) as f:
             held[name] = json.load(f)
-    assert sorted(held) == ["resnet50-serve.json"]
+    assert "resnet50-serve.json" in held
     bench = Manifest(REPO).data
     listed = {e["name"] for g in ("workloads", "end_to_end", "per_layer")
               for e in bench[g]}
@@ -38,8 +38,7 @@ def test_a_held_out_cell_is_out_of_the_manifest_and_fits_back_in(tmp_path):
         assert names and not names & listed
     whole = Manifest(add_held_out(copy_benchmark(tmp_path)))
     assert whole.problems() == []
-    assert [w["name"] for w in whole.data["workloads"]][-1] == "resnet50-serve"
-    assert whole.data["end_to_end"][-1]["name"] == "setup_s"
+    assert "resnet50-serve" in [w["name"] for w in whole.data["workloads"]]
 
 
 @pytest.mark.parametrize("held_out", [False, True])
@@ -68,7 +67,8 @@ BREAKS = {
     "second four-chip cell": lambda d: d["workloads"][0].update(chips=4),
     "bound over a tenth": lambda d: d["end_to_end"][0].update(bound=0.2),
     "bound under a hundredth": lambda d: d["end_to_end"][0].update(bound=0.001),
-    "no setup_s": lambda d: d["end_to_end"].pop(),
+    "no setup_s": lambda d: d.update(end_to_end=[
+        m for m in d["end_to_end"] if m["name"] != "setup_s"]),
     "name used twice": lambda d: d["per_layer"][0].update(name="rows_per_s"),
     "bad name": lambda d: d["workloads"][0].update(name="-x y"),
     "metric without a reader": lambda d: d["per_layer"][0].update(name="nope"),
