@@ -184,21 +184,22 @@ def _greedy_freeze(logits, finished, eos_id):
     return tok, finished
 
 
-def _keeps_window_state(module) -> bool:
-    """Whether ``module`` keeps per-slot window state beside its pages
-    (``init_window_cache(rows)``; see :class:`PagePool`)."""
-    return hasattr(module, "init_window_cache")
+def _keeps_slot_state(module) -> bool:
+    """Whether ``module`` keeps per-slot state beside its pages
+    (``init_slot_state(rows)``; see :class:`PagePool`)."""
+    return hasattr(module, "init_slot_state")
 
 
-def _refuse_window_prefix(module) -> None:
-    """Prefix sharing over window-layer state is not built: refuse it by
-    name rather than serve a hit whose window layers never saw the prefix."""
-    if _keeps_window_state(module):
+def _refuse_slot_state_prefix(module) -> None:
+    """Prefix sharing over per-slot state is not built: refuse it by name
+    rather than serve a hit whose state never saw the prefix."""
+    if _keeps_slot_state(module):
         raise ValueError(
-            "prefix_cache=True is not supported for a module with window "
-            f"layers ({type(module).__name__}): the prefix index shares "
-            "PAGES, and a window layer keeps its last positions in a "
-            "per-slot ring that a hit would leave unwritten")
+            "prefix_cache=True is not supported for a module that keeps "
+            f"per-slot state beside its pages ({type(module).__name__}): "
+            "the prefix index shares PAGES, and a hit would need a window "
+            "layer's ring or a linear-attention layer's recurrent state as "
+            "it stood at the prefix's end, which no page holds")
 
 
 def _cached_apply(module, variables, toks, positions, table, cache,
@@ -210,7 +211,7 @@ def _cached_apply(module, variables, toks, positions, table, cache,
     its head to that one row of each sequence (prefill: the last real
     position), so the (B, P, vocab) logits are never built.  ``rows`` (B,)
     names each sequence's row of the per-slot state a module with
-    ``init_window_cache`` keeps beside its pages (a join prefills ONE
+    ``init_slot_state`` keeps beside its pages (a join prefills ONE
     sequence into its slot's row; without it sequence b uses row b).  Returns
     ``(logits, cache, sown)``: ``sown`` is what the module sowed into
     ``intermediates`` (a routed model's ``experts_touched``) and ``{}`` —
@@ -269,16 +270,19 @@ class PagePool:
     occupancy) is lock-protected and usable standalone — sizing studies
     never have to build device slabs.
 
-    Window state (ISSUE 36): a module whose window-attention layers keep a
-    bounded ring of positions a SLOT instead of pages (it has
-    ``init_window_cache(rows)``) gets that state from this pool too, built
-    for the borrower's row count and handed out and returned WITH the slabs
-    as ``(slabs, window state)``.  Pages keep their meaning: capacity,
-    occupancy, ``page_nbytes`` and the ``mmlspark_runner_page_*`` series
-    count pages of the paged layers only; the window state's bytes are
-    :meth:`window_nbytes` and ``mmlspark_runner_window_state_bytes``.  It
-    needs no allocation and no free: a slot's ring is rewritten by whoever
-    holds the slot, and what a row holds is read by position alone.
+    Per-slot state: a module some of whose layers keep a fixed-size state
+    a SLOT instead of pages (it has ``init_slot_state(rows)``: a window
+    layer's ring of positions, a linear-attention layer's recurrent state
+    and conv history) gets that state from this pool too, built for the
+    borrower's row count and handed out and returned WITH the slabs as
+    ``(slabs, slot state)``; row ``r`` is slot ``r``'s and the last row is
+    the trash row.  Pages keep their meaning: capacity, occupancy,
+    ``page_nbytes`` and the ``mmlspark_runner_page_*`` series count pages
+    of the paged layers only; the slot state's bytes are
+    :meth:`slot_state_nbytes` and ``mmlspark_runner_slot_state_bytes``.  It
+    needs no allocation, no free and no zeroing: whoever holds a slot
+    rewrites its row, a ring's rows are read by position alone, and a
+    recurrent layer starts from zero where a chunk's first position is 0.
     """
 
     #: booking ops — each books pages moved, not call count ("denied"
@@ -314,10 +318,10 @@ class PagePool:
         self._cond = make_condition("PagePool._cond")
         self._cache = None          # built lazily, rebuilt if dropped
         self._cache_nbytes = 0
-        #: rows (slots) the window state in ``_cache`` was built for, and
-        #: its bytes; 0 for a module without ``init_window_cache``
-        self._window_rows = 0
-        self._window_nbytes = 0
+        #: rows (slots) the slot state in ``_cache`` was built for, and
+        #: its bytes; 0 for a module without ``init_slot_state``
+        self._state_rows = 0
+        self._state_nbytes = 0
         self._borrowed = False
         self.high_water = 0
         #: True when the owning runner sized this pool implicitly (from a
@@ -346,11 +350,11 @@ class PagePool:
             "mmlspark_runner_page_pool_high_water_pages",
             "max KV pages ever simultaneously held",
             labels=("runner", "page_size"))
-        self._g_window = reg.gauge(
-            "mmlspark_runner_window_state_bytes",
-            "device bytes of the per-slot window state kept beside the "
-            "pages (0 for a module all of whose layers are paged)",
-            labels=("runner", "page_size"))
+        self._g_state = reg.gauge(
+            "mmlspark_runner_slot_state_bytes",
+            "device bytes of the per-slot state (window rings, recurrent "
+            "state) kept beside the pages (0 for a module all of whose "
+            "layers are paged)", labels=("runner", "page_size"))
         # page-seconds integral (ISSUE 17): pages held x wall time,
         # integrated exactly at the alloc/extend/free edges — the memory
         # half of the per-request cost ledger, and the pool-level total
@@ -502,18 +506,21 @@ class PagePool:
         return self._cache_nbytes // self.num_pages if self._cache_nbytes \
             else 0
 
-    def window_nbytes(self) -> int:
-        """Device bytes of the window state (0 until built, and for a
-        module that keeps none): bounded by the window and the borrower's
-        rows, whatever the pool's pages."""
-        return self._window_nbytes
+    def slot_state_nbytes(self) -> int:
+        """Device bytes of the per-slot state (0 until built, and for a
+        module that keeps none): fixed by the borrower's rows, whatever the
+        pool's pages."""
+        return self._state_nbytes
 
-    def borrow_cache(self, window_rows: int = 0):
+    #: the name the window cell's benchmark family reads
+    window_nbytes = slot_state_nbytes
+
+    def borrow_cache(self, state_rows: int = 0):
         """Take exclusive ownership of the device slabs (building them on
         first use), blocking while another decode holds them — the step
         executables donate the buffers, so exactly one loop may own them.
-        For a module with ``init_window_cache`` the result is ``(slabs,
-        window state)``, the state built for ``window_rows`` sequences (the
+        For a module with ``init_slot_state`` the result is ``(slabs,
+        slot state)``, the state built for ``state_rows`` sequences (the
         borrower's slots); it is rebuilt when the row count changes, and
         what it held is nobody's: no request outlives a borrow.  A borrower
         that names no rows (one that only drops the slabs) gets the state as
@@ -527,7 +534,7 @@ class PagePool:
             self._borrowed = True
             cache = self._cache
             self._cache = None
-        windowed = _keeps_window_state(self.module)
+        stateful = _keeps_slot_state(self.module)
         try:
             import jax
 
@@ -538,17 +545,17 @@ class PagePool:
                 slabs = self.module.init_paged_cache(self.num_pages,
                                                      self.page_size)
                 self._cache_nbytes = nbytes(slabs)
-                cache = (slabs, None) if windowed else slabs
-                self._window_rows = 0
-            if windowed and window_rows \
-                    and self._window_rows != int(window_rows):
-                state = self.module.init_window_cache(int(window_rows))
+                cache = (slabs, None) if stateful else slabs
+                self._state_rows = 0
+            if stateful and state_rows \
+                    and self._state_rows != int(state_rows):
+                state = self.module.init_slot_state(int(state_rows))
                 cache = (cache[0], state)
-                self._window_rows = int(window_rows)
-                self._window_nbytes = nbytes(state)
-                self._g_window.set(float(self._window_nbytes),
-                                   runner=self._name,
-                                   page_size=str(self.page_size))
+                self._state_rows = int(state_rows)
+                self._state_nbytes = nbytes(state)
+                self._g_state.set(float(self._state_nbytes),
+                                  runner=self._name,
+                                  page_size=str(self.page_size))
         except Exception:
             # a failed slab build (HBM exhaustion) must not leave the
             # pool borrowed forever — every later borrower would block
@@ -1380,7 +1387,7 @@ class ModelRunner:
             raise ValueError("prefix_cache=True needs kv_layout='paged' — "
                              "the cache shares resident PagePool pages")
         if prefix_cache:
-            _refuse_window_prefix(self.module)
+            _refuse_slot_state_prefix(self.module)
         if prefix_cache and (sample_fn is not None or collect_logits):
             raise ValueError(
                 "prefix_cache=True supports the greedy fused path only: "
@@ -2040,7 +2047,7 @@ class ContinuousDecoder:
             raise ValueError("slots, prompt_bucket and max_new_tokens "
                              "must all be >= 1")
         if prefix_cache:
-            _refuse_window_prefix(module)
+            _refuse_slot_state_prefix(module)
         self.runner = runner
         self.slots = int(slots)
         self.prompt_bucket = int(prompt_bucket)
@@ -2116,10 +2123,10 @@ class ContinuousDecoder:
         #: clock charges an overlapped step from here, not from its dispatch
         self._t_retired = 0.0
         #: per slot, the device-resident (1,) row index a join's prefill
-        #: names its slot's window state by; None for a module all of whose
+        #: names its slot's state by; None for a module all of whose
         #: layers are paged (its prefill takes no such argument)
         self._slot_rows = None
-        if _keeps_window_state(module):
+        if _keeps_slot_state(module):
             import jax.numpy as jnp
             self._slot_rows = [jnp.asarray([s], jnp.int32)
                                for s in range(self.slots)]
@@ -2316,7 +2323,7 @@ class ContinuousDecoder:
 
     def _row_arg(self, s: int) -> Tuple:
         """The join prefill's trailing argument for slot ``s``: its row of
-        the window state, or nothing for a module that keeps none."""
+        the slot state, or nothing for a module that keeps none."""
         return (self._slot_rows[s],) if self._slot_rows else ()
 
     def _return_cache_if_idle(self) -> None:
@@ -2840,7 +2847,7 @@ class ContinuousDecoder:
             "capacity": self.pool.capacity,
             "pages_in_use": self.pool.pages_in_use(),
             "occupancy_pct": round(self.pool.occupancy_pct(), 2),
-            "window_state_bytes": self.pool.window_nbytes()}
+            "slot_state_bytes": self.pool.slot_state_nbytes()}
         if self.index is not None:
             state["prefix_cache"] = self.index.stats()
         return state

@@ -36,7 +36,7 @@ Cache.  Two kinds of state, both handed out by ``PagePool``:
   slabs ``(pages, page_size, num_kv_heads * head_dim)`` addressed through the
   page table, written by ``transformer.paged_write``);
 - window layers: a RING of ``window`` positions a sequence
-  (``init_window_cache(rows)``: K and V ``(rows + 1, window, C)``; ring row
+  (``init_slot_state(rows)``: K and V ``(rows + 1, window, C)``; ring row
   ``r`` belongs to the engine's slot ``r`` and the last row is the trash row,
   where sequences whose table names no page write).  Position ``p`` lives at
   ring index ``p % window``, so with the newest position ``last`` index ``i``
@@ -125,6 +125,99 @@ def step_attention(q, keys, vals, visible, kv_heads: int):
     return out.reshape(B, H * d)
 
 
+def attend(q, k, v, select, kv_heads: int):
+    """Attention of ``q`` (B, L, H, d) over ``k``, ``v`` (B, S, kv_heads *
+    d) where ``select`` (B, L, S) holds: a step's one row by
+    :func:`step_attention`, a chunk's rows by ``masked_attention``; float32
+    (B, L, H * d)."""
+    B, L, _, d = q.shape
+    S = k.shape[1]
+    if L == 1:
+        return step_attention(q[:, 0], k, v, select[:, 0], kv_heads)[:, None]
+    return masked_attention(q, k.reshape(B, S, kv_heads, d),
+                            v.reshape(B, S, kv_heads, d), select)
+
+
+def full_attention(q, k, v, positions, cache=(), page_table=None):
+    """A full layer without positional encoding: every position up to the
+    query's own.  ``q`` (B, L, H, d), ``k``, ``v`` (B, L, kv_heads * d) the
+    chunk's own; with the layer's paged ``cache`` ``(k, v)`` the rows are
+    written through ``page_table`` and the context is read back from the
+    pages.  Returns ``(a (B, L, H * d) float32, cache)``.  Every model with
+    such a layer calls this one function."""
+    B, _, _, d = q.shape
+    kv_heads = k.shape[-1] // d
+    if not cache:
+        select = positions[:, None, :] <= positions[:, :, None]
+    else:
+        with jax.named_scope("lm.cache_write"):
+            cache = tuple(paged_write(slab, new, positions, page_table)
+                          for slab, new in zip(cache, (k, v)))
+        with jax.named_scope("lm.full_attn"):
+            S = page_table.shape[1] * cache[0].shape[1]
+            k, v = (slab[page_table].reshape(B, S, kv_heads * d)
+                    for slab in cache)
+            # gathered slot s IS absolute position s
+            select = jnp.arange(S) <= positions[:, :, None]
+    with jax.named_scope("lm.full_attn"):
+        return attend(q, k, v, select, kv_heads), cache
+
+
+def window_attention(q, k, v, positions, window: int, cache=(),
+                     page_table=None, rows=None, last_row=None):
+    """A window layer: key ``s`` visible to query ``t`` iff ``0 <= t - s <
+    window``, read from the slot's ring (``cache`` ``(k, v)``, see the
+    module's docstring) as earlier chunks and steps left it, ``rows`` (B,)
+    naming each sequence's ring row.  Shapes and result as
+    :func:`full_attention`'s."""
+    B, L, _, d = q.shape
+    Wn, kv_heads = window, k.shape[-1] // d
+    if not cache:
+        select = window_visible(positions, positions, Wn)
+    else:
+        ck, cv = cache
+        own = jnp.arange(B, dtype=jnp.int32) if rows is None else rows
+        # a sequence whose table names no page (a pad row, a warm-up
+        # dispatch) writes the trash row, as it writes the trash page
+        to = jnp.where(page_table[:, 0] > 0, own, ck.shape[0] - 1)
+        if L == 1:
+            # a step: write this position's row, read the slot's window
+            with jax.named_scope("lm.cache_write"):
+                at = positions[:, 0] % Wn
+                ck, cv = ck.at[to, at].set(k[:, 0]), \
+                    cv.at[to, at].set(v[:, 0])
+            k_pos = ring_positions(positions[:, 0], Wn)
+            with jax.named_scope("lm.window_attn"):
+                k, v = (ck[:B], cv[:B]) if rows is None else \
+                    (ck[own], cv[own])
+        else:
+            # a prefill chunk: the ring as earlier chunks left it, then
+            # the chunk's own rows; its last ``window`` real rows stay
+            with jax.named_scope("lm.window_attn"):
+                old_k, old_v = ck[own], cv[own]
+                k_pos = jnp.concatenate(
+                    [ring_positions(positions[:, 0] - 1, Wn), positions],
+                    axis=1)
+            with jax.named_scope("lm.cache_write"):
+                first = positions[:, 0]
+                last = first + (L - 1 if last_row is None else last_row)
+                src = ring_positions(last, Wn) - first[:, None]  # (B, Wn)
+                fresh = (src >= 0)[..., None]
+                src = jnp.clip(src, 0, L - 1)[..., None]
+                ck, cv = (
+                    ring.at[to].set(jnp.where(
+                        fresh, jnp.take_along_axis(new, src, axis=1),
+                        old))
+                    for ring, new, old in ((ck, k, old_k), (cv, v, old_v)))
+            with jax.named_scope("lm.window_attn"):
+                k = jnp.concatenate([old_k, k], axis=1)
+                v = jnp.concatenate([old_v, v], axis=1)
+        cache = (ck, cv)
+        select = window_visible(positions, k_pos, Wn)
+    with jax.named_scope("lm.window_attn"):
+        return attend(q, k, v, select, kv_heads), cache
+
+
 class GatedMLP(nn.Module):
     """``(silu(x Wg) * (x Wu)) Wd``: the dense MLP and the shared expert."""
     features: int
@@ -205,69 +298,11 @@ class WindowMoEBlock(nn.Module):
                 k = _rope(k, positions, self.rope_theta)
             q, k = q.astype(dt), k.astype(dt).reshape(B, L, KV * d)
 
-        if not cache:
-            k_pos = positions
-            select = window_visible(positions, k_pos, Wn) if Wn else \
-                k_pos[:, None, :] <= positions[:, :, None]
-        elif not Wn:
-            with jax.named_scope("lm.cache_write"):
-                ck, cv = (paged_write(slab, new, positions, page_table)
-                          for slab, new in zip(cache, (k, v)))
-            cache = (ck, cv)
-            with jax.named_scope("lm.full_attn"):
-                S = page_table.shape[1] * ck.shape[1]
-                k, v = (slab[page_table].reshape(B, S, KV * d)
-                        for slab in cache)
-                # gathered slot s IS absolute position s
-                select = jnp.arange(S) <= positions[:, :, None]
+        if Wn:
+            a, cache = window_attention(q, k, v, positions, Wn, cache,
+                                        page_table, rows, last_row)
         else:
-            ck, cv = cache
-            own = jnp.arange(B, dtype=jnp.int32) if rows is None else rows
-            # a sequence whose table names no page (a pad row, a warm-up
-            # dispatch) writes the trash row, as it writes the trash page
-            to = jnp.where(page_table[:, 0] > 0, own, ck.shape[0] - 1)
-            if L == 1:
-                # a step: write this position's row, read the slot's window
-                with jax.named_scope("lm.cache_write"):
-                    at = positions[:, 0] % Wn
-                    ck, cv = ck.at[to, at].set(k[:, 0]), \
-                        cv.at[to, at].set(v[:, 0])
-                k_pos = ring_positions(positions[:, 0], Wn)
-                with jax.named_scope("lm.window_attn"):
-                    k, v = (ck[:B], cv[:B]) if rows is None else \
-                        (ck[own], cv[own])
-            else:
-                # a prefill chunk: the ring as earlier chunks left it, then
-                # the chunk's own rows; its last ``window`` real rows stay
-                with jax.named_scope("lm.window_attn"):
-                    old_k, old_v = ck[own], cv[own]
-                    k_pos = jnp.concatenate(
-                        [ring_positions(positions[:, 0] - 1, Wn), positions],
-                        axis=1)
-                with jax.named_scope("lm.cache_write"):
-                    first = positions[:, 0]
-                    last = first + (L - 1 if last_row is None else last_row)
-                    src = ring_positions(last, Wn) - first[:, None]  # (B, Wn)
-                    fresh = (src >= 0)[..., None]
-                    src = jnp.clip(src, 0, L - 1)[..., None]
-                    ck, cv = (
-                        ring.at[to].set(jnp.where(
-                            fresh, jnp.take_along_axis(new, src, axis=1),
-                            old))
-                        for ring, new, old in ((ck, k, old_k), (cv, v, old_v)))
-                with jax.named_scope("lm.window_attn"):
-                    k = jnp.concatenate([old_k, k], axis=1)
-                    v = jnp.concatenate([old_v, v], axis=1)
-            cache = (ck, cv)
-            select = window_visible(positions, k_pos, Wn)
-
-        with jax.named_scope("lm.window_attn" if Wn else "lm.full_attn"):
-            S = k.shape[1]
-            if L == 1:
-                a = step_attention(q[:, 0], k, v, select[:, 0], KV)[:, None]
-            else:
-                a = masked_attention(q, k.reshape(B, S, KV, d),
-                                     v.reshape(B, S, KV, d), select)
+            a, cache = full_attention(q, k, v, positions, cache, page_table)
         with jax.named_scope("lm.dense"):
             x = x + RMSNorm(self.rms_eps, name="attn_out_norm")(
                 Proj(D, dt, name="o")(a))
@@ -401,11 +436,12 @@ class WindowMoEDecoder(nn.Module):
         return self._zero_state(
             (num_pages, page_size, self.num_kv_heads * self.head_dim), False)
 
-    def init_window_cache(self, rows: int):
-        """Zeroed rings of the WINDOW layers for ``rows`` sequences: per
-        layer ``(k, v)`` of ``(rows + 1, sliding_window, num_kv_heads *
-        head_dim)``, the last row the trash row; ``()`` for a full layer.
-        Its size does not depend on how long a sequence may grow."""
+    def init_slot_state(self, rows: int):
+        """The per-slot state ``PagePool`` keeps beside the pages: zeroed
+        rings of the WINDOW layers for ``rows`` sequences, per layer ``(k,
+        v)`` of ``(rows + 1, sliding_window, num_kv_heads * head_dim)``, the
+        last row the trash row; ``()`` for a full layer.  Its size does not
+        depend on how long a sequence may grow."""
         if rows < 1:
             raise ValueError(f"rows must be >= 1, got {rows}")
         return self._zero_state(

@@ -145,7 +145,7 @@ def served():
         out = dict(
             module=module, variables=variables, handles=first + later,
             slots=[h.slot for h in first + later],
-            window_bytes=pool.window_nbytes(),
+            window_bytes=pool.slot_state_nbytes(),
             page_bytes=pool.page_nbytes(), pages_left=pool.pages_in_use(),
             chunks=reg.family("mmlspark_runner_prefill_chunks_total")
             .labels(runner="wm.served").value,
@@ -153,7 +153,7 @@ def served():
             .labels(runner="wm.served").value,
             touched=reg.family("mmlspark_runner_moe_experts_touched_total")
             .labels(runner="wm.served").value,
-            gauge=reg.family("mmlspark_runner_window_state_bytes")
+            gauge=reg.family("mmlspark_runner_slot_state_bytes")
             .labels(runner="wm.served", page_size=str(PAGE)).value,
             steps=dec.steps, debug=dec.debug_state())
         dec.close()
@@ -186,7 +186,7 @@ def test_a_slot_taken_again_by_a_shorter_request_sees_no_stale_row(served):
 def test_the_counters_and_the_gauge_of_the_window_state(served):
     ring = (3 + 1) * WINDOW * 2 * 16 * 4        # rows x window x C x float32
     assert served["window_bytes"] == served["gauge"] == ring * 2 * 4
-    assert served["debug"]["pool"]["window_state_bytes"] == ring * 2 * 4
+    assert served["debug"]["pool"]["slot_state_bytes"] == ring * 2 * 4
     # a page holds K and V of the ONE full layer
     assert served["page_bytes"] == PAGE * 2 * 16 * 4 * 2
     # 8 of 16 experts held: about half of a step's 3 x 4 x 4 assignments
@@ -202,7 +202,7 @@ def test_window_state_does_not_grow_with_the_longest_sequence(new):
                      new=new)
     dec.warmup()
     pool = dec.pool
-    assert pool.window_nbytes() == 4 * WINDOW * 32 * 4 * 2 * 4
+    assert pool.slot_state_nbytes() == 4 * WINDOW * 32 * 4 * 2 * 4
     assert pool.num_pages == 3 * -(-(112 + new) // PAGE) + 1
     assert pool.page_nbytes() == PAGE * 32 * 4 * 2
     dec.close()
@@ -214,7 +214,7 @@ def test_a_step_of_window_layers_reads_window_rows_a_slot():
     slot whatever the context length."""
     module, variables = _model(layer_types=("sliding_attention",) * 5)
     slots, width = 3, 37                       # 148 positions a slot
-    cache = (module.init_paged_cache(8, PAGE), module.init_window_cache(slots))
+    cache = (module.init_paged_cache(8, PAGE), module.init_slot_state(slots))
     assert all(layer == () for layer in cache[0])
     assert {a.shape for a in jax.tree_util.tree_leaves(cache[1])} \
         == {(slots + 1, WINDOW, 32)}
@@ -270,7 +270,7 @@ def test_prefix_sharing_over_window_state_is_refused_by_name():
 
 
 def test_a_pool_without_a_module_and_a_paged_module_keep_no_window_state():
-    assert PagePool(None, 4, PAGE, name="wm.bare").window_nbytes() == 0
+    assert PagePool(None, 4, PAGE, name="wm.bare").slot_state_nbytes() == 0
     module = sparse_moe.SparseMoEDecoder(
         vocab_size=64, embed_dim=32, num_layers=1, num_heads=2,
         num_kv_heads=1, head_dim=16, num_experts=4, experts_per_token=2,
@@ -278,7 +278,7 @@ def test_a_pool_without_a_module_and_a_paged_module_keep_no_window_state():
     pool = PagePool(module, 4, PAGE, name="wm.paged")
     cache = pool.borrow_cache(3)
     assert len(cache) == 1 and len(cache[0]) == 3        # (k, v, index_k)
-    assert pool.window_nbytes() == 0 and pool.page_nbytes() > 0
+    assert pool.slot_state_nbytes() == 0 and pool.page_nbytes() > 0
     pool.return_cache(cache)
 
 
